@@ -401,7 +401,7 @@ def test_tmlint_script_gate_runs_without_jax(tmp_path):
     """tools/tmlint.py must run the gate on a box where `import jax`
     raises (broken plugin, half-installed venv): it loads the analysis
     subpackage behind a parent-package stub so theanompi_tpu/__init__
-    (which imports jax via compat) never executes."""
+    never executes."""
     import subprocess
     import sys as _sys
 

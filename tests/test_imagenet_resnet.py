@@ -339,8 +339,8 @@ class TestSyncBN:
 
 @pytest.mark.slow
 def test_graft_entry_dryrun():
-    # conftest already pinned cpu + 8 virtual devices, so the dryrun's
-    # own forcing is a no-op and 8 devices are available.
+    # conftest pinned cpu + 8 virtual devices; the dryrun takes the
+    # devices the process has
     from __graft_entry__ import dryrun_multichip
 
     dryrun_multichip(8)

@@ -264,36 +264,10 @@ class TestCopyAttribution:
         assert rep["rows"] == [] and rep["copy_done_ms_per_step"] == 0
 
 
-from xla_sweep import SWEEPS, ab_report, build_entries  # noqa: E402
+from xla_sweep import ab_report  # noqa: E402
 
 
 class TestXlaSweep:
-    def test_entries_are_queue_ready(self):
-        entries = build_entries()
-        names = [e[0] for e in entries]
-        # flags x models throughput points + the A/B profile pair
-        assert "sweep_resnet_k4_b128_lhs" in names
-        assert "resnet_ab_before_profile" in names
-        assert "resnet_ab_after_fused_profile" in names
-        for name, argv, timeout in entries:
-            assert isinstance(name, str) and isinstance(timeout, int)
-            assert isinstance(argv, list) and len(argv) >= 2
-        ab = dict((e[0], e[1]) for e in entries)
-        after = ab["resnet_ab_after_fused_profile"]
-        assert "--bn-act-impl" in after and "pallas" in after
-        before = ab["resnet_ab_before_profile"]
-        assert "--bn-act-impl" not in before
-        # every non-base sweep entry carries its flags
-        lhs = ab["sweep_resnet_k4_b128_lhs"]
-        assert "--xla-flags" in lhs
-        assert SWEEPS["lhs"] in lhs
-
-    def test_entries_respect_config_override(self):
-        entries = build_entries(sweeps={"only": "--xla_foo=1"})
-        names = [e[0] for e in entries]
-        assert "sweep_resnet_k4_b128_only" in names
-        assert not any("_lhs" in n for n in names)
-
     def test_ab_report_deltas(self):
         def account(conv, copy, copy_rows):
             return {

@@ -31,11 +31,6 @@ Public API parity surface (reference ``theanompi/__init__.py``):
     rule.wait()
 """
 
-# jax version shims (installs jax.shard_map on the 0.4.x line) — must
-# run before any submodule traces a step; importing the parent package
-# happens before any submodule import, so this covers every entry path
-from theanompi_tpu import compat as _compat  # noqa: F401
-
 __version__ = "0.1.0"
 
 __all__ = ["BSP", "EASGD", "ASGD", "GOSGD", "__version__"]

@@ -30,6 +30,15 @@ from theanompi_tpu.analysis.lockgraph import make_lock
 from theanompi_tpu.ingest import protocol
 
 
+def _host_only_env() -> dict:
+    """Environment for readers and the coordinator: their work is
+    numpy + sockets, so they are pinned to the CPU platform and can
+    never claim the trainer's chip.  Set by the spawner because the
+    children's own ``setdefault`` loses to an inherited
+    ``JAX_PLATFORMS=tpu``."""
+    return dict(os.environ, JAX_PLATFORMS="cpu")
+
+
 def _free_port() -> int:
     import socket
 
@@ -103,13 +112,13 @@ class IngestProcessGroup:
                "--reader-id", str(index)]
         if self.max_inflight is not None:
             cmd += ["--max-inflight", str(self.max_inflight)]
-        return subprocess.Popen(cmd, env=dict(os.environ))
+        return subprocess.Popen(cmd, env=_host_only_env())
 
     def _spawn_coordinator(self, port: int) -> subprocess.Popen:
         cmd = [sys.executable, "-m", "theanompi_tpu.ingest.coordinator",
                "--host", self.host, "--port", str(port),
                "--readers", ",".join(self.reader_addresses)]
-        return subprocess.Popen(cmd, env=dict(os.environ))
+        return subprocess.Popen(cmd, env=_host_only_env())
 
     def _probe(self, addr: str) -> dict | None:
         from theanompi_tpu.parallel.service import ServiceClient

@@ -287,15 +287,6 @@ def _build_parser(multihost: bool) -> argparse.ArgumentParser:
     p.add_argument("--slo-p99-ms", type=float, default=None,
                    help="SERVE --disaggregate --autoscale: intertoken "
                         "p99 target feeding the decode scale signal")
-    p.add_argument("--compilation-cache-dir", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache "
-                        "(utils/helper_funcs.enable_compilation_cache): "
-                        "a repeat run deserializes compiled programs "
-                        "instead of paying the measured 39.3 s ResNet-50 "
-                        "compile again.  Default: <monitor-dir>/jax_cache "
-                        "when --monitor-dir is set, else off; exported "
-                        "as THEANOMPI_TPU_COMPILATION_CACHE so "
-                        "subprocesses share it")
     p.add_argument("--monitor-dir", default=None, metavar="DIR",
                    help="enable the telemetry subsystem and write its "
                         "artifacts (metrics snapshot JSONL + Prometheus "
@@ -467,21 +458,11 @@ def _run_session(args, multihost: bool) -> int:
     if args.platform:
         import jax
 
-        # must land before the first backend touch; env alone can be
-        # overridden by site customizations that pre-register plugins
+        # must land before the first backend touch
         jax.config.update("jax_platforms", args.platform)
-    cache_dir = args.compilation_cache_dir
-    if cache_dir is None and args.monitor_dir:
-        # default under the monitor dir: the run's artifacts and its
-        # compiled-program cache live (and get cleaned up) together
-        import os
-
-        cache_dir = os.path.join(args.monitor_dir, "jax_cache")
-    # cache_dir=None still honors an inherited env var (a run_tpu_queue
-    # child gets the queue-wide cache without any flag)
     from theanompi_tpu.utils.helper_funcs import enable_compilation_cache
 
-    enable_compilation_cache(cache_dir)
+    enable_compilation_cache()
     if args.decode and args.rule != "SERVE":
         # silently ignoring the flag would let the user believe the
         # decode plane is live when it is not

@@ -55,7 +55,7 @@ class ResNet50_LargeBatch(ResNet50):
 
         return ModelConfig(
             # per-chip batch 128, measured: the round-3 on-chip ladder
-            # (artifacts/tpu_queue_r03.jsonl, BASELINE.md table) ran
+            # (older stack, JAX 0.4.x — BASELINE.md table) ran
             # b/chip in {128,256} x k in {1,4,8} and b=256 LOST at
             # every k (-2.45% to -5.08% img/s/chip) — N<=256 lane-bound
             # conv GEMMs don't gain from doubling M while the 2x

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from theanompi_tpu.data.imagenet import ImageNet_data
 from theanompi_tpu.models import layers as L
 from theanompi_tpu.models.base import ModelConfig, TpuModel
-from theanompi_tpu.ops.maxpool import maxpool_stem
 
 
 class BottleneckBlock(nn.Module):
@@ -117,9 +116,6 @@ class ResNet(nn.Module):
     stem: str = "conv7"          # 'conv7' | 's2d'
     #: cross-replica BN axis (ModelConfig.sync_bn); None = per-shard
     bn_axis: str | None = None
-    #: stem max-pool impl (ModelConfig.pool_impl): 'xla' or 'pallas'
-    #: (argmax-saving kernel, ops/maxpool_pallas.py)
-    pool_impl: str = "xla"
     #: BN+activation epilogue impl (ModelConfig.bn_act_impl): 'xla'
     #: (unfused reference path) or 'pallas' (ops/fused_bn.py)
     bn_act_impl: str = "xla"
@@ -156,7 +152,7 @@ class ResNet(nn.Module):
         # HBM-bound loop fusion (artifacts/fusion_deepdive.json
         # 'fwd/ResNet/max'); post-pool it fuses into the maxpool
         # output fusion's quarter-size stream.
-        x = maxpool_stem(x, impl=self.pool_impl)
+        x = nn.max_pool(x, (3, 3), (2, 2), padding=[(1, 1), (1, 1)])
         x = nn.relu(x)
         for stage, n_blocks in enumerate(self.stage_sizes):
             for block in range(n_blocks):
@@ -204,7 +200,6 @@ class ResNet50(TpuModel):
                       dtype=self._compute_dtype(),
                       stem=self.config.resnet_stem,
                       bn_axis=self._bn_axis(),
-                      pool_impl=self.config.pool_impl,
                       bn_act_impl=self.config.bn_act_impl)
 
     def build_data(self):

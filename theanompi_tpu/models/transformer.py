@@ -82,6 +82,10 @@ class Block(nn.Module):
     d_ff: int
     sp_strategy: str = "ring"
     dtype: jnp.dtype = jnp.float32
+    #: ops.attention impl for the full local path; None = its own
+    #: choice (Pallas on TPU).  GSPMD-jitted steps pass 'xla'
+    #: (TransformerLM_TP.attn_impl)
+    attn_impl: str | None = None
 
     @nn.compact
     def __call__(self, x, seq_axis: str | None = None):
@@ -106,7 +110,8 @@ class Block(nn.Module):
         else:
             # full local attention: the fused Pallas kernel on TPU
             # (ops/attention.py; XLA oracle elsewhere/oversize)
-            o = fused_attention(q, k, v, causal=True)
+            o = fused_attention(q, k, v, causal=True,
+                                impl=self.attn_impl)
         o = o.reshape((b, t, self.d_model))
         x = x + nn.Dense(self.d_model, use_bias=False,
                          kernel_init=L.xavier_init(), dtype=self.dtype,
@@ -140,6 +145,8 @@ class TransformerLMNet(nn.Module):
     #: jax.checkpoint each block: recompute activations in the
     #: backward instead of storing them (ModelConfig.remat)
     remat: bool = False
+    #: forwarded to every Block (see Block.attn_impl)
+    attn_impl: str | None = None
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,
@@ -162,7 +169,7 @@ class TransformerLMNet(nn.Module):
                      if self.remat else Block)
         for i in range(self.n_layers):
             x = block_cls(self.d_model, self.n_heads, self.d_ff,
-                          self.sp_strategy, self.dtype,
+                          self.sp_strategy, self.dtype, self.attn_impl,
                           name=f"Block_{i}")(x, seq_axis)
         x = nn.LayerNorm(dtype=self.dtype)(x)
         logits = nn.Dense(self.vocab, kernel_init=L.xavier_init(),
@@ -179,6 +186,9 @@ class TransformerLM(TpuModel):
     #: mesh axis the TIME dimension is sharded over inside the step
     #: (None = full attention; the TP variant sets None)
     seq_axis: str | None = AXIS_SEQ
+    #: ops.attention impl of the full local path; None = its own choice
+    #: (Pallas on TPU).  The tensor-parallel variant overrides it
+    attn_impl: str | None = None
     #: exports of this family may serve the autoregressive decode path
     #: (theanompi_tpu/decode — single-flax-module param tree; the
     #: PP/MoE variants assemble diverging trees and stay eval-only)
@@ -238,7 +248,8 @@ class TransformerLM(TpuModel):
             vocab=c["vocab"], n_layers=c["n_layers"], d_model=c["d_model"],
             n_heads=c["n_heads"], d_ff=4 * c["d_model"],
             max_len=max(2048, c["seq_len"]), sp_strategy=self.sp_strategy,
-            dtype=self._compute_dtype(), remat=self.config.remat)
+            dtype=self._compute_dtype(), remat=self.config.remat,
+            attn_impl=self.attn_impl)
 
     # -- (data x seq) SPMD wiring -------------------------------------------
 
@@ -280,6 +291,13 @@ class TransformerLM_TP(TransformerLM):
     name = "transformer_lm_tp"
     batch_partition = P(AXIS_DATA)   # tokens (B, T): batch over 'data'
     seq_axis = None                  # full attention; 'model' splits heads
+
+    #: GSPMD cannot partition a Mosaic kernel: on four chips this
+    #: model's plain-jit step died with "Mosaic kernels cannot be
+    #: automatically partitioned. Please wrap the call in a shard_map"
+    #: (PR 21), so it takes the XLA attention.  Giving it the kernel
+    #: means wrapping the call in a shard_map over (data, model) — open
+    attn_impl = "xla"
 
     def _create_state(self, params, model_state):
         """Shard params per the Megatron specs and build the optimizer

@@ -1,5 +1,4 @@
 from theanompi_tpu.ops.fused_bn import scale_bias_act
 from theanompi_tpu.ops.lrn import lrn
-from theanompi_tpu.ops.maxpool import maxpool_stem
 
-__all__ = ["lrn", "maxpool_stem", "scale_bias_act"]
+__all__ = ["lrn", "scale_bias_act"]

@@ -23,19 +23,31 @@ Scope notes:
   matrix never exists outside VMEM in either direction.  Ragged
   q-blocks or oversize shapes fall back to the composed-XLA VJP.
 * ``impl='auto'``: Pallas on TPU, XLA elsewhere; force with
-  ``THEANOMPI_TPU_ATTN_IMPL=pallas|xla`` (interpret mode makes the
-  Pallas path unit-testable on the CPU mesh, tests/test_ops.py).
+  ``THEANOMPI_TPU_ATTN_IMPL=pallas|xla`` (interpret mode, CPU platform
+  only, makes the Pallas path unit-testable — tests/test_ops.py).
+  Every choice made from a shape is logged once per shape at trace
+  time (logger ``theanompi_tpu.ops.attention``; a warning when a TPU
+  run takes the XLA form), so no path is taken quietly.
+* On-chip status (PR 21, TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34):
+  fwd and the fused bwd compile and match the XLA form at
+  (8, 1024, 12, 64) bf16 causal; chip_smoke.py repeats that check.
+  The ragged-q-tail path has not been compiled.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from theanompi_tpu.ops import pallas_mode
+
+_log = logging.getLogger(__name__)
 
 # large-negative mask value: finite so softmax/online-softmax
 # accumulators never produce inf-inf=nan; exp(-1e30 - m) underflows to
@@ -44,8 +56,7 @@ from jax.experimental.pallas import tpu as pltpu
 _MASK_NEG = -1e30
 #: per-(batch*head) VMEM budget for K + V + one fp32 score block.
 #: Env-tunable (THEANOMPI_TPU_ATTN_VMEM_MB / _ATTN_QBLOCK) so on-chip
-#: block-size sweeps need no code edits; defaults are the round-2
-#: interpret-validated values.
+#: block-size sweeps need no code edits.
 _VMEM_BUDGET_BYTES = int(float(os.environ.get(
     "THEANOMPI_TPU_ATTN_VMEM_MB", "12")) * 1024 * 1024)
 if _VMEM_BUDGET_BYTES <= 0:
@@ -168,22 +179,39 @@ def _fits_vmem_bwd(tq, tk, d, dtype) -> bool:
     return need <= _VMEM_BUDGET_BYTES
 
 
+@functools.lru_cache(maxsize=None)
+def _log_choice(what: str, shape: tuple, dtype: str, choice: str,
+                why: str) -> None:
+    """One line per (shape, choice): the cache is the once-per-shape
+    memory.  Runs at trace time only."""
+    level = (logging.WARNING
+             if choice == "xla" and jax.default_backend() == "tpu"
+             else logging.INFO)
+    _log.log(level, "%s q=%s %s -> %s (%s)", what, shape, dtype, choice,
+             why)
+
+
 def _resolve_impl(impl: str | None, q, k) -> str:
     impl = impl or os.environ.get("THEANOMPI_TPU_ATTN_IMPL", "auto")
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
-    if impl == "auto":
-        b, tq, h, d = q.shape
-        if not _fits_vmem(tq, k.shape[1], d, q.dtype):
-            return "xla"
-        # ragged q-tails rely on Pallas out-of-range block padding that
-        # is only exercised in interpret mode (ADVICE r2) — on real
-        # silicon route them to XLA like the backward already does;
-        # impl='pallas' still forces the kernel (how tests cover it)
-        if tq % min(_Q_BLOCK, tq) != 0:
-            return "xla"
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return impl
+    if impl != "auto":
+        return impl
+    b, tq, h, d = q.shape
+    if jax.default_backend() != "tpu":
+        choice, why = "xla", "not a TPU"
+    elif not _fits_vmem(tq, k.shape[1], d, q.dtype):
+        choice, why = "xla", "K/V + score block exceed the VMEM budget"
+    elif tq % min(_Q_BLOCK, tq) != 0:
+        # ragged q-tails rely on Pallas out-of-range block padding,
+        # which has only ever run interpreted; impl='pallas' still
+        # forces the kernel (how tests cover it)
+        choice, why = "xla", f"ragged q-tail (Tq % {_Q_BLOCK} != 0)"
+    else:
+        choice, why = "pallas", "fits"
+    _log_choice("attention fwd", q.shape + (k.shape[1],), str(q.dtype),
+                choice, why)
+    return choice
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
@@ -322,8 +350,13 @@ def _fused_bwd(scale, causal, interpret, res, g):
     tq = q.shape[1]
     # the fused bwd loops exact q-blocks; ragged tails or oversize
     # VMEM needs take the composed-XLA path instead
-    if tq % min(_Q_BLOCK, tq) == 0 and _fits_vmem_bwd(
-            tq, k.shape[1], q.shape[-1], q.dtype):
+    fused = tq % min(_Q_BLOCK, tq) == 0 and _fits_vmem_bwd(
+        tq, k.shape[1], q.shape[-1], q.dtype)
+    _log_choice("attention bwd", q.shape + (k.shape[1],), str(q.dtype),
+                "pallas" if fused else "xla",
+                "fits" if fused else "ragged q-tail or over the VMEM "
+                "budget")
+    if fused:
         dq, dk, dv = _pallas_attention_bwd(q, k, v, q_pos, k_pos, lse,
                                            g, scale, causal, interpret)
     else:
@@ -351,5 +384,5 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
     resolved = _resolve_impl(impl, q, k)
     if resolved == "xla":
         return _xla_attention(q, k, v, q_pos, k_pos, scale, causal)
-    interpret = jax.default_backend() != "tpu"
-    return _fused(q, k, v, q_pos, k_pos, scale, causal, interpret)
+    return _fused(q, k, v, q_pos, k_pos, scale, causal,
+                  pallas_mode.interpret())

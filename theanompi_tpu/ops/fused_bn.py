@@ -22,12 +22,21 @@ the relu mask from the saved input instead of storing it and emits
 single stream, so fwd+bwd touch x, residual and g once each.
 
 Like ops/lrn_pallas.py this tiles the flattened ``(N*H*W, C)`` view
-into VMEM row-blocks and runs in interpret mode off-TPU, so the
-numerics are unit-tested on the CPU mesh (tests/test_fused_bn.py pins
-forward AND gradient against the unfused XLA reference).  Opt-in via
-``ModelConfig.bn_act_impl='pallas'`` — 'xla' stays the default until
-the queued A/B pair (tools/xla_sweep.py, artifacts/) confirms the
-account's prediction on chip.
+into VMEM row-blocks and runs in interpret mode on the CPU platform
+only (ops/pallas_mode.py), so the numerics are unit-tested on the CPU
+mesh (tests/test_fused_bn.py pins forward AND gradient against the
+unfused XLA reference).
+
+On-chip status (PR 21, TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34):
+compiles and matches the XLA form fwd+bwd at the ResNet-50 stage-1
+shapes (128*56*56 rows x 256 and x 64 channels, bf16, with and
+without residual) — chip_smoke.py repeats that check.  As first
+written the backward was refused: its per-block partial sums used a
+``(1, C)`` block over an ``(n_blocks, C)`` array, which breaks the
+(8, 128) block rule; they now accumulate into one resident ``(1, C)``
+block (``_accumulate``).  Its SPEED against the XLA form has not been
+measured, so it stays opt-in via ``ModelConfig.bn_act_impl='pallas'``
+(ROADMAP A2).
 """
 
 from __future__ import annotations
@@ -39,18 +48,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from theanompi_tpu.ops.pallas_mode import interpret
+
 #: per-operand VMEM block budget; with 4 streamed operands (x, g, dx,
 #: res) in the widest backward this keeps the working set ~2 MB
 _TILE_BYTES = 1 << 19
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _tile_rows(m: int, c: int, itemsize: int) -> int:
+    # 32 rows is the sublane tile of the narrowest dtype (int8/fp8;
+    # bf16 packs 16, f32 8), so one rounding serves every operand
     rows = _TILE_BYTES // max(c * itemsize, 1)
-    rows = max(8, (rows // 8) * 8)
+    rows = max(32, (rows // 32) * 32)
     return min(rows, m)
 
 
@@ -80,6 +89,22 @@ def _fwd_res_kernel(x_ref, s_ref, b_ref, r_ref, y_ref, *, relu):
     y_ref[:] = z.astype(y_ref.dtype)
 
 
+def _accumulate(ds_ref, db_ref, gx, g):
+    """Add this row-block's column sums into the (1, C) cotangents.
+    Their out-spec maps every grid step to the same block, so it stays
+    resident in VMEM across the (sequential) row grid and is written
+    back once.  (A per-block (n_blocks, C) partial-sum output needs a
+    (1, C) block on a dimension that is not 1, which Mosaic's (8, 128)
+    block rule refuses.)"""
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        ds_ref[...] = jnp.zeros_like(ds_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    ds_ref[...] += jnp.sum(gx, axis=0, keepdims=True)
+    db_ref[...] += jnp.sum(g, axis=0, keepdims=True)
+
+
 def _bwd_kernel(x_ref, s_ref, b_ref, g_ref, dx_ref, ds_ref, db_ref,
                 *, relu, m_rows, tile):
     x = x_ref[:].astype(jnp.float32)
@@ -89,8 +114,7 @@ def _bwd_kernel(x_ref, s_ref, b_ref, g_ref, dx_ref, ds_ref, db_ref,
         g = jnp.where(x * s + b_ref[0] > 0, g, 0.0)
     g = jnp.where(_row_mask(x.shape, m_rows, tile), g, 0.0)
     dx_ref[:] = (g * s).astype(dx_ref.dtype)
-    ds_ref[0] = jnp.sum(g * x, axis=0)
-    db_ref[0] = jnp.sum(g, axis=0)
+    _accumulate(ds_ref, db_ref, g * x, g)
 
 
 def _bwd_res_kernel(x_ref, s_ref, b_ref, r_ref, g_ref,
@@ -105,21 +129,18 @@ def _bwd_res_kernel(x_ref, s_ref, b_ref, r_ref, g_ref,
     g = jnp.where(_row_mask(x.shape, m_rows, tile), g, 0.0)
     dx_ref[:] = (g * s).astype(dx_ref.dtype)
     dr_ref[:] = g.astype(dr_ref.dtype)
-    ds_ref[0] = jnp.sum(g * x, axis=0)
-    db_ref[0] = jnp.sum(g, axis=0)
+    _accumulate(ds_ref, db_ref, g * x, g)
 
 
 def _specs(m: int, c: int, itemsize: int):
-    """(grid, row-block spec, broadcast (1,C) spec, partial-sum spec,
-    tile) shared by the forward and backward pallas_calls."""
+    """(grid, row-block spec, whole-(1,C)-vector spec, tile) shared by
+    the forward and backward pallas_calls."""
     tile = _tile_rows(m, c, itemsize)
     grid = (pl.cdiv(m, tile),)
     row = pl.BlockSpec((tile, c), lambda i: (i, 0),
                        memory_space=pltpu.VMEM)
     vec = pl.BlockSpec((1, c), lambda i: (0, 0), memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, c), lambda i: (i, 0),
-                        memory_space=pltpu.VMEM)
-    return grid, row, vec, part, tile
+    return grid, row, vec, tile
 
 
 # -- custom_vjp wrappers (2-D view; reshape happens in scale_bias_act) ----
@@ -132,7 +153,7 @@ def _fused(x, scale, bias, relu, out_dtype):
 
 def _fused_fwd(x, scale, bias, relu, out_dtype):
     m, c = x.shape
-    grid, row, vec, _part, _tile = _specs(m, c, x.dtype.itemsize)
+    grid, row, vec, _tile = _specs(m, c, x.dtype.itemsize)
     out_row = pl.BlockSpec(row.block_shape, lambda i: (i, 0),
                            memory_space=pltpu.VMEM)
     y = pl.pallas_call(
@@ -141,7 +162,7 @@ def _fused_fwd(x, scale, bias, relu, out_dtype):
         in_specs=[row, vec, vec],
         out_specs=out_row,
         out_shape=jax.ShapeDtypeStruct((m, c), out_dtype),
-        interpret=_auto_interpret(),
+        interpret=interpret(),
     )(x, scale.reshape(1, c), bias.reshape(1, c))
     return y, (x, scale, bias)
 
@@ -149,22 +170,21 @@ def _fused_fwd(x, scale, bias, relu, out_dtype):
 def _fused_bwd(relu, out_dtype, saved, g):
     x, scale, bias = saved
     m, c = x.shape
-    grid, row, vec, part, tile = _specs(m, c, x.dtype.itemsize)
-    n_blocks = grid[0]
-    dx, ds_p, db_p = pl.pallas_call(
+    grid, row, vec, tile = _specs(m, c, x.dtype.itemsize)
+    dx, ds, db = pl.pallas_call(
         functools.partial(_bwd_kernel, relu=relu, m_rows=m, tile=tile),
         grid=grid,
         in_specs=[row, vec, vec, row],
-        out_specs=[row, part, part],
+        out_specs=[row, vec, vec],
         out_shape=[
             jax.ShapeDtypeStruct((m, c), x.dtype),
-            jax.ShapeDtypeStruct((n_blocks, c), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, c), jnp.float32),
+            jax.ShapeDtypeStruct((1, c), jnp.float32),
+            jax.ShapeDtypeStruct((1, c), jnp.float32),
         ],
-        interpret=_auto_interpret(),
+        interpret=interpret(),
     )(x, scale.reshape(1, c), bias.reshape(1, c), g)
-    return (dx, ds_p.sum(0).astype(scale.dtype),
-            db_p.sum(0).astype(bias.dtype))
+    return (dx, ds.reshape(c).astype(scale.dtype),
+            db.reshape(c).astype(bias.dtype))
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
@@ -178,7 +198,7 @@ def _fused_res(x, scale, bias, res, relu, out_dtype):
 
 def _fused_res_fwd(x, scale, bias, res, relu, out_dtype):
     m, c = x.shape
-    grid, row, vec, _part, _tile = _specs(m, c, x.dtype.itemsize)
+    grid, row, vec, _tile = _specs(m, c, x.dtype.itemsize)
     y = pl.pallas_call(
         functools.partial(_fwd_res_kernel, relu=relu),
         grid=grid,
@@ -186,7 +206,7 @@ def _fused_res_fwd(x, scale, bias, res, relu, out_dtype):
         out_specs=pl.BlockSpec(row.block_shape, lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, c), out_dtype),
-        interpret=_auto_interpret(),
+        interpret=interpret(),
     )(x, scale.reshape(1, c), bias.reshape(1, c), res)
     return y, (x, scale, bias, res)
 
@@ -194,24 +214,23 @@ def _fused_res_fwd(x, scale, bias, res, relu, out_dtype):
 def _fused_res_bwd(relu, out_dtype, saved, g):
     x, scale, bias, res = saved
     m, c = x.shape
-    grid, row, vec, part, tile = _specs(m, c, x.dtype.itemsize)
-    n_blocks = grid[0]
-    dx, dr, ds_p, db_p = pl.pallas_call(
+    grid, row, vec, tile = _specs(m, c, x.dtype.itemsize)
+    dx, dr, ds, db = pl.pallas_call(
         functools.partial(_bwd_res_kernel, relu=relu, m_rows=m,
                           tile=tile),
         grid=grid,
         in_specs=[row, vec, vec, row, row],
-        out_specs=[row, row, part, part],
+        out_specs=[row, row, vec, vec],
         out_shape=[
             jax.ShapeDtypeStruct((m, c), x.dtype),
             jax.ShapeDtypeStruct((m, c), res.dtype),
-            jax.ShapeDtypeStruct((n_blocks, c), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, c), jnp.float32),
+            jax.ShapeDtypeStruct((1, c), jnp.float32),
+            jax.ShapeDtypeStruct((1, c), jnp.float32),
         ],
-        interpret=_auto_interpret(),
+        interpret=interpret(),
     )(x, scale.reshape(1, c), bias.reshape(1, c), res, g)
-    return (dx, ds_p.sum(0).astype(scale.dtype),
-            db_p.sum(0).astype(bias.dtype), dr)
+    return (dx, ds.reshape(c).astype(scale.dtype),
+            db.reshape(c).astype(bias.dtype), dr)
 
 
 _fused_res.defvjp(_fused_res_fwd, _fused_res_bwd)
@@ -228,7 +247,7 @@ def scale_bias_act(x: jax.Array, scale: jax.Array, bias: jax.Array,
     ``scale``/``bias`` are per-channel vectors (the folded BN affine or
     a conv bias with ``scale=ones``); ``residual`` must match ``x``'s
     shape.  ``impl='pallas'`` runs the fused single-stream kernel
-    (interpret mode off-TPU); ``impl='xla'`` is the plain jnp fallback
+    (interpret mode on the CPU platform); ``impl='xla'`` is the plain jnp form
     the kernel is oracle-tested against.  Math is f32 either way; the
     result is cast to ``out_dtype`` (default: ``x.dtype``).
     """

@@ -5,8 +5,11 @@ The reference got LRN from cuDNN via Theano's dnn ops (layer library
 file:line).  On TPU there is no library kernel to call; two impls:
 a composed-XLA form (shift-and-add over the channel axis, fused by
 the compiler) and a Pallas VMEM-tiled kernel with an analytic VJP
-(ops/lrn_pallas.py), which microbenchmarks ~1.2-1.5x faster fwd+bwd
-on the v5e chip and is the TPU default.
+(ops/lrn_pallas.py), which is the TPU default: it microbenchmarked
+~1.2-1.5x faster fwd+bwd on a v5e under the older stack (JAX 0.4.x;
+not re-timed on the installed one), and compiles and matches the XLA
+form at AlexNet's two LRN shapes under JAX 0.9.0 / libtpu 0.0.34
+(PR 21; chip_smoke.py repeats that check).
 
 y = x / (k + alpha/n * sum_{j in window(n)} x_j^2)^beta
 (matching cuDNN/Caffe LRN, where alpha is divided by the window size;
@@ -42,37 +45,6 @@ def window_sum(v: jax.Array, n: int, adjoint: bool = False) -> jax.Array:
     return win
 
 
-_PALLAS_OK: bool | None = None  # lazily probed once per process
-
-
-def _pallas_available() -> bool:
-    """One-time probe: compile+run the Pallas kernel on a tiny input.
-
-    'auto' was validated on v5e only; other TPU generations could hit a
-    Mosaic lowering regression that would otherwise surface mid-train.
-    A failed probe falls back to the composed-XLA impl (which lowers
-    everywhere) and warns once.  Explicit ``impl='pallas'`` skips the
-    probe so real errors stay loud.
-    """
-    global _PALLAS_OK
-    if _PALLAS_OK is None:
-        try:
-            from theanompi_tpu.ops.lrn_pallas import lrn_pallas
-
-            x = jnp.ones((1, 8, 8, 16), jnp.float32)
-            jax.block_until_ready(lrn_pallas(x, 5, 2.0, 1e-4, 0.75, True))
-            _PALLAS_OK = True
-        except Exception as e:  # lowering/compile failure on this backend
-            import warnings
-
-            warnings.warn(
-                f"Pallas LRN unavailable on this backend ({e!r}); "
-                "falling back to the composed-XLA impl. Set "
-                "THEANOMPI_TPU_LRN_IMPL=pallas to force (and see the error).")
-            _PALLAS_OK = False
-    return _PALLAS_OK
-
-
 def lrn(
     x: jax.Array,
     n: int = 5,
@@ -88,17 +60,18 @@ def lrn(
     ``impl``: 'auto' (default), 'xla' (composed ops, fused by the
     compiler) or 'pallas' (VMEM-tiled kernel with analytic VJP,
     ops/lrn_pallas.py); default from the ``THEANOMPI_TPU_LRN_IMPL``
-    env var.  'auto' picks pallas on TPU — measured on the v5e chip
-    (tools/bench_lrn.py, batch 64): fwd+bwd 4.35→2.94 ms at
-    (55,55,96) and 2.41→1.96 ms at (27,27,256) vs the composed form —
-    and xla elsewhere (interpret-mode pallas is test-only).
+    env var.  'auto' picks pallas on TPU and xla elsewhere
+    (interpret-mode pallas is for tests on the CPU platform).  There
+    is no compile probe and no fallback: a refused kernel raises.
     """
     if x.ndim != 4:
         raise ValueError(f"lrn expects NHWC, got shape {x.shape}")
     impl = impl or os.environ.get("THEANOMPI_TPU_LRN_IMPL", "auto")
     if impl == "auto":
-        impl = ("pallas" if jax.default_backend() == "tpu"
-                and _pallas_available() else "xla")
+        # no probe, no fallback: a kernel the compiler refuses is a
+        # loud error on the chip (chip_smoke.py compiles it at the
+        # zoo's shapes), never a silent switch to the other form
+        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
     if impl == "pallas":
         from theanompi_tpu.ops.lrn_pallas import lrn_pallas
 

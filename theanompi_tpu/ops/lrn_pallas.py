@@ -1,8 +1,7 @@
 """Pallas TPU kernel for cross-channel LRN (forward + custom VJP).
 
-This is the TPU default for ``ops.lrn`` (it microbenchmarked ~1.2-1.5x
-faster fwd+bwd than the XLA-composed form on the v5e chip — see
-tools/bench_lrn.py).  It tiles the flattened (N*H*W, C) view into VMEM
+This is the TPU default for ``ops.lrn`` (see ops/lrn.py for its
+on-chip record; tools/bench_lrn.py times it).  It tiles the flattened (N*H*W, C) view into VMEM
 blocks, computes the windowed squared-sum on the VPU in one pass, and
 backs it with an analytic VJP so the backward pass reuses the same
 kernel shape instead of differentiating through the shift-and-add
@@ -11,9 +10,9 @@ chain (W^T is the adjoint window — equal to W for odd n):
     y  = x * s^{-beta},            s = k + a * W(x^2)
     dx = g * s^{-beta} - 2*a*beta * x * W^T(g * x * s^{-beta-1})
 
-Falls back to interpret mode off-TPU so the numerics are unit-testable
-on the CPU mesh.  Select explicitly with ``ops.lrn(..., impl=...)`` or
-the ``THEANOMPI_TPU_LRN_IMPL`` env var.
+Runs in interpret mode on the CPU platform (ops/pallas_mode.py) so the
+numerics are unit-testable on the CPU mesh.  Select explicitly with
+``ops.lrn(..., impl=...)`` or the ``THEANOMPI_TPU_LRN_IMPL`` env var.
 """
 
 from __future__ import annotations
@@ -25,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from theanompi_tpu.ops.lrn import window_sum as _window_sum
+from theanompi_tpu.ops.pallas_mode import interpret
 
 # rows of the flattened (pixels, channels) view per VMEM block; with
 # C<=512 fp32 this stays well under the ~16MB VMEM budget
@@ -46,8 +46,7 @@ def _bwd_kernel(x_ref, g_ref, dx_ref, *, n, k, a, beta):
         g * x * s_mb1, n, adjoint=True)
 
 
-def _blocked_call(kernel, n_in: int, m: int, c: int, dtype,
-                  interpret: bool):
+def _blocked_call(kernel, n_in: int, m: int, c: int, dtype):
     tile = min(TILE_M, m)
     grid = (pl.cdiv(m, tile),)
     spec = pl.BlockSpec((tile, c), lambda i: (i, 0),
@@ -58,12 +57,8 @@ def _blocked_call(kernel, n_in: int, m: int, c: int, dtype,
         in_specs=[spec] * n_in,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((m, c), dtype),
-        interpret=interpret,
+        interpret=interpret(),
     )
-
-
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
@@ -83,7 +78,7 @@ def _lrn_fwd(x, n, k, alpha, beta, alpha_scaled_by_n):
     m = b * h * w
     flat = x.reshape(m, c)
     kern = functools.partial(_fwd_kernel, n=n, k=k, a=a, beta=beta)
-    y = _blocked_call(kern, 1, m, c, x.dtype, _auto_interpret())(flat)
+    y = _blocked_call(kern, 1, m, c, x.dtype)(flat)
     return y.reshape(x.shape), x
 
 
@@ -92,7 +87,7 @@ def _lrn_bwd(n, k, alpha, beta, alpha_scaled_by_n, x, g):
     b, h, w, c = x.shape
     m = b * h * w
     kern = functools.partial(_bwd_kernel, n=n, k=k, a=a, beta=beta)
-    dx = _blocked_call(kern, 2, m, c, x.dtype, _auto_interpret())(
+    dx = _blocked_call(kern, 2, m, c, x.dtype)(
         x.reshape(m, c), g.reshape(m, c))
     return (dx.reshape(x.shape),)
 

@@ -678,9 +678,13 @@ class ShardProcessGroup:
         cmd = [sys.executable, "-m", "theanompi_tpu.parallel.shards",
                "--host", host, "--port", str(port),
                "--shard-index", str(index)]
+        env = dict(os.environ)
         if self.platform:
-            cmd += ["--platform", self.platform]
-        return subprocess.Popen(cmd, env=dict(os.environ))
+            # a shard does host arithmetic and must never claim the
+            # trainer's chip: the variable (not an inherited 'tpu')
+            # decides its platform, like the collector's child
+            env["JAX_PLATFORMS"] = self.platform
+        return subprocess.Popen(cmd, env=env)
 
     def _wait_ready(self, timeout_s: float) -> None:
         deadline = time.monotonic() + timeout_s

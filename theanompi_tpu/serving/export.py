@@ -5,7 +5,7 @@ Training-side state (``TrainState``: params + optimizer state + batch
 stats) is NOT what serving loads — the optimizer state is dead weight
 and the module must run its EVAL path (``train=False``:
 ``BatchNormAct``/``BatchNorm`` switch to running statistics, dropout
-off), with the model's ``bn_act_impl``/``pool_impl`` threading intact
+off), with the model's ``bn_act_impl`` threading intact
 so a recipe benched with the fused epilogue serves with it too.
 
 An export is a directory of numbered versions written through the same
@@ -389,7 +389,7 @@ def load_export(export_dir: str, version: int | None = None,
 
 def build_model_from_meta(meta: dict, mesh=None):
     """Reconstruct the exported model (module + config threading —
-    ``bn_act_impl``, ``pool_impl``, dtypes) around restored arrays.
+    ``bn_act_impl``, dtypes) around restored arrays.
     JSON round-trips ModelConfig's tuple fields as lists; they are
     re-tupled here so the rebuilt config equals the exporter's."""
     from theanompi_tpu.models.base import ModelConfig

@@ -813,7 +813,7 @@ def serve_main(export_dir: str, host: str = "0.0.0.0",
     # persistent compilation cache before any replica warms up: the
     # per-bucket eval programs compile once per (shape, flags) EVER,
     # not once per server restart — a hot-standby restart re-serves in
-    # deserialization time (no flag/env -> no-op)
+    # deserialization time
     from theanompi_tpu.utils.helper_funcs import enable_compilation_cache
 
     enable_compilation_cache()
@@ -917,22 +917,11 @@ def main(argv=None) -> int:
                          "SERVING.md 'Fleet prefix cache'")
     ap.add_argument("--platform", default=None,
                     help="jax platform (e.g. 'cpu')")
-    ap.add_argument("--compilation-cache-dir", default=None, metavar="DIR",
-                    help="persistent XLA compilation cache: warmup "
-                         "deserializes the per-bucket eval programs "
-                         "instead of recompiling on every server "
-                         "restart (also honors "
-                         "THEANOMPI_TPU_COMPILATION_CACHE)")
     args = ap.parse_args(argv)
     if args.platform:
         import jax
 
         jax.config.update("jax_platforms", args.platform)
-    if args.compilation_cache_dir:
-        import os
-
-        os.environ["THEANOMPI_TPU_COMPILATION_CACHE"] = \
-            args.compilation_cache_dir
     buckets = (tuple(int(b) for b in args.buckets.split(","))
                if args.buckets else None)
     decode_opts = decode_opts_from_args(args)

@@ -39,7 +39,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-import _bootstrap  # noqa: F401,E402  (tools/ sibling; pins JAX_PLATFORMS)
 
 import numpy as np  # noqa: E402
 
@@ -308,7 +307,7 @@ def run_sharded(args) -> int:
     improvement = 1.0 - kk["wall_ms_mean"] / k1["wall_ms_mean"]
     out_doc = {
         "bench": "shard_exchange",
-        "backend": "cpu",
+        "backend": jax.default_backend(),
         "n_params": n_params,
         "n_leaves": len(tree),
         "tree_mb_f32": round(tree_nbytes(tree) / 1e6, 2),
@@ -619,7 +618,7 @@ def run_shm_compare(args) -> int:
         leg.pop("digests", None)
     out_doc = {
         "bench": "shm_lane",
-        "backend": "cpu",
+        "backend": jax.default_backend(),
         "n_params": n_params,
         "n_leaves": len(tree),
         "tree_mb_f32": round(tree_nbytes(tree) / 1e6, 2),
@@ -990,7 +989,7 @@ def run_hierarchy(args) -> int:
                if m["shards"] == k and m["local_workers"] == n_workers)
     out_doc = {
         "bench": "hierarchical_exchange",
-        "backend": "cpu",
+        "backend": jax.default_backend(),
         "n_params": n_params,
         "n_leaves": len(base),
         "tree_mb_f32": round(tree_nbytes(base) / 1e6, 2),
@@ -1119,8 +1118,8 @@ def run_buckets(args) -> int:
     from the shared plan every rank derives), and wall/exchange; plus
     the aggregate wall delta vs B=1 per dtype.  CPU walls bound the
     host-visible overhead of splitting the exchange, NOT the ICI
-    overlap win — that is what the queued on-chip profile pair grades
-    (artifacts/queue_xla_sweep_exps.json).
+    overlap win — that needs a profile pair on a four-chip host
+    (ROADMAP A3).
 
     ``--smoke`` is the preflight gate: sweeps only {1, B}, asserts the
     B=4-vs-B=1 train-step bit-identity and the bucket-count gauge in
@@ -1225,7 +1224,7 @@ def run_buckets(args) -> int:
             if m["dtype"] == dtype and m["buckets"] != 1}
     out_doc = {
         "bench": "bucketed_exchange",
-        "backend": "cpu",
+        "backend": jax.default_backend(),
         "mesh_devices": 8,
         "n_params": n_params,
         "n_leaves": len(tree),

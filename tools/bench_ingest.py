@@ -41,7 +41,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-import _bootstrap  # noqa: F401,E402  (tools/ sibling; pins JAX_PLATFORMS)
 
 import numpy as np  # noqa: E402
 
@@ -344,7 +343,8 @@ def run_shm_compare(args) -> int:
     with monitor.session():
         doc = shm_compare_leg(n_samples, args.store, args.shard_size,
                               batch, args.depth)
-    out_doc = {"bench": "ingest_shm_lane", "backend": "cpu", **doc}
+    out_doc = {"bench": "ingest_shm_lane",
+               "backend": jax.default_backend(), **doc}
     tag = args.tag or "ingest_shm"
     path = args.out or os.path.join(REPO, "artifacts",
                                     f"BENCH_{tag}.json")
@@ -504,7 +504,7 @@ def main(argv=None) -> int:
                if nk is not n1 else 1.0)
     out_doc = {
         "bench": "ingest_fleet",
-        "backend": "cpu",
+        "backend": jax.default_backend(),
         "n_samples": dataset.n_train,
         "store_px": args.store,
         "batch": args.batch,

@@ -58,7 +58,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-import _bootstrap  # noqa: F401,E402  (tools/ sibling; pins JAX_PLATFORMS)
 
 import numpy as np  # noqa: E402
 

@@ -88,7 +88,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import _bootstrap  # noqa: F401,E402  (makes JAX_PLATFORMS effective)
 import numpy as np  # noqa: E402
 
 
@@ -1271,7 +1270,10 @@ def shm_compare_main(args) -> int:
     with tempfile.TemporaryDirectory() as td:
         with monitor.session(os.path.join(td, "monitor")):
             doc = shm_compare_leg(td)
-    out_doc = {"bench": "serving_shm_lane", **doc}
+    import jax
+
+    out_doc = {"bench": "serving_shm_lane",
+               "backend": jax.default_backend(), **doc}
     path = (args.out if args.out != "BENCH_serving.json"
             else os.path.join(repo, "artifacts",
                               "BENCH_serving_shm.json"))

@@ -28,8 +28,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-import _bootstrap  # noqa: F401,E402  (makes JAX_PLATFORMS effective)
-
 
 def resnet50_convs(batch: int, stem: str = "conv7",
                    stage_sizes=(3, 4, 6, 3), width: int = 64):

@@ -2,7 +2,7 @@
 """Repo entry point for the static checker suite (docs/ANALYSIS.md).
 
 Loads ``theanompi_tpu.analysis`` WITHOUT executing the package
-``__init__`` (which imports jax via compat): a stub parent module with
+``__init__`` (whatever it grows to import): a stub parent module with
 ``__path__`` pointing at the real package directory is installed
 first, so the subpackage resolves from the filesystem while the
 parent's body never runs.  The gate is therefore pure stdlib end to
