@@ -157,7 +157,8 @@ def _run_period(ports, payloads):
     for t in ths:
         t.start()
     for t in ths:
-        t.join()
+        t.join(120)
+    assert not any(t.is_alive() for t in ths), "an exchange never returned"
     assert all(e is None for e in errs), errs
     return outs
 
@@ -197,7 +198,9 @@ class TestLocalAggregator:
         for t in ths:
             t.start()
         for t in ths:
-            t.join()
+            t.join(120)
+        assert not any(t.is_alive() for t in ths), \
+            "a push_pull never returned"
         center = jax.device_get(srv.get_center())
         for out in outs:
             assert_tree_bytes_equal(out, center, "fanned-out center")
@@ -265,13 +268,13 @@ class TestAggregatorFaultMatrix:
         started = threading.Barrier(4)
 
         def run(i):
-            started.wait()
+            started.wait(60)
             outs[i] = ports[i].exchange(workers[i])
 
         ths = [threading.Thread(target=run, args=(i,)) for i in range(3)]
         for t in ths:
             t.start()
-        started.wait()  # all three are inside exchange (or about to be)
+        started.wait(60)  # all three are inside exchange (or about to be)
         agg.kill("fault-matrix kill")
         for t in ths:
             t.join(timeout=30)
@@ -336,7 +339,9 @@ class TestAggregatorFaultMatrix:
         for t in ths:
             t.start()
         for t in ths:
-            t.join()
+            t.join(120)
+        assert not any(t.is_alive() for t in ths), \
+            "a failed-over exchange never returned"
         assert len(errs) == 2  # both workers of the period failed over
         assert all(o is not None for o in outs)
         # next period succeeds (the failure was one period's, not a
@@ -348,7 +353,7 @@ class TestAggregatorFaultMatrix:
         for t in ths:
             t.start()
         for t in ths:
-            t.join()
+            t.join(120)
         assert all(o is not None for o in outs)
 
     def test_kill_restart_racing_inflight_aggregate_never_wedges(self):

@@ -376,6 +376,101 @@ def test_leak_detector_sees_a_leak_and_clears():
 
 
 # ---------------------------------------------------------------------------
+# The per-phase alarm
+# ---------------------------------------------------------------------------
+
+
+def test_phase_limit_fails_an_unbounded_wait(request, monkeypatch):
+    """A wait with no limit of its own FAILS when the phase's alarm
+    rings (and keeps failing while the unwinding waits again); once the
+    phase is over the alarm is off and the handler restored."""
+    import signal
+
+    import conftest
+
+    monkeypatch.setattr(conftest, "_PHASE_LIMIT_S", 0.2)
+    outer = signal.getsignal(signal.SIGALRM)
+    phase = conftest._phase_limit(request.node, "call")
+    next(phase)
+    try:
+        with pytest.raises(pytest.fail.Exception,
+                           match="call still running after 0 s"):
+            threading.Event().wait()
+    finally:
+        phase.close()
+    assert signal.getsignal(signal.SIGALRM) is outer
+    assert signal.getitimer(signal.ITIMER_REAL) == (0.0, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# Shm-segment-leak fixture
+# ---------------------------------------------------------------------------
+
+#: creates one lane-named segment under its own pid, says so, and lives
+#: until its stdin closes (or 60 s, so a lost test leaves nothing)
+_SEGMENT_HOLDER = """
+import os, select, sys
+name = f"tmshm_{os.getpid()}_foreign_1"
+with open(os.path.join("/dev/shm", name), "wb") as f:
+    f.write(b"x" * 64)
+print(name, flush=True)
+select.select([sys.stdin], [], [], 60)
+"""
+
+
+def test_segment_guard_judges_only_its_own_process_tree():
+    """Six xdist workers share one /dev/shm: a live segment of ANOTHER
+    process tree is neither reported nor unlinked by this process's
+    fence, while this process's own and a spawned child's are."""
+    import select
+    import subprocess
+    import sys
+
+    import conftest
+    from theanompi_tpu.parallel import shm
+
+    def spawn(code):
+        p = subprocess.Popen([sys.executable, "-c", code],
+                             stdin=subprocess.PIPE,
+                             stdout=subprocess.PIPE, text=True)
+        assert select.select([p.stdout], [], [], 30)[0], \
+            "segment holder did not report its segment within 30 s"
+        return p, p.stdout.readline().strip()
+
+    before = set(shm.segment_names())
+    # an unrelated live process: the grandchild of a parent that exits
+    # at once, so its ancestry no longer leads here
+    launcher, foreign = spawn(
+        "import subprocess, sys\n"
+        f"subprocess.Popen([sys.executable, '-c', {_SEGMENT_HOLDER!r}])")
+    child, childs = spawn(_SEGMENT_HOLDER)
+    own = f"tmshm_{os.getpid()}_own_1"
+    try:
+        assert launcher.wait(30) == 0
+        with open(os.path.join("/dev/shm", own), "wb") as f:
+            f.write(b"x" * 64)
+        assert foreign in shm.segment_names()
+        leaked = conftest.leaked_segments(before, grace_s=0.2)
+        assert sorted(leaked) == sorted([own, childs])
+        assert foreign in shm.segment_names()  # judged AND left alone
+    finally:
+        for p in (launcher, child):  # stdin closes: the holders exit
+            p.stdin.close()
+            p.stdout.close()
+        child.wait(30)
+        os.unlink(os.path.join("/dev/shm", own))
+        # the foreign holder is nobody's child now: once it is dead
+        # its segment is an orphan and the sweep takes it
+        deadline = time.monotonic() + 30
+        while (foreign in shm.segment_names()
+               and time.monotonic() < deadline):
+            shm.sweep_orphans()
+            time.sleep(0.05)
+    assert conftest.leaked_segments(before, grace_s=0.2) == []
+    assert foreign not in shm.segment_names()
+
+
+# ---------------------------------------------------------------------------
 # The repo gate itself
 # ---------------------------------------------------------------------------
 

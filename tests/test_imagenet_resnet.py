@@ -239,11 +239,11 @@ class TestSyncBN:
         plain = ResNet(**kw)
         sync = ResNet(**kw, bn_axis="data")
         x = jax.random.normal(jax.random.key(0), (32, 32, 32, 3))
-        variables = plain.init({"params": jax.random.key(1)}, x[:2],
-                               train=True)
+        variables = jax.jit(lambda x: plain.init(
+            {"params": jax.random.key(1)}, x, train=True))(x[:2])
 
-        logits_ref, upd_ref = plain.apply(
-            variables, x, train=True, mutable=["batch_stats"])
+        logits_ref, upd_ref = jax.jit(lambda v, x: plain.apply(
+            v, x, train=True, mutable=["batch_stats"]))(variables, x)
 
         def shard_fwd(variables, xs):
             logits, upd = sync.apply(variables, xs, train=True,
@@ -276,10 +276,10 @@ class TestSyncBN:
         plain = ResNet(stage_sizes=(1,), width=8, n_classes=4,
                        dtype=jnp.float32)
         x = jax.random.normal(jax.random.key(0), (32, 32, 32, 3))
-        variables = plain.init({"params": jax.random.key(1)}, x[:2],
-                               train=True)
-        _, upd_ref = plain.apply(variables, x, train=True,
-                                 mutable=["batch_stats"])
+        variables = jax.jit(lambda x: plain.init(
+            {"params": jax.random.key(1)}, x, train=True))(x[:2])
+        _, upd_ref = jax.jit(lambda v, x: plain.apply(
+            v, x, train=True, mutable=["batch_stats"]))(variables, x)
 
         def shard_fwd(variables, xs):
             _, upd = plain.apply(variables, xs, train=True,

@@ -485,7 +485,9 @@ class TestAssignRace:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(120)
+        assert not any(t.is_alive() for t in threads), \
+            "an assignment never returned"
         reader.shutdown()
         assert not errs, errs
 

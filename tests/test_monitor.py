@@ -108,7 +108,7 @@ def test_registry_thread_safety():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(60)
     assert r.value("n") == 8000
     assert r.write_count == 8000
 
@@ -191,7 +191,7 @@ def test_open_spans_visible_across_threads():
         assert "worker-phase" in names
     finally:
         release.set()
-        t.join()
+        t.join(30)
     assert "worker-phase" not in [s["name"] for s in open_spans()]
 
 
@@ -396,7 +396,7 @@ def test_postmortem_on_injected_exception(tmp_path):
                     raise RuntimeError("injected failure")
     finally:
         release.set()
-        t.join()
+        t.join(30)
     pm = json.load(open(tmp_path / "postmortem_rank0.json"))
     assert pm["exception"]["type"] == "RuntimeError"
     assert "injected failure" in pm["exception"]["message"]

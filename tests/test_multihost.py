@@ -180,7 +180,7 @@ def test_tmlauncher_cli_two_processes(workdir):
         for p in procs:  # a failed host-0 assert must not orphan host 1
             if p.poll() is None:
                 p.kill()
-                p.wait()
+                p.wait(30)
     p1 = run_cli(1, 0, 45728, 8, snap1)
     out1, _ = p1.communicate(timeout=600)
     assert p1.returncode == 0, out1.decode()[-4000:]

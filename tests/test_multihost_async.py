@@ -66,7 +66,7 @@ def tmserver(monkeypatch):
             time.sleep(0.3)
     yield addr
     proc.kill()
-    proc.wait()
+    proc.wait(30)
 
 
 def _worker_group(addr, session, rank_offset, tmp_path, tag,
@@ -117,7 +117,7 @@ def test_gosgd_two_worker_groups_one_service(tmp_path, tmserver):
         for p in (pa, pb):  # a failed assert must not orphan a trainer
             if p.poll() is None:
                 p.kill()
-                p.wait()
+                p.wait(30)
     ra = json.load(open(outa))
     rb = json.load(open(outb))
     # This test owns the DEPLOYMENT invariants.  It deliberately does
@@ -188,4 +188,4 @@ def test_displaced_session_fails_fast_across_processes(tmp_path, tmserver):
         for p in (pa, pb):
             if p is not None and p.poll() is None:
                 p.kill()
-                p.wait()
+                p.wait(30)

@@ -53,7 +53,8 @@ def test_show_record_tool(tmp_path):
     out = subprocess.run(
         [sys.executable, os.path.join("tools", "show_record.py"),
          str(tmp_path)],
-        capture_output=True, text=True, cwd=os.path.dirname(
+        capture_output=True, text=True, timeout=120,
+        cwd=os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))
     assert out.returncode == 0, out.stderr
     assert "images/sec" in out.stdout and "train_loss" in out.stdout

@@ -525,6 +525,37 @@ class TestServiceResilience:
             _shutdown_service(port)
             t.join(timeout=5)
 
+    def test_dropped_init_of_a_new_session_displaces_the_old(
+            self, service_env):
+        """A session-creating init whose first send is lost is SENT
+        AGAIN, not preceded by a rejoin: the service still holds the
+        session this init displaces, and a rejoin under the new id was
+        refused as `SessionDisplaced ... by <the old session>` (PR 25:
+        how test_shards' sharded ASGD resume failed whenever another
+        xdist worker's leak fence unlinked the shm segment its init
+        rode on)."""
+        from theanompi_tpu.parallel.service import RemoteASGD
+
+        port = _free_port()
+        t, stop = _start_service(port)
+        try:
+            addr = f"127.0.0.1:{port}"
+            params = {"w": np.ones((3,), np.float32)}
+            opt = dict(learning_rate=0.1, optimizer="sgd")
+            old = RemoteASGD(addr, params, opt, session_id="old")
+            faults.install([{"site": "service_call", "op": "asgd_init",
+                             "action": "drop"}])
+            new = RemoteASGD(addr, params, opt, session_id="new")
+            np.testing.assert_allclose(new.get_center()["w"], 1.0)
+            with pytest.raises(Exception, match="displaced"):
+                old.get_center()
+            old.close()
+            new.close()
+        finally:
+            stop.set()
+            _shutdown_service(port)
+            t.join(timeout=5)
+
     def test_client_survives_server_restart(self, service_env, rpc_loop):
         """Acceptance-criteria case: a ServiceClient reconnects
         through a full parameter-service restart (new process-worth of

@@ -149,6 +149,24 @@ class TestBothLoops:
         finally:
             silent.close()
 
+    def test_client_handshake_has_the_deadline_too(self, monkeypatch):
+        """A connect the kernel completed but nobody greets (a listener
+        closing during the three-way handshake leaves exactly that: the
+        client ESTABLISHED, no peer, no RST) fails at the deadline as a
+        ``ConnectionError``, so a retry loop sees it like any refused
+        connect.  (PR 25: the stdlib client waited for ever, and a
+        test's `shutdown` poke after `stop.set()` hung a tier-1 worker
+        about once in 40 000 connects.)"""
+        monkeypatch.setenv("THEANOMPI_TPU_RPC_HANDSHAKE_TIMEOUT_S",
+                           "0.5")
+        with socket.socket() as mute:  # listens, never accepts
+            mute.bind(("127.0.0.1", 0))
+            mute.listen(1)
+            t0 = time.monotonic()
+            with pytest.raises(rpc.HandshakeTimeout):
+                rpc.connect(mute.getsockname(), b"k")
+            assert time.monotonic() - t0 < 5
+
     def test_abrupt_disconnect_sweeps_clients_gauge(
             self, echo_server, tmp_path):
         """ISSUE 11 satellite: an RST mid-frame must run the same
@@ -243,6 +261,24 @@ class TestSelectorOnly:
             pass
         t.join(timeout=10)
         assert not t.is_alive()
+
+    def test_peer_handshaken_during_shutdown_sees_eof(self):
+        """A peer whose handshake ends after the loop's last turn is
+        CLOSED by the shutdown, as is one that ends later still: left
+        open and unregistered, it waits for ever for the reply to its
+        hello (PR 25: a test's own `shutdown` client, connecting just
+        after `stop.set()`, hung a tier-1 worker this way)."""
+        srv = rpc._SelectorServer(EchoService(), "127.0.0.1", 0,
+                                  threading.Event(), b"k",
+                                  rpc.RpcHooks(), 2)
+        ours, theirs = zip(socket.socketpair(), socket.socketpair())
+        srv.register_ready(ours[0])  # handshaken, never registered
+        srv._shutdown()
+        srv.register_ready(ours[1])  # outlived the pools' join
+        for peer in theirs:
+            with peer:
+                peer.settimeout(5)
+                assert peer.recv(1) == b""
 
     def test_mux_streams_share_one_socket(self, server):
         addr, svc = server

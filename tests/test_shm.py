@@ -634,8 +634,7 @@ def test_prefill_to_decode_pages_over_lane(shm_env, tmp_path,
     """The KV plane: prefill exports pages, the client receives them
     over the lane BYTE-identically, and the decode server adopts them
     into a stream equal to the uncached full-forward oracle."""
-    import jax.numpy as jnp
-
+    from tests._decode_helpers import flax_greedy
     from theanompi_tpu.frontdoor import PrefillClient, PrefillServer
     from theanompi_tpu.frontdoor import prefill as prefill_mod
     from theanompi_tpu.models.base import ModelConfig
@@ -700,15 +699,7 @@ def test_prefill_to_decode_pages_over_lane(shm_env, tmp_path,
                 toks = dc.adopt(man, k, v, 6)
             finally:
                 dc.close()
-            cur, expect = [int(t) for t in prompt], []
-            for _ in range(6):
-                logits = np.asarray(model.module.apply(
-                    {"params": params}, jnp.asarray([cur], jnp.int32),
-                    train=False, seq_axis=None))
-                tok = int(np.argmax(logits[0, -1]))
-                expect.append(tok)
-                cur.append(tok)
-            assert list(toks) == expect
+            assert list(toks) == flax_greedy(model, params, prompt, 6)
             reg = monitor.registry()
             assert (reg.value("shm/oob_bytes_total", dir="recv")
                     or 0) > 0

@@ -564,8 +564,11 @@ def optax_sgd_momentum():
 
 def test_zero_bucketed_collectives_in_lowering(mesh8):
     """Structural pin: the f32 bucketed step lowers to exactly B
-    reduce-scatters (one per bucket), interleaved with backward
-    compute — not one whole-vector scatter after the full backward."""
+    reduce-scatters (one per bucket), the first of which does not
+    depend on the whole backward — not one whole-vector scatter that
+    every backward dot feeds.  (Dependence, not the order of lines:
+    tests/_hlo_dataflow.py.)"""
+    from tests._hlo_dataflow import ancestors
     from theanompi_tpu.utils.helper_funcs import build_optimizer
 
     tx = build_optimizer(0.05, optimizer="sgd", momentum=0.9,
@@ -590,20 +593,25 @@ def test_zero_bucketed_collectives_in_lowering(mesh8):
                 if "stablehlo.dot_general" in l]
         return rs, dots
 
-    rs1, dots1 = layout(lowered(1))
+    txt1 = lowered(1)
+    rs1, dots1 = layout(txt1)
     assert len(rs1) == 1
     assert not [d for d in dots1 if d > rs1[0]], \
         "B=1 has backward compute after the scatter"
+    assert set(dots1) <= ancestors(txt1, rs1[0]), \
+        "B=1: a backward dot does not feed the one scatter"
     # _params() has 3 leaves, so B=4 clamps to 3 per-leaf buckets —
     # assert against the plan's own bucket count
     from theanompi_tpu.parallel.zero import _zero_layout
 
     for B in (2, 4):
         n_buckets = len(_zero_layout(params, 8, B).ranges)
-        rsB, dotsB = layout(lowered(B))
+        txtB = lowered(B)
+        rsB, dotsB = layout(txtB)
         assert len(rsB) == n_buckets, (B, n_buckets, len(rsB))
-        assert [d for d in dotsB if d > rsB[0]], \
-            f"B={B}: no backward compute after the first scatter"
+        waits_for = ancestors(txtB, rsB[0])
+        assert [d for d in dotsB if d not in waits_for], \
+            f"B={B}: the first scatter depends on every backward dot"
 
 
 def test_zero_bucketed_donation_unchanged(mesh8):

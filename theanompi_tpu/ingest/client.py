@@ -56,7 +56,6 @@ import os
 import threading
 import time
 from collections import deque
-from multiprocessing.connection import Client as _MpClient
 
 import numpy as np
 
@@ -66,6 +65,7 @@ from theanompi_tpu.ingest import protocol
 from theanompi_tpu.ingest.protocol import ingest_addresses  # re-export
 from theanompi_tpu.monitor import trace
 from theanompi_tpu.parallel import shm, wire
+from theanompi_tpu.parallel.rpc import connect as _connect
 from theanompi_tpu.parallel.rpc import unix_path as _unix_path
 from theanompi_tpu.parallel.rpc import wait_readable as _wait_readable
 from theanompi_tpu.resilience import faults
@@ -134,11 +134,11 @@ class _ReaderPipe:
         else:
             p = _unix_path(addr)
             if p is not None:
-                self.conn = _MpClient(p, authkey=_authkey())
+                self.conn = _connect(p, _authkey())
             else:
                 host, _, port = addr.rpartition(":")
-                self.conn = _MpClient((host or "127.0.0.1", int(port)),
-                                      authkey=_authkey())
+                self.conn = _connect((host or "127.0.0.1", int(port)),
+                                     _authkey())
         if os.environ.get("THEANOMPI_TPU_WIRE_PROTOCOL", "v2") == "v2":
             want = wire.WireOptions.from_env()
             offer = shm.client_offer() if offer_shm else None

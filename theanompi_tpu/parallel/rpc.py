@@ -86,7 +86,7 @@ from theanompi_tpu.monitor import trace as _trace
 from theanompi_tpu.parallel import shm, wire
 
 __all__ = [
-    "serve", "RpcHooks", "MuxConnection", "HandshakeTimeout",
+    "serve", "connect", "RpcHooks", "MuxConnection", "HandshakeTimeout",
     "wait_readable", "set_nodelay", "unix_path", "have_af_unix",
 ]
 
@@ -269,6 +269,42 @@ def handshake_server_conn(conn, authkey: bytes, timeout_s: float) -> None:
     response = _conn_recv_deadline(conn, deadline, 256)
     if response != WELCOME:
         raise AuthenticationError("digest sent was rejected")
+
+
+def connect(address, authkey: bytes):
+    """``multiprocessing.connection.Client(address, authkey=...)`` with
+    the handshake under the same deadline the server holds its side
+    to.  A listener that closes while a connect is in its three-way
+    handshake can leave the client ESTABLISHED with no peer and no
+    RST (Linux 6.18 drops the final ACK: ``TcpExtListenDrops``), and
+    the stdlib client then waits for the challenge for ever; here it
+    raises :class:`HandshakeTimeout`, a ``ConnectionError`` like any
+    other failed connect."""
+    from multiprocessing.connection import Client
+
+    conn = Client(address)  # no authkey: connected, not yet greeted
+    try:
+        deadline = time.monotonic() + _handshake_timeout_s()
+        message = _conn_recv_deadline(conn, deadline, 256)
+        if not message.startswith(CHALLENGE):
+            raise AuthenticationError(f"message = {message!r}")
+        conn.send_bytes(_hmac.new(authkey, message[len(CHALLENGE):],
+                                  "md5").digest())
+        if _conn_recv_deadline(conn, deadline, 256) != WELCOME:
+            raise AuthenticationError("digest sent was rejected")
+        # mutual: now challenge the server
+        message = os.urandom(MESSAGE_LENGTH)
+        conn.send_bytes(CHALLENGE + message)
+        digest = _hmac.new(authkey, message, "md5").digest()
+        response = _conn_recv_deadline(conn, deadline, 256)
+        if not _hmac.compare_digest(response, digest):
+            conn.send_bytes(FAILURE)
+            raise AuthenticationError("digest received was wrong")
+        conn.send_bytes(WELCOME)
+    except BaseException:
+        conn.close()
+        raise
+    return conn
 
 
 def _sock_recv_exact(sock: socket.socket, n: int,
@@ -932,7 +968,8 @@ class _SelectorServer:
         os.set_blocking(self._ww, False)
         self.sel.register(self._wr, selectors.EVENT_READ, "wake")
         self._plock = make_lock("rpc._SelectorServer._plock")
-        self._pending_ready: list = []   # guarded_by: self._plock
+        #: handshaken sockets awaiting registration; None once shut down
+        self._pending_ready: list | None = []  # guarded_by: self._plock
         self._pending_flush: list = []   # guarded_by: self._plock
         self._pending_close: list = []   # guarded_by: self._plock
         self.conns: dict[int, _SelConn] = {}  # io-thread owned
@@ -947,8 +984,13 @@ class _SelectorServer:
 
     def register_ready(self, sock: socket.socket) -> None:
         with self._plock:
-            self._pending_ready.append(sock)
-        self._wake()
+            running = self._pending_ready is not None
+            if running:
+                self._pending_ready.append(sock)
+        if running:
+            self._wake()
+        else:  # the loop has shut down: nobody would ever read it
+            sock.close()
 
     def request_flush(self, conn: _SelConn) -> None:
         with self._plock:
@@ -1377,6 +1419,13 @@ class _SelectorServer:
             pool.shutdown()
         for pool in (self.pool, self.ctl_pool, self.hs_pool):
             pool.join(timeout_s=2.0)
+        # a peer that finished its handshake after the loop's last turn
+        # sits here unregistered: close it (and any later one), or it
+        # waits for ever on a socket nobody reads
+        with self._plock:
+            ready, self._pending_ready = self._pending_ready, None
+        for sock in ready:
+            sock.close()
         try:
             self.sel.unregister(self._wr)
         except (KeyError, ValueError):
@@ -1579,9 +1628,7 @@ class MuxConnection:
     # -- connection management -----------------------------------------
 
     def _connect_locked(self) -> None:  # requires_lock: self._lock
-        from multiprocessing.connection import Client
-
-        conn = Client(self.address, authkey=self._authkey)
+        conn = connect(self.address, self._authkey)
         set_nodelay(conn)
         offer = shm.client_offer() if self._shm_on else None
         try:
@@ -1652,9 +1699,7 @@ class MuxConnection:
                 # also downgrade us to the non-mux fallback below)
                 self._connect_locked()
             if not self._mux:
-                from multiprocessing.connection import Client
-
-                conn = Client(self.address, authkey=self._authkey)
+                conn = connect(self.address, self._authkey)
                 set_nodelay(conn)
                 return conn, None
             sid = self._next_sid

@@ -65,7 +65,6 @@ import os
 import threading
 import time
 import uuid
-from multiprocessing.connection import Client
 from typing import Any
 
 import jax
@@ -444,6 +443,14 @@ _PENDING = object()
 #: at-least-once.
 AT_MOST_ONCE_OPS = frozenset({"gosgd_push", "gosgd_drain"})
 
+#: ops that CREATE the caller's session (idempotent per session id:
+#: ``ParamService._fresh``).  Retried after a transport failure they
+#: are re-sent as they are: a ``rejoin`` first would ask the service
+#: for a session that does not exist yet, and a service that still
+#: holds the session this init was about to displace would answer
+#: ``SessionDisplaced`` naming the OLD session.
+SESSION_INIT_OPS = frozenset({"easgd_init", "asgd_init", "gosgd_init"})
+
 
 class ServiceClient:
     """One persistent authenticated connection; thread-safe call()
@@ -524,8 +531,8 @@ class ServiceClient:
                 return
         else:
             with self._lock:
-                self._conn = Client(self.address,  # guarded_by: self._lock
-                                    authkey=self._authkey)
+                self._conn = rpc.connect(  # guarded_by: self._lock
+                    self.address, self._authkey)
                 rpc.set_nodelay(self._conn)
         self._negotiate()
 
@@ -658,7 +665,8 @@ class ServiceClient:
                     # retry loop rather than sending an op the server
                     # must reject
                     self._reconnect()
-                    self._rejoin()
+                    if op not in SESSION_INIT_OPS:
+                        self._rejoin()
                     needs_rejoin = False
                 if fault == "drop":
                     fault = None  # drop once, then the retry proceeds
