@@ -9,7 +9,9 @@ TPU-native inversion: the reference gave each of N processes its own
 shard and its own iterator.  Here one controller process yields
 *global* batches (size ``batch_size * data_axis_size``) which
 ``shard_batch`` splits across the mesh in a single ``device_put`` —
-the per-worker shard view becomes a sharding annotation.  The
+the per-worker shard view becomes a sharding annotation (and, for a
+``RowGather`` batch on several chips, each chip's slice is copied and
+put by a worker of its own: ``data/prefetch.py``).  The
 ``rank``/``size`` arguments survive for multi-host mode, where each
 host process loads only its slice of the global batch.
 """
@@ -22,6 +24,48 @@ from typing import Iterator
 import numpy as np
 
 Batch = tuple[np.ndarray, np.ndarray]  # (images NHWC, integer labels)
+
+
+class RowGather:
+    """A batch leaf whose rows are drawn but not yet copied: the
+    concatenation of ``src[sel]`` over ``parts`` (``(src, sel)`` pairs,
+    ``src`` any row-addressable array — a pool, a shard's mmap).
+
+    The source decides WHICH rows, in its one sequential thread (rng
+    draws, file order); WHO copies them is the consumer's choice:
+    ``np.asarray(leaf)`` gives the whole batch, ``rows(lo, hi)`` one
+    device's slice of it, so ``DevicePrefetcher`` can have each device's
+    slice gathered by a worker of its own and never materialise the
+    global batch.  Either way the bytes are the same."""
+
+    def __init__(self, parts: list[tuple[np.ndarray, np.ndarray]]):
+        self.parts = parts
+        src = parts[0][0]
+        self.dtype = src.dtype
+        self.shape = (sum(len(sel) for _, sel in parts),) + src.shape[1:]
+
+    def __len__(self) -> int:
+        return self.shape[0]
+
+    def rows(self, lo: int, hi: int) -> np.ndarray:
+        """Rows ``[lo, hi)`` in a fresh array: one gather per part that
+        holds some of them, the only host copy a row takes."""
+        out = np.empty((hi - lo,) + self.shape[1:], self.dtype)
+        at = 0
+        for src, sel in self.parts:
+            a, b = max(lo, at), min(hi, at + len(sel))
+            if a < b:
+                # the indices are the source's own draws, all in range;
+                # under the default mode='raise' np.take fills a
+                # temporary and copies it to ``out``: twice the traffic
+                np.take(src, sel[a - at:b - at], axis=0,
+                        out=out[a - lo:b - lo], mode="clip")
+            at += len(sel)
+        return out
+
+    def __array__(self, dtype=None, copy=None) -> np.ndarray:
+        x = self.rows(0, len(self))
+        return x if dtype is None else x.astype(dtype, copy=False)
 
 
 class Dataset(abc.ABC):
@@ -57,6 +101,21 @@ class Dataset(abc.ABC):
         self, global_batch: int, rank: int = 0, size: int = 1
     ) -> Iterator[Batch]:
         """Yield validation batches in fixed order, no augmentation."""
+
+    # -- rows named, not yet copied (DevicePrefetcher's input) -----------
+
+    def train_batch_rows(self, epoch: int, global_batch: int,
+                         rank: int = 0, size: int = 1) -> Iterator[Batch]:
+        """The stream of ``train_batches``, except that a leaf MAY be a
+        ``RowGather``: what ``models/base.py`` hands the prefetcher.  A
+        source whose batches are row gathers overrides this and derives
+        ``train_batches`` from it; the default has nothing to defer."""
+        return self.train_batches(epoch, global_batch, rank, size)
+
+    def val_batch_rows(self, global_batch: int,
+                       rank: int = 0, size: int = 1) -> Iterator[Batch]:
+        """``val_batches``, in the same form."""
+        return self.val_batches(global_batch, rank, size)
 
     # -- multi-host (one controller process per host) -------------------
 

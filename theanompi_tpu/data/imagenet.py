@@ -43,7 +43,7 @@ from typing import Callable, Iterator, Sequence
 
 import numpy as np
 
-from theanompi_tpu.data.base import Batch, Dataset
+from theanompi_tpu.data.base import Batch, Dataset, RowGather
 from theanompi_tpu.data.utils import augment_normalize, center_normalize
 
 IMAGENET_MEAN = (0.485, 0.456, 0.406)
@@ -232,6 +232,12 @@ def _synthetic_pool(n_images: int, n_classes: int, hw: int, seed: int):
     return imgs, labels.astype(np.int32)
 
 
+def _copied(batches: Iterator[Batch]) -> Iterator[Batch]:
+    """Plain ``(x, y)`` arrays of a ``*_batch_rows`` stream."""
+    for x, y in batches:
+        yield np.asarray(x), y
+
+
 class ImageNet_data(Dataset):
     """ImageNet batches from shard files, or synthetic.
 
@@ -306,17 +312,18 @@ class ImageNet_data(Dataset):
 
     # -- shared prep ---------------------------------------------------------
 
-    def _prep_train(self, x: np.ndarray,
-                    rng: np.random.Generator) -> np.ndarray:
+    def _prep_train(self, x: RowGather, rng: np.random.Generator):
         if self.augment_on_device:
-            return x  # raw uint8 store images; device crops/normalizes
-        return augment_normalize(x, self.crop, self.crop, rng,
+            # raw uint8 store images, device crops/normalizes: the rows
+            # stay uncopied, for whoever stages them (RowGather)
+            return x
+        return augment_normalize(np.asarray(x), self.crop, self.crop, rng,
                                  mean=IMAGENET_MEAN, std=IMAGENET_STD)
 
-    def _prep_val(self, x: np.ndarray) -> np.ndarray:
+    def _prep_val(self, x: RowGather):
         if self.augment_on_device:
             return x
-        return center_normalize(x, self.crop, self.crop,
+        return center_normalize(np.asarray(x), self.crop, self.crop,
                                 mean=IMAGENET_MEAN, std=IMAGENET_STD)
 
     # -- synthetic path ------------------------------------------------------
@@ -327,7 +334,7 @@ class ImageNet_data(Dataset):
         pool = len(self._pool_x)
         for _ in range(n_batches):
             idx = rng.integers(0, pool, size=global_batch)
-            x, y = self._pool_x[idx], self._pool_y[idx]
+            x, y = RowGather([(self._pool_x, idx)]), self._pool_y[idx]
             if self.label_noise > 0.0:
                 flip = rng.random(global_batch) < self.label_noise
                 y = y.copy()
@@ -353,15 +360,18 @@ class ImageNet_data(Dataset):
         """Stream batches across shard files with read-ahead decode.
         Leftover tail samples of each file carry into the next batch.
 
-        Each batch is assembled with ONE fancy-index gather per
-        contributing shard, straight from the mmap — the only host
-        copy an image takes before ``device_put``.  (The round-5
-        in-session probe, tools/ingest_session_probe.py, found the
-        previous shape of this loop — materialize ``x[perm]`` for the
-        whole shard, then np.concatenate carried tails — cost ~3
-        memcpy passes per image and capped a one-core host at ~1.4k
-        img/s warm; the gather form is bit-identical in output: the
-        same per-shard permutation sliced in the same order.)"""
+        Each batch is ONE gather per contributing shard, straight
+        from the mmap — the only host copy an image takes before
+        ``device_put``.  This loop only names the rows (``RowGather``:
+        the per-shard permutation slices, in order); the copy runs
+        where the batch is staged, per device slice when there are
+        several.  (The round-5 in-session probe,
+        tools/ingest_session_probe.py, found the previous shape of this
+        loop — materialize ``x[perm]`` for the whole shard, then
+        np.concatenate carried tails — cost ~3 memcpy passes per image
+        and capped a one-core host at ~1.4k img/s warm; the gather form
+        is bit-identical in output: the same per-shard permutation
+        sliced in the same order.)"""
 
         # pending: [x, y, perm, pos] — shard arrays (x usually a
         # mmap), its draw order, and how much of it is consumed.
@@ -373,18 +383,16 @@ class ImageNet_data(Dataset):
         pending: list[list] = []
         buffered = 0
 
-        def assemble() -> Batch:
-            x0 = pending[0][0]
-            xb = np.empty((global_batch,) + x0.shape[1:], x0.dtype)
+        def assemble() -> tuple[RowGather, np.ndarray]:
+            parts: list[tuple[np.ndarray, np.ndarray]] = []
             parts_y: list[np.ndarray] = []
-            need, at = global_batch, 0
+            need = global_batch
             while need:
                 x, y, perm, pos = pending[0]
                 take = min(need, len(perm) - pos)
                 sel = perm[pos:pos + take]
-                np.take(x, sel, axis=0, out=xb[at:at + take])
+                parts.append((x, sel))
                 parts_y.append(y[sel])
-                at += take
                 need -= take
                 if pos + take == len(perm):
                     pending.pop(0)
@@ -392,7 +400,7 @@ class ImageNet_data(Dataset):
                     pending[0][3] = pos + take
             yb = parts_y[0] if len(parts_y) == 1 \
                 else np.concatenate(parts_y)
-            return xb, yb
+            return RowGather(parts), yb
 
         for x, y in readahead(files, _load_shard, self.readahead_depth):
             perm = (shuffle_rng.permutation(len(y))
@@ -410,8 +418,8 @@ class ImageNet_data(Dataset):
 
     # -- Dataset interface ---------------------------------------------------
 
-    def train_batches(self, epoch: int, global_batch: int,
-                      rank: int = 0, size: int = 1) -> Iterator[Batch]:
+    def train_batch_rows(self, epoch: int, global_batch: int,
+                         rank: int = 0, size: int = 1) -> Iterator[Batch]:
         if self.synthetic:
             rng = np.random.default_rng(
                 self.seed + 5000 + 7919 * epoch + 104729 * rank)
@@ -423,8 +431,8 @@ class ImageNet_data(Dataset):
         shuf = shuffle_rng(self.seed, epoch, rank)
         yield from self._file_batches(files, global_batch, aug, shuf)
 
-    def val_batches(self, global_batch: int,
-                    rank: int = 0, size: int = 1) -> Iterator[Batch]:
+    def val_batch_rows(self, global_batch: int,
+                       rank: int = 0, size: int = 1) -> Iterator[Batch]:
         if self.synthetic:
             rng = np.random.default_rng(self.seed + 31337 + rank)
             n = (self.n_val // size) // global_batch
@@ -432,6 +440,15 @@ class ImageNet_data(Dataset):
             return
         files = self._sharded_files(self.val_files, None, rank, size)
         yield from self._file_batches(files, global_batch, None, None)
+
+    def train_batches(self, epoch: int, global_batch: int,
+                      rank: int = 0, size: int = 1) -> Iterator[Batch]:
+        yield from _copied(
+            self.train_batch_rows(epoch, global_batch, rank, size))
+
+    def val_batches(self, global_batch: int,
+                    rank: int = 0, size: int = 1) -> Iterator[Batch]:
+        yield from _copied(self.val_batch_rows(global_batch, rank, size))
 
     def n_train_batches(self, global_batch: int) -> int:
         return self.n_train // global_batch

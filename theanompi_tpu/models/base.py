@@ -822,7 +822,7 @@ class TpuModel:
                 epoch, self.global_batch, self.host_rank, self.host_count)
             n_iters = self.data.n_train_batches_for(epoch, self.global_batch)
         else:
-            host_iter = self.data.train_batches(
+            host_iter = self.data.train_batch_rows(
                 epoch, self.global_batch, self.shard_rank, self.shard_size)
             n_iters = self.data.n_train_batches_for(
                 epoch, self.global_batch, self.shard_rank, self.shard_size)
@@ -972,7 +972,7 @@ class TpuModel:
             host_iter = self.data.host_val_batches(
                 self.global_batch, self.host_rank, self.host_count)
         else:
-            host_iter = self.data.val_batches(self.global_batch)
+            host_iter = self.data.val_batch_rows(self.global_batch)
         from theanompi_tpu import monitor
 
         with DevicePrefetcher(host_iter, self.mesh,
