@@ -272,6 +272,7 @@ def _normal(*specs):
 def kernel_cases(full: bool = True) -> list[KernelCase]:
     """The zoo's shapes (``full``) or the same cases a few tiles big
     for the interpret-mode run on the CPU mesh."""
+    import jax
     import jax.numpy as jnp
 
     from theanompi_tpu.ops.attention import fused_attention
@@ -296,6 +297,35 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
         lambda impl: lambda q, k, v: fused_attention(
             q, k, v, causal=True, impl=impl),
         _normal(*[(shape, bf16)] * 3), rtol=2e-2, atol=2e-2))
+    # ZayaLM's: 8 query heads over 2 key/value heads of 128 (q block
+    # 128 at this length), and the grouped expert matmul under the
+    # dropless expert layer, 8 held of 16 experts
+    q_shape, kv_shape = (((4, 2048, 8, 128), (4, 2048, 2, 128)) if full
+                         else ((1, 16, 4, 8), (1, 16, 2, 8)))
+    cases.append(KernelCase(
+        f"attention_gqa{q_shape}",
+        lambda impl: lambda q, k, v: fused_attention(
+            q, k, v, causal=True, impl=impl),
+        _normal((q_shape, bf16), (kv_shape, bf16), (kv_shape, bf16)),
+        rtol=2e-2, atol=2e-2))
+    n, d, held, routed = (8192, 2048, 8, 16) if full else (200, 16, 2, 4)
+
+    def experts_layer(impl):
+        from theanompi_tpu.parallel.expert import routed_experts
+
+        def layer(x, logits, gate, up, down):
+            out, _ = routed_experts(
+                x, jax.nn.softmax(logits, -1),
+                {"gate": gate * d ** -0.5, "up": up * d ** -0.5,
+                 "down": down * d ** -0.5}, (0, held),
+                impl="pallas" if impl == "pallas" else "ragged_dot")
+            return out
+        return layer
+
+    cases.append(KernelCase(
+        f"grouped_matmul({n}, {d})x{held}of{routed}", experts_layer,
+        _normal(((n, d), bf16), ((n, routed), f32),
+                *[((held, d, d), bf16)] * 3), rtol=2e-2, atol=2e-2))
     # ResNet-50 stage-1 epilogues: conv3 (C=256, +residual) and
     # conv1/conv2 (C=64), each with and without the residual stream
     for c in (256, 64) if full else (32,):

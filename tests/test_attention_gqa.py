@@ -1,0 +1,121 @@
+"""ops/attention.py since PR 27: key/value heads fewer than query
+heads, head size 128, the q block chosen per shape, named kernels.
+Interpret mode on the CPU against the composed XLA form."""
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import theanompi_tpu.ops.attention as A
+
+
+def _qkv(b, t, hq, hkv, d, dtype=jnp.float32, seed=0):
+    key = jax.random.key(seed)
+    shape = lambda h: (b, t, h, d)  # noqa: E731
+    return tuple(jax.random.normal(jax.random.fold_in(key, i), shape(h),
+                                   dtype)
+                 for i, h in enumerate((hq, hkv, hkv)))
+
+
+def _oracle(q, k, v):
+    """Causal attention with the key/value heads repeated by hand."""
+    group = q.shape[2] // k.shape[2]
+    pos = jnp.arange(q.shape[1])
+    return A._xla_attention(q, jnp.repeat(k, group, 2),
+                            jnp.repeat(v, group, 2), pos, pos,
+                            q.shape[-1] ** -0.5, True)
+
+
+@pytest.mark.parametrize("q_block", [256, 128])
+def test_grouped_query_heads_of_128_match_the_xla_form(monkeypatch, q_block):
+    """8 query heads over 2 key/value heads of 128, forward and the
+    fused backward, in one and in two q blocks."""
+    monkeypatch.setattr(A, "_Q_BLOCK", q_block)
+    q, k, v = _qkv(1, 256, 8, 2, 128)
+    loss = lambda fn: lambda *a: (fn(*a) ** 2).sum()  # noqa: E731
+    kernel = lambda q, k, v: A.fused_attention(  # noqa: E731
+        q, k, v, causal=True, impl="pallas", name="test_attention")
+    np.testing.assert_allclose(kernel(q, k, v), _oracle(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(_oracle), argnums=(0, 1, 2))(q, k, v)
+    assert got[1].shape == k.shape and got[2].shape == v.shape
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+def test_the_xla_forms_take_grouped_heads_too():
+    q, k, v = _qkv(2, 24, 4, 2, 8, seed=3)
+    loss = lambda fn: lambda *a: (fn(*a) ** 2).sum()  # noqa: E731
+    xla = lambda q, k, v: A.fused_attention(  # noqa: E731
+        q, k, v, causal=True, impl="xla")
+    np.testing.assert_allclose(xla(q, k, v), _oracle(q, k, v),
+                               rtol=1e-6, atol=1e-6)
+    # the hand-written composed backward (the fused path's fallback)
+    pos = jnp.arange(24)
+    g = jax.random.normal(jax.random.key(4), q.shape)
+    got = A._xla_bwd(q, k, v, pos, pos, 8 ** -0.5, True, g)
+    want = jax.vjp(_oracle, q, k, v)[1](g)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+def test_head_counts_must_divide():
+    q, k, v = _qkv(1, 8, 4, 3, 8)
+    with pytest.raises(ValueError, match="multiple of one shared count"):
+        A.fused_attention(q, k, v, causal=True)
+
+
+def test_the_q_block_is_chosen_from_the_shape():
+    bf16 = jnp.bfloat16
+    # GPT-2-medium's shape stays on the configured block
+    assert A._q_block(1024, 1024, 64, bf16) == 256
+    # head 128 at 2 048: the fused backward needs 13.75 MiB at 256,
+    # over the 12 MiB budget, and 10.6 MiB at 128
+    assert not A._fits_vmem_bwd(2048, 2048, 128, bf16, 256)
+    assert A._fits_vmem_bwd(2048, 2048, 128, bf16, 128)
+    assert A._fits_vmem(2048, 128, bf16, 128)
+    assert A._q_block(2048, 2048, 128, bf16) == 128
+    # shorter than a block: one block, as before
+    assert A._q_block(20, 20, 16, jnp.float32) == 20
+    # nothing divides: the configured block, and the caller sees a
+    # ragged tail
+    assert A._q_block(300, 300, 8, jnp.float32) == 256
+
+
+def test_both_passes_take_the_kernel_at_the_zaya_shape(monkeypatch, caplog):
+    """On a TPU (4, 2048, 8|2, 128) bf16 resolves to the kernel forward
+    and backward, and the log names the block."""
+    A._log_choice.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((4, 2048, 8, 128), jnp.bfloat16)
+    k = jnp.zeros((4, 2048, 2, 128), jnp.bfloat16)
+    lse = jnp.zeros((32, 2048, 1), jnp.float32)
+    pos = jnp.arange(2048)
+    seen = {}
+    monkeypatch.setattr(A, "_pallas_attention_bwd",
+                        lambda *a: seen.setdefault("bwd", (q, k, k)))
+    with caplog.at_level(logging.INFO, logger=A.__name__):
+        assert A._resolve_impl(None, q, k) == "pallas"
+        A._fused_bwd(128 ** -0.5, True, False, "n",
+                     (q, k, k, pos, pos, lse), q)
+    A._log_choice.cache_clear()
+    assert "bwd" in seen
+    said = [r.getMessage() for r in caplog.records]
+    assert any("attention fwd" in m and "pallas" in m and "q block 128" in m
+               for m in said)
+    assert any("attention bwd" in m and "pallas" in m and "q block 128" in m
+               for m in said)
+    assert all(r.levelno == logging.INFO for r in caplog.records)
+
+
+def test_the_kernels_carry_their_name():
+    q, k, v = _qkv(1, 16, 2, 2, 8)
+    text = str(jax.make_jaxpr(jax.grad(lambda q: A.fused_attention(
+        q, k, v, causal=True, impl="pallas",
+        name="zaya_cca_attention").sum()))(q))
+    assert "zaya_cca_attention_fwd" in text
+    assert "zaya_cca_attention_bwd" in text

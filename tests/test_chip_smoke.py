@@ -147,9 +147,10 @@ class TestKernelLeg:
                 if "pl.pallas_call(" in f.read():
                     with_kernel.add(os.path.basename(path))
         assert with_kernel == {"lrn_pallas.py", "attention.py",
-                               "fused_bn.py"}
+                               "fused_bn.py", "grouped_matmul.py"}
         names = " ".join(c.name for c in _TINY_CASES)
-        for stem in ("lrn", "attention", "fused_bn"):
+        for stem in ("lrn", "attention", "attention_gqa", "fused_bn",
+                     "grouped_matmul"):
             assert stem in names
 
     def test_kernel_that_disagrees_fails(self):

@@ -20,6 +20,9 @@ MODEL_ZOO = {
                           "TransformerLM_PP"),
     "transformer_lm_moe": ("theanompi_tpu.models.transformer",
                            "TransformerLM_MoE"),
+    # a current block: compressed convolutional attention + a dropless
+    # expert layer that is told which experts it holds (ZAYA1 family)
+    "zaya_lm": ("theanompi_tpu.models.zaya", "ZayaLM"),
     # zoo variants (reference lasagne_model_zoo equivalents)
     "vgg19": ("theanompi_tpu.models.model_zoo", "VGG19"),
     "resnet101": ("theanompi_tpu.models.model_zoo", "ResNet101"),

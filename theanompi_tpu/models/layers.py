@@ -15,6 +15,7 @@ FLOPs on the MXU in bf16 while keeping fp32 master params.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
@@ -311,6 +312,96 @@ def softmax_cross_entropy(logits: jax.Array, labels: jax.Array,
         # -mean over batch of [ (1-eps)*logp_y + eps * mean_k logp_k ]
         return (1.0 - eps) * nll - eps * jnp.mean(logp)
     return nll
+
+
+def _token_block(n: int, block: int) -> int:
+    """The largest divisor of ``n`` that is at most ``block``."""
+    block = min(block, n)
+    while n % block:
+        block -= 1
+    return block
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _tied_xent_sums(h, table, labels, block: int):
+    """Summed token cross-entropy and summed top-1 misses of
+    ``logits = h @ table^T``, a block of tokens at a time."""
+    table_c = table.astype(h.dtype)
+
+    def one(args):
+        hb, yb = args
+        logits = jnp.dot(hb, table_c.T, preferred_element_type=jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(logits, yb[:, None], axis=1)[:, 0]
+        miss = (jnp.argmax(logits, axis=-1) != yb).astype(jnp.float32)
+        return jnp.sum(lse - picked), jnp.sum(miss)
+
+    losses, misses = lax.map(one, (h.reshape(-1, block, h.shape[-1]),
+                                   labels.reshape(-1, block)))
+    return losses.sum(), misses.sum()
+
+
+def _tied_xent_sums_fwd(h, table, labels, block: int):
+    """The loss is the end of the program, so its gradient is taken in
+    the same pass over the blocks: ``softmax - onehot`` of a block's
+    logits gives that block's ``dh`` and its part of ``dtable`` (fp32
+    accumulation across blocks), and no block's logits outlive it.
+    The backward only scales by the incoming cotangent."""
+    table_c = table.astype(h.dtype)
+    vocab, d = table.shape
+
+    def one(d_table, args):
+        hb, yb = args
+        logits = jnp.dot(hb, table_c.T, preferred_element_type=jnp.float32)
+        top = jnp.max(logits, axis=-1, keepdims=True)
+        exp = jnp.exp(logits - top)
+        total = jnp.sum(exp, axis=-1, keepdims=True)
+        hit = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == yb[:, None]
+        picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+        loss = jnp.sum(jnp.log(total[:, 0]) + top[:, 0] - picked)
+        miss = jnp.sum((jnp.argmax(logits, axis=-1) != yb)
+                       .astype(jnp.float32))
+        d_logits = (exp / total - hit).astype(h.dtype)          # (blk, V)
+        d_hb = jnp.dot(d_logits, table_c,
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        d_table = d_table + lax.dot_general(
+            d_logits, hb, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return d_table, (loss, miss, d_hb)
+
+    d_table, (losses, misses, d_h) = lax.scan(
+        one, jnp.zeros((vocab, d), jnp.float32),
+        (h.reshape(-1, block, d), labels.reshape(-1, block)))
+    return (losses.sum(), misses.sum()), (d_h.reshape(h.shape), d_table)
+
+
+def _tied_xent_sums_bwd(block: int, res, cotangents):
+    del block
+    d_h, d_table = res
+    g = cotangents[0]                    # the miss count carries none
+    return ((g * d_h).astype(d_h.dtype), g * d_table, None)
+
+
+_tied_xent_sums.defvjp(_tied_xent_sums_fwd, _tied_xent_sums_bwd)
+
+
+def tied_softmax_cross_entropy(h: jax.Array, table: jax.Array,
+                               labels: jax.Array,
+                               block_tokens: int = 2048):
+    """Mean token cross-entropy and top-1 error of a head TIED to the
+    embedding, ``logits = h @ table^T``, without ever holding the whole
+    ``(tokens, vocab)`` logits: the tokens pass in blocks (the largest
+    divisor of their count up to ``block_tokens``), forward and
+    gradient in one pass (``_tied_xent_sums_fwd``).
+
+    ``h (tokens, d)`` in the compute dtype, ``table (vocab, d)`` the
+    master weights (cast to ``h.dtype`` for the products; its gradient
+    comes back in its own dtype, accumulated in float32), ``labels
+    (tokens,)`` integer ids.  Returns ``(loss, error)``, float32."""
+    n = h.shape[0]
+    loss, miss = _tied_xent_sums(h, table, labels.astype(jnp.int32),
+                                 _token_block(n, block_tokens))
+    return loss / n, miss / n
 
 
 def error_rate(logits: jax.Array, labels: jax.Array) -> jax.Array:

@@ -28,10 +28,28 @@ Scope notes:
   Every choice made from a shape is logged once per shape at trace
   time (logger ``theanompi_tpu.ops.attention``; a warning when a TPU
   run takes the XLA form), so no path is taken quietly.
-* On-chip status (PR 21, TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34):
-  fwd and the fused bwd compile and match the XLA form at
-  (8, 1024, 12, 64) bf16 causal; chip_smoke.py repeats that check.
-  The ragged-q-tail path has not been compiled.
+* Grouped-query heads: k/v may carry FEWER heads than q (Hq a
+  multiple of Hkv; query head h reads key/value head h // (Hq/Hkv)).
+  The kernels pick the shared head by index map — k/v are never
+  repeated in HBM; the fused bwd walks the group's query heads in
+  its innermost grid axis and accumulates their dk/dv in the same
+  VMEM scratch.  The XLA fallback repeats k/v (it is the fallback).
+* The q block is chosen per shape (``_q_block``): the configured
+  ``THEANOMPI_TPU_ATTN_QBLOCK`` (256), else its halves down to 128,
+  the largest that divides Tq and keeps BOTH passes inside the VMEM
+  budget.  (8, 1024, 16, 64) bf16 stays on 256; (4, 2048, 8|2, 128)
+  bf16 takes 128, where the fused bwd needs 10.6 MiB (13.75 at 256,
+  over the 12 MiB budget).
+* ``name=`` labels the two ``pallas_call``s (``<name>_fwd`` /
+  ``<name>_bwd``) so a trace reducer can tell one model's attention
+  from another custom call; None keeps Pallas's default.
+* On-chip status (TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34): fwd and
+  the fused bwd compile and match the XLA form at (8, 1024, 12, 64)
+  bf16 causal (PR 21; chip_smoke.py repeats that check) and, since
+  PR 27, at head size 128 with 8 query over 2 key/value heads,
+  (4, 2048, 8|2, 128) bf16 causal at q block 128 (the zaya1_8b
+  cell's reference check runs both passes against float32).  The
+  ragged-q-tail path has not been compiled.
 """
 
 from __future__ import annotations
@@ -99,32 +117,36 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref, *,
     lse_ref[0] = m + jnp.log(l)                       # (TQ, 1) fp32
 
 
+def _fold(x):                                # (B,T,H,D) -> (B*H,T,D)
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
 def _pallas_attention(q, k, v, q_pos, k_pos, scale, causal,
-                      interpret: bool):
+                      interpret: bool, name: str | None = None):
     b, tq, h, d = q.shape
     tk = k.shape[1]
     bh = b * h
+    group = h // k.shape[2]    # query heads per key/value head
 
-    def fold(x):                                      # (B,T,H,D)->(BH,T,D)
-        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
-
-    qf, kf, vf = fold(q), fold(k), fold(v)
+    qf, kf, vf = _fold(q), _fold(k), _fold(v)
     qp = q_pos.astype(jnp.int32).reshape(tq, 1)
     kp = k_pos.astype(jnp.int32).reshape(1, tk)
 
-    tq_blk = min(_Q_BLOCK, tq)
+    tq_blk = _q_block(tq, tk, d, q.dtype)
     grid = (bh, pl.cdiv(tq, tq_blk))
     kern = functools.partial(_kernel, scale=scale, causal=causal)
+    # folded query row b*Hq + h reads folded key/value row
+    # b*Hkv + h // group, which is (b*Hq + h) // group
+    shared = lambda i, j: (i // group, 0, 0)  # noqa: E731
     out, lse = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tq_blk, d), lambda i, j: (i, j, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
             pl.BlockSpec((tq_blk, 1), lambda i, j: (j, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tk), lambda i, j: (0, 0),
@@ -141,13 +163,23 @@ def _pallas_attention(q, k, v, q_pos, k_pos, scale, causal,
             jax.ShapeDtypeStruct((bh, tq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name and name + "_fwd",
     )(qf, kf, vf, qp, kp)
     return (out.reshape(b, h, tq, d).transpose(0, 2, 1, 3),
             lse.reshape(bh, tq, 1))
 
 
+def _repeat_kv(q, k, v):
+    """k/v with q's head count (the XLA forms only)."""
+    group = q.shape[2] // k.shape[2]
+    if group == 1:
+        return k, v
+    return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
 def _xla_attention(q, k, v, q_pos, k_pos, scale, causal):
     """The composed-XLA fallback (same primitives as the oracle)."""
+    k, v = _repeat_kv(q, k, v)
     s = block_scores(q, k, scale)
     if causal:
         s = jnp.where(causal_mask(q_pos, k_pos)[None, None], s, _MASK_NEG)
@@ -155,21 +187,19 @@ def _xla_attention(q, k, v, q_pos, k_pos, scale, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
-def _fits_vmem(tq, tk, d, dtype) -> bool:
+def _fits_vmem(tk, d, dtype, tq_blk: int) -> bool:
     itemsize = jnp.dtype(dtype).itemsize
-    tq_blk = min(_Q_BLOCK, tq)
     need = (2 * tk * d * itemsize          # K + V
             + tq_blk * d * itemsize        # Q block
             + 2 * tq_blk * tk * 4)         # fp32 scores + exp
     return need <= _VMEM_BUDGET_BYTES
 
 
-def _fits_vmem_bwd(tq, tk, d, dtype) -> bool:
+def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int) -> bool:
     """The fused bwd holds whole Q/G/dq plus K/V/dk/dv per (b*h),
     fp32 copies of K/V (kmat/vmat), fp32 dk/dv scratch, and per-block
     fp32 casts of q/g."""
     itemsize = jnp.dtype(dtype).itemsize
-    tq_blk = min(_Q_BLOCK, tq)
     need = (3 * tq * d * itemsize          # Q, G, dq
             + 4 * tk * d * itemsize        # K, V, dk, dv
             + 2 * tk * d * 4               # kmat/vmat fp32 copies
@@ -177,6 +207,22 @@ def _fits_vmem_bwd(tq, tk, d, dtype) -> bool:
             + 2 * tq_blk * d * 4           # q/g block fp32 casts
             + 3 * tq_blk * tk * 4)         # s/p + dp/ds blocks
     return need <= _VMEM_BUDGET_BYTES
+
+
+def _q_block(tq, tk, d, dtype) -> int:
+    """The q block of a shape: the configured block, else its halves
+    down to 128, the largest that divides ``tq`` and keeps the forward
+    AND the fused backward inside the VMEM budget.  Where none does,
+    the configured block (one block when ``tq`` is shorter): the
+    callers' own checks then route what does not fit or divide."""
+    top = min(_Q_BLOCK, tq)
+    blk = top
+    while blk >= 128 and blk % 8 == 0:
+        if (tq % blk == 0 and _fits_vmem(tk, d, dtype, blk)
+                and _fits_vmem_bwd(tq, tk, d, dtype, blk)):
+            return blk
+        blk //= 2
+    return top
 
 
 @functools.lru_cache(maxsize=None)
@@ -198,18 +244,19 @@ def _resolve_impl(impl: str | None, q, k) -> str:
     if impl != "auto":
         return impl
     b, tq, h, d = q.shape
+    tq_blk = _q_block(tq, k.shape[1], d, q.dtype)
     if jax.default_backend() != "tpu":
         choice, why = "xla", "not a TPU"
-    elif not _fits_vmem(tq, k.shape[1], d, q.dtype):
+    elif not _fits_vmem(k.shape[1], d, q.dtype, tq_blk):
         choice, why = "xla", "K/V + score block exceed the VMEM budget"
-    elif tq % min(_Q_BLOCK, tq) != 0:
+    elif tq % tq_blk != 0:
         # ragged q-tails rely on Pallas out-of-range block padding,
         # which has only ever run interpreted; impl='pallas' still
         # forces the kernel (how tests cover it)
         choice, why = "xla", f"ragged q-tail (Tq % {_Q_BLOCK} != 0)"
     else:
-        choice, why = "pallas", "fits"
-    _log_choice("attention fwd", q.shape + (k.shape[1],), str(q.dtype),
+        choice, why = "pallas", f"fits, q block {tq_blk}"
+    _log_choice("attention fwd", q.shape + k.shape[1:3], str(q.dtype),
                 choice, why)
     return choice
 
@@ -217,13 +264,21 @@ def _resolve_impl(impl: str | None, q, k) -> str:
 def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref, dk_s, dv_s, *, scale, causal,
                 tq_blk):
-    """Flash-style backward for one (batch*head): loop q-blocks,
-    recompute p from (q, k, lse) — no stored score matrix anywhere —
-    accumulating dk/dv in fp32 VMEM scratch."""
+    """Flash-style backward for one (batch * key/value head, query head
+    of its group): loop q-blocks, recompute p from (q, k, lse) — no
+    stored score matrix anywhere — accumulating dk/dv in fp32 VMEM
+    scratch over the q-blocks AND over the group's query heads (the
+    innermost grid axis; one head when q and k/v have the same
+    count)."""
+    member = pl.program_id(1)
     kmat = k_ref[0].astype(jnp.float32)               # (TK, D)
     vmat = v_ref[0].astype(jnp.float32)
-    dk_s[...] = jnp.zeros_like(dk_s)
-    dv_s[...] = jnp.zeros_like(dv_s)
+
+    @pl.when(member == 0)
+    def _():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
     n_blocks = q_ref.shape[1] // tq_blk
 
     def body(i, _):
@@ -255,61 +310,65 @@ def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
         return 0
 
     jax.lax.fori_loop(0, n_blocks, body, 0)
-    dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
-    dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+    @pl.when(member == pl.num_programs(1) - 1)
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
 def _pallas_attention_bwd(q, k, v, q_pos, k_pos, lse, g, scale, causal,
-                          interpret):
+                          interpret, name: str | None = None):
     b, tq, h, d = q.shape
-    tk = k.shape[1]
-    bh = b * h
+    tk, h_kv = k.shape[1:3]
+    group = h // h_kv
 
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(bh, x.shape[1], d)
-
-    qf, kf, vf, gf = fold(q), fold(k), fold(v), fold(g)
+    qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
     qp = q_pos.astype(jnp.int32).reshape(tq, 1)
     kp = k_pos.astype(jnp.int32).reshape(1, tk)
-    tq_blk = min(_Q_BLOCK, tq)
+    tq_blk = _q_block(tq, tk, d, q.dtype)
 
-    whole = lambda i: (i, 0, 0)  # noqa: E731
+    # grid: (batch * key/value heads, query heads of a group); the
+    # key/value blocks stay put while the group's query heads pass
+    query = lambda i, m: (i * group + m, 0, 0)  # noqa: E731
+    shared = lambda i, m: (i, 0, 0)  # noqa: E731
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, scale=scale, causal=causal,
                           tq_blk=tq_blk),
-        grid=(bh,),
+        grid=(b * h_kv, group),
         in_specs=[
-            pl.BlockSpec((1, tq, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((tq, 1), lambda i: (0, 0),
+            pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+            pl.BlockSpec((tq, 1), lambda i, m: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk), lambda i: (0, 0),
+            pl.BlockSpec((1, tk), lambda i, m: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tq, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tq, 1), whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tq, 1), query, memory_space=pltpu.VMEM),
         ],
         out_specs=[
-            pl.BlockSpec((1, tq, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), whole, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), whole, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((b * h_kv, tk, d), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((tk, d), jnp.float32),
             pltpu.VMEM((tk, d), jnp.float32),
         ],
         interpret=interpret,
+        name=name and name + "_bwd",
     )(qf, kf, vf, qp, kp, gf, lse)
 
-    def unfold(x, t):
-        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    def unfold(x, t, heads):
+        return x.reshape(b, heads, t, d).transpose(0, 2, 1, 3)
 
-    return unfold(dq, tq), unfold(dk, tk), unfold(dv, tk)
+    return unfold(dq, tq, h), unfold(dk, tk, h_kv), unfold(dv, tk, h_kv)
 
 
 def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g):
@@ -317,48 +376,56 @@ def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g):
     ds = p * (dp - rowsum(dp*p)), dp = g v^T; dq = ds k * scale;
     dk = ds^T q * scale.  Fallback when the Pallas bwd's VMEM/blocking
     premises don't hold."""
+    group, kv_shape = q.shape[2] // k.shape[2], k.shape
+    k, v = _repeat_kv(q, k, v)
     s = block_scores(q, k, scale)
     if causal:
         s = jnp.where(causal_mask(q_pos, k_pos)[None, None], s, _MASK_NEG)
     p = jax.nn.softmax(s, axis=-1)                       # fp32
     g32 = g.astype(jnp.float32)
-    dv = jnp.einsum("bhqk,bqhd->bkhd", p, g32).astype(v.dtype)
+    dv = jnp.einsum("bhqk,bqhd->bkhd", p, g32)
     dp = jnp.einsum("bqhd,bkhd->bhqk", g32, v.astype(jnp.float32))
     ds = p * (dp - jnp.sum(dp * p, axis=-1, keepdims=True))
     dq = (jnp.einsum("bhqk,bkhd->bqhd", ds, k.astype(jnp.float32))
           * scale).astype(q.dtype)
-    dk = (jnp.einsum("bhqk,bqhd->bkhd", ds, q.astype(jnp.float32))
-          * scale).astype(k.dtype)
-    return dq, dk, dv
+    dk = jnp.einsum("bhqk,bqhd->bkhd", ds, q.astype(jnp.float32)) * scale
+
+    def shared(x):     # a key/value head's gradient sums over its group
+        b, tk, h_kv, d = kv_shape
+        return x.reshape(b, tk, h_kv, group, d).sum(3)
+
+    return dq, shared(dk).astype(k.dtype), shared(dv).astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _fused(q, k, v, q_pos, k_pos, scale, causal, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _fused(q, k, v, q_pos, k_pos, scale, causal, interpret, name):
     out, _ = _pallas_attention(q, k, v, q_pos, k_pos, scale, causal,
-                               interpret)
+                               interpret, name)
     return out
 
 
-def _fused_fwd(q, k, v, q_pos, k_pos, scale, causal, interpret):
+def _fused_fwd(q, k, v, q_pos, k_pos, scale, causal, interpret, name):
     out, lse = _pallas_attention(q, k, v, q_pos, k_pos, scale, causal,
-                                 interpret)
+                                 interpret, name)
     return out, (q, k, v, q_pos, k_pos, lse)
 
 
-def _fused_bwd(scale, causal, interpret, res, g):
+def _fused_bwd(scale, causal, interpret, name, res, g):
     q, k, v, q_pos, k_pos, lse = res
-    tq = q.shape[1]
+    tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+    tq_blk = _q_block(tq, tk, d, q.dtype)
     # the fused bwd loops exact q-blocks; ragged tails or oversize
     # VMEM needs take the composed-XLA path instead
-    fused = tq % min(_Q_BLOCK, tq) == 0 and _fits_vmem_bwd(
-        tq, k.shape[1], q.shape[-1], q.dtype)
-    _log_choice("attention bwd", q.shape + (k.shape[1],), str(q.dtype),
+    fused = tq % tq_blk == 0 and _fits_vmem_bwd(tq, tk, d, q.dtype,
+                                                tq_blk)
+    _log_choice("attention bwd", q.shape + k.shape[1:3], str(q.dtype),
                 "pallas" if fused else "xla",
-                "fits" if fused else "ragged q-tail or over the VMEM "
-                "budget")
+                f"fits, q block {tq_blk}" if fused else "ragged q-tail "
+                "or over the VMEM budget")
     if fused:
         dq, dk, dv = _pallas_attention_bwd(q, k, v, q_pos, k_pos, lse,
-                                           g, scale, causal, interpret)
+                                           g, scale, causal, interpret,
+                                           name)
     else:
         dq, dk, dv = _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g)
     return dq, dk, dv, None, None
@@ -369,13 +436,19 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 def fused_attention(q, k, v, q_pos=None, k_pos=None,
                     causal: bool = False, scale: float | None = None,
-                    impl: str | None = None):
+                    impl: str | None = None, name: str | None = None):
     """Softmax attention, fused on TPU.
 
-    q: (B, Tq, H, D); k/v: (B, Tk, H, D); optional global positions
-    (Tq,)/(Tk,) for the causal mask (default: local aranges).  Returns
-    (B, Tq, H, D) in q.dtype.
+    q: (B, Tq, H, D); k/v: (B, Tk, Hkv, D) with H a multiple of Hkv
+    (query head h reads key/value head h // (H / Hkv)); optional global
+    positions (Tq,)/(Tk,) for the causal mask (default: local aranges).
+    ``name`` labels the kernels in a trace.  Returns (B, Tq, H, D) in
+    q.dtype.
     """
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
+                         f"and {v.shape[2]} value heads: the query count "
+                         "must be a multiple of one shared count")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if q_pos is None:
         q_pos = jnp.arange(q.shape[1])
@@ -385,4 +458,4 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
     if resolved == "xla":
         return _xla_attention(q, k, v, q_pos, k_pos, scale, causal)
     return _fused(q, k, v, q_pos, k_pos, scale, causal,
-                  pallas_mode.interpret())
+                  pallas_mode.interpret(), name)

@@ -14,6 +14,18 @@ beyond an expert's capacity are dropped (their combine weight is
 zero), and the router is trained with the standard load-balancing
 auxiliary loss (mean fraction routed x mean router probability, scaled
 by E).
+
+``routed_experts`` (PR 27) is the other expert layer: dropless, top-k,
+and TOLD WHICH EXPERTS IT HOLDS.  It routes over every expert the
+router knows, lays the tokens routed to its own experts out expert by
+expert, multiplies each expert's rows by that expert's matrices in one
+grouped product a projection (ops/grouped_matmul.py), and returns the
+part of the layer's output that its own experts give; tokens routed
+elsewhere get zero from it.  No capacity, no dropped token, no dense
+one-hot, and nothing stands in for the chips that hold the other
+experts: across an expert-parallel group the shares add up to the
+whole layer (tests/test_routed_experts.py).  The capacity path above
+stays for ``TransformerLM_MoE`` until that model moves over.
 """
 
 from __future__ import annotations
@@ -24,9 +36,144 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from theanompi_tpu.ops import pallas_mode
+from theanompi_tpu.ops.grouped_matmul import TILE_M, grouped_matmul
 from theanompi_tpu.parallel.mesh import AXIS_EXPERT
 
 PyTree = Any
+
+
+@jax.custom_vjp
+def _take_rows(x, idx, mask, inv_idx, inv_mask):
+    """``where(mask, x[idx], 0)``: rows of ``x (R, d)`` picked by an
+    index array of any shape.  The picks are a partial permutation
+    whose inverse the caller knows, so the transpose is a gather too
+    (a scatter-add of rows is the slow form on a TPU): row ``r`` of
+    ``x`` receives the cotangent rows ``inv_idx[r, :]`` of the
+    flattened output where ``inv_mask[r, :]``."""
+    return jnp.where(mask[..., None], x[idx], 0).astype(x.dtype)
+
+
+def _take_rows_fwd(x, idx, mask, inv_idx, inv_mask):
+    return _take_rows(x, idx, mask, inv_idx, inv_mask), (inv_idx, inv_mask)
+
+
+def _take_rows_bwd(res, g):
+    inv_idx, inv_mask = res
+    rows = g.reshape(-1, g.shape[-1])[inv_idx]         # (R, j, d)
+    dx = jnp.where(inv_mask[..., None], rows, 0).sum(-2).astype(g.dtype)
+    return dx, None, None, None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
+                   held: tuple[int, int], top_k: int = 1,
+                   select_by: jax.Array | None = None,
+                   impl: str | None = None, name: str = "routed_experts"):
+    """This chip's part of a dropless top-k expert layer.
+
+    ``x (n, d)`` tokens; ``probs (n, E)`` the router's probabilities
+    over ALL ``E`` experts; ``held = (first, count)``: this chip holds
+    experts ``first .. first + count - 1``, and ``expert_params`` are
+    theirs alone: ``gate`` and ``up`` ``(count, d, f)``, ``down``
+    ``(count, f, d)`` (a gated SiLU MLP).  Each token goes to its
+    ``top_k`` experts weighted by their probabilities (as they are: no
+    renormalisation); ``select_by (n, E)``, where given, is what the
+    top-k is taken over in place of ``probs`` (a balancing bias moves
+    the choice and not the weight).  Returns ``(out, stats)``: ``out
+    (n, d)`` is
+    ``sum over a token's chosen experts HELD HERE of p_e * expert_e(x)``
+    and zero for a token none of whose experts is held; ``stats`` counts
+    the rows this chip multiplied (``held_rows``), the assignments that
+    went elsewhere (``rows_elsewhere``) and the fullest held expert's
+    rows (``max_expert_rows``), float32 scalars, and gives every
+    expert's assignments, held or not (``expert_load (E,)``: what a
+    balancing controller steers by).
+
+    Layout: the ``n * top_k`` assignments are counted per held expert
+    (a cumulative sum, no sort), each expert's rows are padded up to
+    whole tiles of ``TILE_M`` and placed expert by expert in a buffer
+    of static size ``n * top_k + count * TILE_M`` rows; an empty expert
+    keeps one tile of zero rows.  The buffer's rows are gathered from
+    ``x``, pass through three grouped products, and are gathered back.
+
+    ``impl``: ``'pallas'`` (the kernels; default on a TPU, interpreted
+    on the CPU platform when forced) or ``'ragged_dot'``
+    (``jax.lax.ragged_dot`` over the same layout: the oracle, default
+    elsewhere).
+    """
+    n, d = x.shape
+    first, count = held
+    n_experts = probs.shape[-1]
+    if not 0 <= first <= first + count <= n_experts:
+        raise ValueError(f"held experts {first}..{first + count - 1} are "
+                         f"not among the router's {n_experts}")
+    if impl is None:
+        impl = "pallas" if jax.default_backend() == "tpu" else "ragged_dot"
+    if impl not in ("pallas", "ragged_dot"):
+        raise ValueError(f"unknown expert matmul impl {impl!r}")
+
+    if select_by is None:
+        weights, chosen = lax.top_k(probs, top_k)              # (n, k)
+    else:
+        chosen = lax.top_k(select_by, top_k)[1]
+        weights = jnp.take_along_axis(probs, chosen, axis=-1)
+    local = chosen.reshape(-1) - first                         # (n*k,)
+    here = (local >= 0) & (local < count)
+    # one-hot over the HELD experts only: (n*k, count), all-false rows
+    # for assignments that went elsewhere
+    onehot = (local[:, None] == jnp.arange(count)[None, :])
+    sizes = onehot.sum(0, dtype=jnp.int32)                     # (count,)
+    rank = ((jnp.cumsum(onehot, axis=0, dtype=jnp.int32) - 1)
+            * onehot).sum(-1)                    # place within its expert
+    tiles = jnp.maximum(-(-sizes // TILE_M), 1)    # an empty expert: one
+    tile_ends = jnp.cumsum(tiles)
+    starts = (tile_ends - tiles) * TILE_M          # each expert's first row
+    n_assign = n * top_k
+    rows = -(-n_assign // TILE_M) * TILE_M + count * TILE_M
+    dest = jnp.where(here, starts[jnp.clip(local, 0, count - 1)] + rank, 0)
+    # the buffer row -> assignment map: a 1-D integer scatter
+    src = jnp.full((rows,), n_assign, jnp.int32).at[
+        jnp.where(here, dest, rows)].set(
+        jnp.arange(n_assign, dtype=jnp.int32), mode="drop")
+    placed = src < n_assign
+    src = jnp.where(placed, src, 0)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(rows // TILE_M),
+                         side="right"), count - 1).astype(jnp.int32)
+    n_tiles = tile_ends[-1]
+
+    dest_nk = dest.reshape(n, top_k)
+    here_nk = here.reshape(n, top_k)
+    buf = _take_rows(x, src // top_k, placed, dest_nk, here_nk)
+
+    if impl == "pallas":
+        def matmul(lhs, rhs, which):
+            return grouped_matmul(lhs, rhs.astype(lhs.dtype), tile_group,
+                                  n_tiles, f"{name}_{which}",
+                                  pallas_mode.interpret())
+    else:
+        padded_sizes = tiles * TILE_M
+
+        def matmul(lhs, rhs, which):
+            del which
+            return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), padded_sizes)
+
+    gate = matmul(buf, expert_params["gate"], "gate")
+    up = matmul(buf, expert_params["up"], "up")
+    out_rows = matmul(jax.nn.silu(gate) * up, expert_params["down"], "down")
+    picked = _take_rows(out_rows, dest_nk, here_nk, src[:, None],
+                        placed[:, None])                       # (n, k, d)
+    out = (picked * weights[..., None].astype(picked.dtype)).sum(1)
+    held_rows = sizes.sum()
+    stats = {"held_rows": held_rows.astype(jnp.float32),
+             "rows_elsewhere": (n_assign - held_rows).astype(jnp.float32),
+             "max_expert_rows": sizes.max().astype(jnp.float32),
+             "expert_load": (chosen.reshape(-1, 1) == jnp.arange(n_experts)
+                             ).sum(0, dtype=jnp.float32)}
+    return out.astype(x.dtype), stats
 
 
 def top1_dispatch(router_logits: jax.Array, capacity: int):

@@ -21,6 +21,16 @@ import os
 import jax
 
 
+def trace_running() -> bool:
+    """Whether a ``jax.profiler`` trace is being captured in this
+    process, whoever started it (JAX keeps one session a process).
+    JAX 0.9.0 has no public accessor: this reads the state
+    ``jax.profiler.start_trace`` sets."""
+    from jax._src import profiler
+
+    return profiler._profile_state.profile_session is not None
+
+
 class StepProfiler:
     """Trace the first ``n_steps`` training iterations, then stop.
 
