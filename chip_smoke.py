@@ -297,9 +297,9 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
         lambda impl: lambda q, k, v: fused_attention(
             q, k, v, causal=True, impl=impl),
         _normal(*[(shape, bf16)] * 3), rtol=2e-2, atol=2e-2))
-    # ZayaLM's: 8 query heads over 2 key/value heads of 128 (q block
-    # 128 at this length), and the grouped expert matmul under the
-    # dropless expert layer, 8 held of 16 experts
+    # ZayaLM's: 8 query heads over 2 key/value heads of 128 (10 of 16
+    # tiles of 512 x 512 at this length), and the grouped expert matmul
+    # under the dropless expert layer, 8 held of 16 experts
     q_shape, kv_shape = (((4, 2048, 8, 128), (4, 2048, 2, 128)) if full
                          else ((1, 16, 4, 8), (1, 16, 2, 8)))
     cases.append(KernelCase(

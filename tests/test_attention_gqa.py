@@ -71,43 +71,55 @@ def test_head_counts_must_divide():
 
 def test_the_q_block_is_chosen_from_the_shape():
     bf16 = jnp.bfloat16
-    # GPT-2-medium's shape stays on the configured block
-    assert A._q_block(1024, 1024, 64, bf16) == 256
-    # head 128 at 2 048: the fused backward needs 13.75 MiB at 256,
-    # over the 12 MiB budget, and 10.6 MiB at 128
-    assert not A._fits_vmem_bwd(2048, 2048, 128, bf16, 256)
-    assert A._fits_vmem_bwd(2048, 2048, 128, bf16, 128)
-    assert A._fits_vmem(2048, 128, bf16, 128)
-    assert A._q_block(2048, 2048, 128, bf16) == 128
+    # both benchmark shapes take the configured block: since the passes
+    # walk K in tiles (PR 29) a score block is (key tile, q block), not
+    # (q block, Tk), and head 128 at 2 048 no longer has to halve it
+    assert A._q_block(1024, 1024, 64, bf16) == 512
+    assert A._fits_vmem_bwd(2048, 2048, 128, bf16, 512)
+    assert A._fits_vmem(2048, 128, bf16, 512)
+    assert A._q_block(2048, 2048, 128, bf16) == 512
+    # what no longer fits is a head's whole Q/G/dq and K/V/dk/dv, twice:
+    # under its default 16 MiB the v5e compiler takes the fused backward
+    # of (2, 4096, 16, 64) and refuses (2, 5120, 16, 64) at 17.50 MiB
+    assert A._fits_vmem_bwd(4096, 4096, 64, bf16, 512)
+    assert not A._fits_vmem_bwd(5120, 5120, 64, bf16, 128)
+    assert not A._fits_vmem_bwd(3072, 3072, 128, bf16, 128)
+    # a length the configured block does not divide takes a half
+    assert A._q_block(768, 768, 64, bf16) == 256
+    assert A._key_tile(768) == 256
     # shorter than a block: one block, as before
     assert A._q_block(20, 20, 16, jnp.float32) == 20
+    assert A._key_tile(20) == 20
     # nothing divides: the configured block, and the caller sees a
     # ragged tail
-    assert A._q_block(300, 300, 8, jnp.float32) == 256
+    assert A._q_block(600, 600, 8, jnp.float32) == 512
+    assert A._key_tile(600) == 600
 
 
 def test_both_passes_take_the_kernel_at_the_zaya_shape(monkeypatch, caplog):
     """On a TPU (4, 2048, 8|2, 128) bf16 resolves to the kernel forward
-    and backward, and the log names the block."""
+    and backward, and the log names the plan."""
     A._log_choice.cache_clear()
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     q = jnp.zeros((4, 2048, 8, 128), jnp.bfloat16)
     k = jnp.zeros((4, 2048, 2, 128), jnp.bfloat16)
-    lse = jnp.zeros((32, 2048, 1), jnp.float32)
+    lse = jnp.zeros((32, 1, 2048), jnp.float32)
     pos = jnp.arange(2048)
+    plan = A.tile_plan(2048, 2048, 128, jnp.bfloat16, True)
     seen = {}
     monkeypatch.setattr(A, "_pallas_attention_bwd",
-                        lambda *a: seen.setdefault("bwd", (q, k, k)))
+                        lambda *a, **kw: seen.setdefault("bwd", (q, k, k)))
     with caplog.at_level(logging.INFO, logger=A.__name__):
-        assert A._resolve_impl(None, q, k) == "pallas"
-        A._fused_bwd(128 ** -0.5, True, False, "n",
-                     (q, k, k, pos, pos, lse), q)
+        assert A._resolve_impl(None, q, k, plan) == "pallas"
+        A._fused_bwd(128 ** -0.5, True, False, "n", plan,
+                     (q, k, k, pos, pos, q, lse), q)
     A._log_choice.cache_clear()
     assert "bwd" in seen
     said = [r.getMessage() for r in caplog.records]
-    assert any("attention fwd" in m and "pallas" in m and "q block 128" in m
+    named = "q block 512, key tile 512, 10 of 16 tiles"
+    assert any("attention fwd" in m and "pallas" in m and named in m
                for m in said)
-    assert any("attention bwd" in m and "pallas" in m and "q block 128" in m
+    assert any("attention bwd" in m and "pallas" in m and named in m
                for m in said)
     assert all(r.levelno == logging.INFO for r in caplog.records)
 

@@ -1,0 +1,304 @@
+"""ops/attention.py since PR 29: both passes walk K/V in tiles and a
+causal mask over the default positions leaves out the tiles above the
+diagonal; each pass is traced and lowered once a shape.  Interpret mode
+on the CPU against the composed XLA forms."""
+
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import theanompi_tpu.ops.attention as A
+
+
+def _qkv(b, tq, tk, hq, hkv, d, seed=0):
+    key = jax.random.key(seed)
+    shapes = ((b, tq, hq, d), (b, tk, hkv, d), (b, tk, hkv, d))
+    return tuple(jax.random.normal(jax.random.fold_in(key, i), s)
+                 for i, s in enumerate(shapes))
+
+
+def _reference_lse(q, k, v, q_pos, k_pos, scale, causal):
+    k, _ = A._repeat_kv(q, k, v)
+    s = A.block_scores(q, k, scale)
+    if causal:
+        s = jnp.where(A.causal_mask(q_pos, k_pos)[None, None], s,
+                      A._MASK_NEG)
+    return jax.nn.logsumexp(s, axis=-1).reshape(-1, 1, q.shape[1])
+
+
+def _allgather_positions(shard, t_local, n):
+    """What parallel/sequence.py's all-gather strategy passes."""
+    return shard * t_local + jnp.arange(t_local), jnp.arange(n * t_local)
+
+
+#: name: (b, tq, tk, hq, hkv, d), _Q_BLOCK, key tile (None = the rule's),
+#: causal, explicit (q_pos, k_pos) or None, (visited, total), the
+#: backward that runs
+CASES = {
+    "one_tile_s128": ((2, 128, 128, 2, 2, 16), 512, None, True, None,
+                      (1, 1), "pallas"),
+    "q_block_equals_key_tile": ((2, 32, 32, 2, 2, 8), 8, None, True, None,
+                                (10, 16), "pallas"),
+    "q_block_under_key_tile": ((1, 32, 32, 2, 2, 8), 8, 16, True, None,
+                               (6, 8), "pallas"),
+    "q_block_over_key_tile": ((1, 32, 32, 2, 2, 8), 16, 8, True, None,
+                              (6, 8), "pallas"),
+    "keys_longer_than_queries": ((1, 16, 48, 2, 2, 8), 8, None, True, None,
+                                 (3, 12), "pallas"),
+    "grouped_8_over_2_heads_of_128": ((1, 256, 256, 8, 2, 128), 64, None,
+                                      True, None, (10, 16), "pallas"),
+    "not_causal_visits_every_tile": ((2, 24, 24, 2, 2, 8), 8, None, False,
+                                     None, (9, 9), "pallas"),
+    "allgather_positions_last_shard": (
+        (1, 16, 48, 2, 2, 8), 8, None, True, _allgather_positions(2, 16, 3),
+        (12, 12), "pallas"),
+    "allgather_positions_first_shard": (
+        (1, 16, 48, 4, 2, 8), 8, 16, True, _allgather_positions(0, 16, 3),
+        (6, 6), "pallas"),
+    # rows 0..7, the first q block, precede every key: fully masked
+    "a_q_block_that_sees_no_key": (
+        (1, 16, 16, 2, 2, 8), 8, None, True,
+        (jnp.arange(16), 8 + jnp.arange(16)), (4, 4), "pallas"),
+    "ragged_q_tail_takes_the_xla_bwd": ((1, 20, 24, 2, 2, 8), 8, None, True,
+                                        None, (6, 9), "xla"),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_tiled_passes_match_the_xla_forms(monkeypatch, case):
+    """Forward, lse and the gradients of q, k and v, for each way the
+    walk can go: one tile, square and oblong tiles, grouped heads, no
+    mask, explicit positions (every tile visited and masked, a fully
+    masked q block's uniform rows among them), and a shape whose
+    backward is the composed-XLA one."""
+    shape, q_block, key_tile, causal, positions, tiles, bwd = CASES[case]
+    monkeypatch.setattr(A, "_Q_BLOCK", q_block)
+    if key_tile is not None:
+        monkeypatch.setattr(A, "_key_tile", lambda tk: key_tile)
+    q, k, v = _qkv(*shape)
+    tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+    scale = d ** -0.5
+    plan = A.tile_plan(tq, tk, d, q.dtype, causal,
+                       default_positions=positions is None)
+    assert (plan.visited, plan.total) == tiles
+    q_pos, k_pos = positions or (jnp.arange(tq), jnp.arange(tk))
+
+    ran = []
+    for name in ("_pallas_attention_bwd", "_xla_bwd"):
+        real = getattr(A, name)
+        monkeypatch.setattr(A, name, lambda *a, _real=real, _name=name, **kw:
+                            (ran.append(_name), _real(*a, **kw))[1])
+    kernel = lambda q, k, v: A.fused_attention(  # noqa: E731
+        q, k, v, *(positions or ()), causal=causal, impl="pallas")
+    g = jax.random.normal(jax.random.key(7), q.shape)
+    out, vjp = jax.vjp(kernel, q, k, v)
+    got = vjp(g)
+    assert ran == ["_pallas_attention_bwd" if bwd == "pallas" else "_xla_bwd"]
+
+    np.testing.assert_allclose(
+        out, A._xla_attention(q, k, v, q_pos, k_pos, scale, causal),
+        rtol=2e-5, atol=2e-5)
+    _, lse = A._pallas_attention(q, k, v, q_pos, k_pos, scale=scale,
+                                 causal=causal, interpret=True, plan=plan)
+    np.testing.assert_allclose(
+        lse, _reference_lse(q, k, v, q_pos, k_pos, scale, causal),
+        rtol=1e-5, atol=1e-5)
+    want = A._xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+def test_a_fully_masked_q_block_comes_out_uniform():
+    """The rows that see no key average V, as the one-pass softmax gave
+    them, and their lse saturates to the mask value."""
+    q, k, v = _qkv(1, 16, 16, 2, 2, 8)
+    q_pos, k_pos = jnp.arange(16), 8 + jnp.arange(16)
+    plan = A.tile_plan(16, 16, 8, q.dtype, True, default_positions=False)
+    out, lse = A._pallas_attention(q, k, v, q_pos, k_pos, scale=8 ** -0.5,
+                                   causal=True, interpret=True, plan=plan)
+    np.testing.assert_allclose(
+        out[:, :8], jnp.broadcast_to(v.mean(1, keepdims=True),
+                                     (1, 8, 2, 8)), rtol=1e-5, atol=1e-6)
+    assert (lse[:, 0, :8] == jnp.float32(A._MASK_NEG)).all()
+
+
+@pytest.mark.parametrize("tq,tk,q_block,causal,default,want", [
+    (1024, 1024, 256, True, True, (256, 256, 10, 16)),
+    (2048, 2048, 128, True, True, (128, 128, 136, 256)),
+    (2048, 2048, 256, True, True, (256, 256, 36, 64)),
+    (128, 128, 512, True, True, (128, 128, 1, 1)),
+    (1024, 1024, 512, True, True, (512, 512, 3, 4)),
+    (2048, 2048, 512, True, True, (512, 512, 10, 16)),
+    (1024, 1024, 256, False, True, (256, 256, 16, 16)),
+    (1024, 1024, 256, True, False, (256, 256, 16, 16)),
+    (256, 768, 256, True, True, (256, 256, 1, 3)),
+    (384, 384, 256, True, True, (128, 128, 6, 9)),
+])
+def test_the_counter_is_a_function_of_the_shape(monkeypatch, tq, tk, q_block,
+                                                causal, default, want):
+    """``tile_plan``: n of m tiles, the number PERF.md and the log line
+    quote."""
+    monkeypatch.setattr(A, "_Q_BLOCK", q_block)
+    plan = A.tile_plan(tq, tk, 64, jnp.bfloat16, causal,
+                       default_positions=default)
+    assert (plan.q_block, plan.key_tile, plan.visited, plan.total) == want
+    assert plan.skip == (causal and default)
+    assert str(plan) == (f"q block {want[0]}, key tile {want[1]}, "
+                         f"{want[2]} of {want[3]} tiles")
+
+
+def test_walk_bounds_agree_with_the_mask():
+    """A tile is left out only where the mask removes its every score,
+    and is left unmasked only where the mask removes none."""
+    for q_block, key_tile, tq, tk in ((8, 8, 32, 32), (8, 16, 32, 32),
+                                      (16, 8, 32, 48), (8, 8, 24, 16)):
+        mask = np.asarray(A.causal_mask(jnp.arange(tq), jnp.arange(tk)))
+        n_tiles = tk // key_tile
+        for j in range(tq // q_block):
+            first, end = A._walk_bounds(j, q_block, key_tile, n_tiles,
+                                        True, True)
+            rows = mask[j * q_block:(j + 1) * q_block]
+            for t in range(n_tiles):
+                tile = rows[:, t * key_tile:(t + 1) * key_tile]
+                assert (t < first) == tile.all()
+                assert (t >= end) == (not tile.any())
+
+
+def test_the_plan_is_logged_once_a_shape(monkeypatch, caplog):
+    A._log_choice.cache_clear()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(A, "_Q_BLOCK", 256)
+    q = jnp.zeros((8, 1024, 16, 64), jnp.bfloat16)
+    plans = [A.tile_plan(1024, 1024, 64, q.dtype, True, default_positions=d)
+             for d in (True, True, False)]
+    with caplog.at_level(logging.INFO, logger=A.__name__):
+        for plan in plans:
+            assert A._resolve_impl(None, q, q, plan) == "pallas"
+    A._log_choice.cache_clear()
+    said = [r.getMessage() for r in caplog.records]
+    assert len(said) == 2     # one a (shape, plan), not one a call
+    assert "pallas (fits, q block 256, key tile 256, 10 of 16 tiles)" \
+        in said[0]
+    assert "16 of 16 tiles" in said[1]
+
+
+def _net(n_layers):
+    from theanompi_tpu.models.transformer import TransformerLMNet
+
+    return TransformerLMNet(vocab=32, n_layers=n_layers, d_model=24,
+                            n_heads=2, d_ff=48, max_len=16,
+                            attn_impl="pallas")
+
+
+@pytest.fixture
+def kernel_traces(monkeypatch):
+    """Counts of kernel-body traces, with both passes' jit caches (and
+    so their traced jaxprs) emptied first."""
+    A._pallas_attention.clear_cache()
+    A._pallas_attention_bwd.clear_cache()
+    monkeypatch.setattr(A, "_Q_BLOCK", 8)
+    counts = {"_kernel": 0, "_bwd_kernel": 0}
+    for name in counts:
+        real = getattr(A, name)
+
+        def counted(*a, _real=real, _name=name, **kw):
+            counts[_name] += 1
+            return _real(*a, **kw)
+        monkeypatch.setattr(A, name, counted)
+    return counts
+
+
+def test_each_pass_is_traced_and_lowered_once_a_shape(kernel_traces):
+    """24 layers or 2: one kernel trace and one lowered function a pass.
+    (A bare ``pallas_call`` under the ``custom_vjp`` was traced and
+    lowered again at every call site, on every start.)"""
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    seen = {}
+    for n_layers in (2, 6):
+        net = _net(n_layers)
+        before = dict(kernel_traces)
+        params = jax.eval_shape(net.init, jax.random.key(0), tokens)
+        text = jax.jit(jax.grad(
+            lambda p: net.apply(p, tokens).sum())).lower(params).as_text()
+        seen[n_layers] = (
+            {k: kernel_traces[k] - before[k] for k in before},
+            text.count("func.func private @_pallas_attention("),
+            text.count("func.func private @_pallas_attention_bwd("),
+            text.count("call @_pallas_attention("),
+            text.count("call @_pallas_attention_bwd("))
+    # the first depth traces each kernel body once (the jaxpr of the
+    # jitted pass is kept), the second not at all; each program holds
+    # one function a pass, called once a layer
+    assert seen[2] == ({"_kernel": 1, "_bwd_kernel": 1}, 1, 1, 2, 2)
+    assert seen[6] == ({"_kernel": 0, "_bwd_kernel": 0}, 1, 1, 6, 6)
+
+
+def test_the_eager_constructor_compiles_the_forward_once(kernel_traces):
+    """``module.init`` run primitive by primitive, as models/base.py
+    runs it: six layers, one compiled forward."""
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    _net(6).init(jax.random.key(0), tokens)
+    assert kernel_traces["_kernel"] == 1
+    assert A._pallas_attention._cache_size() == 1
+
+
+# ---- the real shapes, compiled for the chip that is described here and
+# not attached (no chip time; what interpret mode cannot refuse) ----
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler here: nothing to compile for
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,plan", [
+    # the three LM cells' shapes
+    ((8, 1024, 16, 64), 16, "q block 512, key tile 512, 3 of 4 tiles"),
+    ((4, 2048, 8, 128), 2, "q block 512, key tile 512, 10 of 16 tiles"),
+    ((64, 128, 16, 64), 16, "q block 128, key tile 128, 1 of 1 tiles"),
+    # long lengths the budget admits, at a batch x heads where the
+    # compiler asks more than the estimate (18.0 MiB at the first), and
+    # one that takes a smaller q block than key tile
+    ((8, 4096, 16, 64), 16, "q block 512, key tile 512, 36 of 64 tiles"),
+    ((16, 2560, 8, 128), 2, "q block 256, key tile 512, 30 of 50 tiles"),
+])
+def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
+                                       kv_heads, plan):
+    """Mosaic takes both kernels at the plans the budget functions
+    admit, bf16, causal."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from theanompi_tpu.ops import pallas_mode
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    b, t, h, d = q_shape
+    assert str(A.tile_plan(t, t, d, jnp.bfloat16, True)) == plan
+    assert A._fits_vmem_bwd(t, t, d, jnp.bfloat16,
+                            A._q_block(t, t, d, jnp.bfloat16))
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
+                             sharding=one_chip)
+    loss = lambda q, k, v: A.fused_attention(  # noqa: E731
+        q, k, v, causal=True, impl="pallas",
+        name="test_attention").astype(jnp.float32).sum()
+    # a program compiled for a described chip cannot be read back from
+    # the persistent cache without one: keep it out
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, k).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "test_attention_fwd" in text and "test_attention_bwd" in text

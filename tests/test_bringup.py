@@ -286,7 +286,7 @@ class TestNoQuietFallback:
 
         A._log_choice.cache_clear()
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        ragged = jnp.zeros((1, 300, 2, 8))   # 300 % 256 != 0
+        ragged = jnp.zeros((1, 600, 2, 8))   # 600 % 512 != 0
         fits = jnp.zeros((1, 256, 2, 8))
         with caplog.at_level(logging.INFO, logger=A.__name__):
             assert A._resolve_impl(None, ragged, ragged) == "xla"

@@ -105,6 +105,19 @@ class TestFusedAttention:
         v = jax.random.normal(ks[2], (b, tk, h, d))
         return q, k, v
 
+    @staticmethod
+    def _fused_and_xla_bwd(A, q, k, v, q_pos, k_pos, g):
+        """Both passes of the kernel under explicit positions (every
+        tile visited and masked) beside the composed-XLA VJP."""
+        tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
+        kw = dict(scale=d ** -0.5, causal=True, interpret=True,
+                  plan=A.tile_plan(tq, tk, d, q.dtype, True,
+                                   default_positions=False))
+        out, lse = A._pallas_attention(q, k, v, q_pos, k_pos, **kw)
+        got = A._pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g,
+                                      **kw)
+        return got, A._xla_bwd(q, k, v, q_pos, k_pos, kw["scale"], True, g)
+
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_oracle(self, causal):
         from theanompi_tpu.ops.attention import fused_attention
@@ -223,12 +236,7 @@ class TestFusedAttention:
         q_pos = 32 + jnp.arange(16)
         k_pos = jnp.arange(48)
         g = jax.random.normal(jax.random.key(9), q.shape)
-        scale = q.shape[-1] ** -0.5
-        _, lse = A._pallas_attention(q, k, v, q_pos, k_pos, scale,
-                                     True, interpret=True)
-        got = A._pallas_attention_bwd(q, k, v, q_pos, k_pos, lse, g,
-                                      scale, True, interpret=True)
-        want = A._xla_bwd(q, k, v, q_pos, k_pos, scale, True, g)
+        got, want = self._fused_and_xla_bwd(A, q, k, v, q_pos, k_pos, g)
         for a, b in zip(got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-5, atol=5e-5)
@@ -284,12 +292,7 @@ class TestFusedAttention:
         q_pos = jnp.arange(8)          # rows 0.. precede k_pos 8..
         k_pos = 8 + jnp.arange(16)     # -> ALL rows fully masked
         g = jax.random.normal(jax.random.key(3), q.shape)
-        scale = q.shape[-1] ** -0.5
-        _, lse = A._pallas_attention(q, k, v, q_pos, k_pos, scale,
-                                     True, interpret=True)
-        got = A._pallas_attention_bwd(q, k, v, q_pos, k_pos, lse, g,
-                                      scale, True, interpret=True)
-        want = A._xla_bwd(q, k, v, q_pos, k_pos, scale, True, g)
+        got, want = self._fused_and_xla_bwd(A, q, k, v, q_pos, k_pos, g)
         for a, b in zip(got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-5, atol=5e-5)
@@ -322,4 +325,4 @@ def test_attention_env_knobs(monkeypatch):
         monkeypatch.delenv("THEANOMPI_TPU_ATTN_QBLOCK", raising=False)
         monkeypatch.delenv("THEANOMPI_TPU_ATTN_VMEM_MB", raising=False)
         importlib.reload(A)
-    assert A._Q_BLOCK == 256
+    assert A._Q_BLOCK == 512
