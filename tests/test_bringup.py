@@ -1,12 +1,11 @@
 """What PR 21 (bring-up on the v5e, JAX 0.9.0) established, pinned on
 the CPU: the compile cache is placed from outside or at one fixed
-path; bench.py and the entry points neither fall back to a CPU nor
-pick a platform; host-only children are pinned to the CPU; a kernel
+path; the entry points neither fall back to a CPU nor pick a
+platform; host-only children are pinned to the CPU; a kernel
 the compiler refuses is loud."""
 
 from __future__ import annotations
 
-import json
 import logging
 import os
 import re
@@ -28,7 +27,7 @@ def _tracked_sources() -> list[str]:
             out += [os.path.join(dirpath, f) for f in files
                     if f.endswith(".py")]
     return out + [os.path.join(REPO, f) for f in
-                  ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                  ("chip_smoke.py", "__graft_entry__.py")]
 
 
 # -- compile cache ----------------------------------------------------------
@@ -80,88 +79,6 @@ class TestCompileCachePlacement:
         with pytest.raises(SystemExit):
             _build_parser(False).parse_args(
                 ["BSP", "--compilation-cache-dir", "/tmp/x"])
-
-
-# -- bench.py ---------------------------------------------------------------
-
-
-class TestBench:
-    def test_no_probe_apparatus_left(self):
-        with open(os.path.join(REPO, "bench.py")) as f:
-            src = f.read()
-        for gone in ("subprocess", "signal", "LAST_VERIFIED_ON_CHIP"):
-            assert gone not in src
-
-    def test_without_a_tpu_it_fails_in_one_line(self, monkeypatch, capsys):
-        import bench
-
-        # the platform IS cpu here, but the caller did not ask for it
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        assert bench.main() != 0
-        cap = capsys.readouterr()
-        assert cap.out == ""
-        assert len(cap.err.strip().splitlines()) == 1
-        assert "no accelerator" in cap.err and "'cpu'" in cap.err
-
-    _DETAIL = {"n_chips": 4, "global_batch": 512, "steps_per_call": 4,
-               "images_per_sec_total": 9000.0, "step_ms": 50.0,
-               "dispatch_ms": 200.0, "e2e_images_per_sec_per_chip": 2000.0,
-               "h2d_gbps": 1.5, "e2e_steps": 64,
-               "recorder_wall_s": 3.0, "augment": "device",
-               "backend": "x"}
-
-    class _Dev:
-        def __init__(self, platform, kind):
-            self.platform, self.device_kind = platform, kind
-
-    def test_cpu_line_carries_null_value_and_names_the_platform(self):
-        """No CPU rate, time or bandwidth is ever printed under the
-        device metric's name; the counts and the platform stay."""
-        import bench
-
-        line = bench.result_line([self._Dev("cpu", "cpu")] * 8, 123.4,
-                                 dict(self._DETAIL))
-        assert line["value"] is None and line["vs_baseline"] is None
-        assert (line["platform"], line["device_kind"],
-                line["n_devices"]) == ("cpu", "cpu", 8)
-        assert "dry run" in line["detail"]["note"]
-        assert line["detail"]["e2e_steps"] == 64
-        assert not [k for k in line["detail"] if k.endswith(
-            ("_ms", "_s", "_gbps", "per_chip", "per_sec_total"))]
-
-    def test_tpu_line_keeps_its_keys_and_gains_the_device(self):
-        import bench
-
-        line = bench.result_line([self._Dev("tpu", "TPU v5 lite")] * 4,
-                                 2500.0, dict(self._DETAIL))
-        assert line["value"] == 2500.0 and line["vs_baseline"] == 16.0
-        assert (line["platform"], line["device_kind"],
-                line["n_devices"]) == ("tpu", "TPU v5 lite", 4)
-        assert line["detail"] == self._DETAIL
-
-    @pytest.mark.slow
-    def test_cpu_dry_run_executes_both_legs(self, monkeypatch, capsys):
-        """JAX_PLATFORMS=cpu (conftest sets it) asks for a dry run: both
-        legs execute on a toy ResNet and the line says nothing was
-        measured."""
-        import bench
-        import theanompi_tpu.data.imagenet as imagenet
-        import theanompi_tpu.models.resnet50 as zoo
-        from tests._tiny_models import TinyRecipeResNet
-
-        real_data = imagenet.ImageNet_data
-        monkeypatch.setattr(zoo, "ResNet50", TinyRecipeResNet)
-        monkeypatch.setattr(
-            imagenet, "ImageNet_data",
-            lambda **kw: real_data(**{**kw, "crop": 32,
-                                      "synthetic_store": 40}))
-        monkeypatch.setattr(bench, "BATCH_PER_CHIP", 2)
-        monkeypatch.setattr(bench, "N_STEPS", 2)
-        monkeypatch.setattr(bench, "E2E_STEPS", 2)
-        assert bench.main() == 0
-        line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-        assert line["value"] is None and line["platform"] == "cpu"
-        assert line["detail"]["e2e_steps"] == 2
 
 
 # -- entry points -------------------------------------------------------------
@@ -244,16 +161,13 @@ class TestChildrenArePinnedToCpu:
 
 class TestPeakTable:
     def test_unknown_device_kind_is_an_error(self):
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        try:
-            from flop_constants import peak_bf16_tflops
-        finally:
-            sys.path.pop(0)
-        assert peak_bf16_tflops("TPU v5 lite") == 197.0
+        from benchmarks.peaks import peak
+
+        assert peak("TPU v5 lite")["bf16_tflops"] == 197.0
         with pytest.raises(KeyError, match="TPU v9"):
-            peak_bf16_tflops("TPU v9")
+            peak("TPU v9")
         with pytest.raises(KeyError):
-            peak_bf16_tflops("cpu")
+            peak("cpu")
 
 
 # -- kernels: no quiet way out -------------------------------------------------

@@ -25,7 +25,7 @@ The acceptance pins:
   overload-delta saturation); scale-down drains before release.
 
 The real-subprocess fleet (``DisaggregatedFleet``) is exercised in the
-slow set and by ``tools/preflight.sh``; everything above runs
+slow set (``--runslow``); everything above runs
 in-process over real sockets, the ``tests/test_decode.py`` pattern.
 """
 
@@ -618,7 +618,7 @@ class TestAutoscaler:
 
 
 # ---------------------------------------------------------------------------
-# the real-subprocess fleet (slow set; tools/preflight.sh drives it too)
+# the real-subprocess fleet (slow set)
 # ---------------------------------------------------------------------------
 
 

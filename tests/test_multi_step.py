@@ -95,7 +95,7 @@ class TestStagedBatchDonation:
         assert donors == n_state + 2
 
     def test_donate_batch_false_keeps_buffers(self, mesh8):
-        # bench.py's device-step leg replays pre-staged batches; the
+        # a caller that replays pre-staged batches opts out; the
         # opt-out must really withhold the batch from donation
         donors, n_state = self._donors(mesh8, donate_batch=False)
         assert donors == n_state

@@ -296,33 +296,3 @@ class TestFusedAttention:
         for a, b in zip(got, want):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=5e-5, atol=5e-5)
-
-
-def test_attention_env_knobs(monkeypatch):
-    """THEANOMPI_TPU_ATTN_QBLOCK / _VMEM_MB let on-chip sweeps tune the
-    kernel without code edits; bad values fail at import, not in a
-    kernel launch."""
-    import importlib
-
-    import theanompi_tpu.ops.attention as A
-
-    try:
-        monkeypatch.setenv("THEANOMPI_TPU_ATTN_QBLOCK", "128")
-        monkeypatch.setenv("THEANOMPI_TPU_ATTN_VMEM_MB", "8")
-        importlib.reload(A)
-        assert A._Q_BLOCK == 128
-        assert A._VMEM_BUDGET_BYTES == 8 * 1024 * 1024
-        monkeypatch.setenv("THEANOMPI_TPU_ATTN_QBLOCK", "100")  # not /8
-        with pytest.raises(ValueError, match="multiple of 8"):
-            importlib.reload(A)
-        monkeypatch.setenv("THEANOMPI_TPU_ATTN_QBLOCK", "256")
-        monkeypatch.setenv("THEANOMPI_TPU_ATTN_VMEM_MB", "0")
-        with pytest.raises(ValueError, match="must be positive"):
-            importlib.reload(A)
-    finally:
-        # monkeypatch restores env at teardown, but NOT the reloaded
-        # module globals — restore them even if an assert above failed
-        monkeypatch.delenv("THEANOMPI_TPU_ATTN_QBLOCK", raising=False)
-        monkeypatch.delenv("THEANOMPI_TPU_ATTN_VMEM_MB", raising=False)
-        importlib.reload(A)
-    assert A._Q_BLOCK == 512

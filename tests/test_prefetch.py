@@ -1,7 +1,7 @@
 """DevicePrefetcher: staging, exhaustion, error propagation, and the
-round-5 ``stats`` hook (the in-session ingest measurement —
-tools/ingest_session_probe.py reads ``stats`` to separate the loader's
-critical path from consumer compute that shares the host core).
+round-5 ``stats`` hook (the in-session ingest measurement: ``stats``
+separates the loader's critical path from consumer compute that shares
+the host core).
 
 PR 26: a batch whose rows are named but not copied (``RowGather``) is
 staged per device slice when its sharding has several addressable

@@ -140,8 +140,8 @@ def test_lm_declares_trained_flops(dp_sp_mesh):
     """The LM family reports achieved TFLOP/s like the CNN zoo: FLOPs
     per sequence = 6·n_active·L (2xMAC, fwd+bwd; embedding/positional
     tables excluded — gather + add, ~0 FLOPs) + the attention score/PV
-    term 12·n_layers·L²·d, computed from the REAL param count so
-    resized/TP models stay honest."""
+    term counted causally, 6·n_layers·d·L(L+1), computed from the REAL
+    param count so resized/TP models stay honest."""
     from jax import tree_util as jtu
 
     m = make_lm(dp_sp_mesh)
@@ -155,7 +155,7 @@ def test_lm_declares_trained_flops(dp_sp_mesh):
     active = sum(int(leaf.size) for p, leaf in flat if not is_table(p))
     total = sum(int(leaf.size) for _, leaf in flat)
     assert 0 < active < total  # the tables exist AND are excluded
-    want = 6 * active * 32 + 12 * 2 * 32 * 32 * 32
+    want = 6 * active * 32 + 6 * 2 * 32 * 32 * 33
     assert m.train_flops_per_sample == float(want)
     m.cleanup()
 
@@ -170,7 +170,7 @@ def test_lm_train_flops_discounts_experts():
     got = _lm_train_flops(params, n_layers=1, seq_len=2, d_model=3,
                           expert_mask=mask, n_experts=4)
     # top-1 routing: 20 expert weights count as 20/4 active per token
-    want = 6 * (10 + 20 // 4) * 2 + 12 * 1 * 2 * 2 * 3
+    want = 6 * (10 + 20 // 4) * 2 + 6 * 1 * 3 * 2 * 3
     assert got == float(want)
 
 

@@ -5,8 +5,8 @@ Modes:
 * default / ``--format json``: run every checker, print findings;
 * ``--gate``: zero-NEW-findings gate against ``analysis/baseline.json``
   (exit 1 on any finding whose stable key is not baselined; stale
-  baseline entries are warnings, not failures) — wired into
-  ``tools/preflight.sh``;
+  baseline entries are warnings, not failures) — tier-1 runs it
+  (``tests/test_analysis.py``);
 * ``--write-baseline``: accept the current findings as the baseline
   (reasons already recorded for surviving keys are preserved);
 * ``--inventory``: print the metric/fault-site inventories as markdown

@@ -3,8 +3,8 @@
 ``jax.jit(f, donate_argnums=(0,))`` hands argument 0's buffers to XLA:
 after the call, reading that array from Python is undefined behavior
 (on TPU it is a crash or garbage; on CPU it often *silently works*,
-which is why this bug class survives tier-1 — the exact class PR 3's
-bench/queue donation opt-outs exist to dodge).
+which is why this bug class survives tier-1 — the exact class the
+``donate_batch=False`` opt-out exists to dodge).
 
 The pass has two phases:
 

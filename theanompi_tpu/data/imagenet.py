@@ -13,10 +13,10 @@ TPU-native inversion of each piece:
 * **hkl batch files → shard files**: mmap-able ``train_*.x.npy`` /
   ``*.y.npy`` pairs (uint8 ``x`` (N,H,W,3), int ``y``) — the round-3
   default: zero decode at training time, the read-ahead thread just
-  pages rows in (measured 1.8x the npz ingest rate on one core,
-  tools/host_pipeline_probe.py) — with ``train_*.npz`` (round 1/2)
-  still read.  Same pre-decoded design either way: decode cost is paid
-  once at preparation time.
+  pages rows in (measured 1.8x the npz ingest rate on one CPU core,
+  round 3) — with ``train_*.npz`` (round 1/2) still read.  Same
+  pre-decoded design either way: decode cost is paid once at
+  preparation time.
 * **rank-0 broadcast of the shuffle → seeded permutation.**  The epoch
   order is a pure function of (seed, epoch), so every host computes
   the identical order with zero communication.
@@ -126,9 +126,8 @@ def _load_shard(path: str):
     """Decode one shard file.  ``*.x.npy`` pairs are the mmap-able
     format: ``np.load(mmap_mode='r')`` costs no decode and no copy —
     the OS pages image rows in as the gather touches them — which is
-    what lets ONE host core assemble uint8 batches at device rate
-    (tools/host_pipeline_probe.py measures both formats).  ``.npz``
-    (zip container, member copy per load) remains supported.
+    what lets ONE host core assemble uint8 batches at device rate.
+    ``.npz`` (zip container, member copy per load) remains supported.
 
     Cold-read strategy (round 5): ``posix_fadvise(WILLNEED)`` first —
     the kernel then streams the whole file at device speed (measured
@@ -365,9 +364,8 @@ class ImageNet_data(Dataset):
         ``device_put``.  This loop only names the rows (``RowGather``:
         the per-shard permutation slices, in order); the copy runs
         where the batch is staged, per device slice when there are
-        several.  (The round-5 in-session probe,
-        tools/ingest_session_probe.py, found the previous shape of this
-        loop — materialize ``x[perm]`` for the whole shard, then
+        several.  (A round-5 in-session probe found the previous shape of
+        this loop — materialize ``x[perm]`` for the whole shard, then
         np.concatenate carried tails — cost ~3 memcpy passes per image
         and capped a one-core host at ~1.4k img/s warm; the gather form
         is bit-identical in output: the same per-shard permutation

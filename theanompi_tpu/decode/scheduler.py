@@ -8,9 +8,8 @@ scheduler batches ITERATIONS instead (the continuous-batching
 discipline): between any two decode steps it may **admit** pending
 prompts into free cache slots and **evict** finished sequences, so a
 request admitted mid-stream shares its very first decode step with
-whatever is already in flight (pinned by tests/test_decode.py and the
-preflight decode smoke) and an evicted slot is refilled without
-draining the batch.
+whatever is already in flight (pinned by tests/test_decode.py) and an
+evicted slot is refilled without draining the batch.
 
 What carries over from ``DynamicBatcher`` unchanged:
 
@@ -182,7 +181,7 @@ class ContinuousBatcher:
         self.n_overloaded = 0
         self.n_step_errors = 0
         #: steps whose decode batch held >= 2 sequences — the
-        #: iteration-level-sharing proof the preflight smoke asserts
+        #: iteration-level-sharing proof tests/test_decode.py asserts
         self.shared_steps = 0
         self.max_concurrent = 0
         #: speculative accounting (utils/token_accounting.py): drafted
@@ -297,7 +296,7 @@ class ContinuousBatcher:
             "draft_compiles": (dict(draft.compiles)
                                if draft is not None else None),
             "speculative": draft is not None,
-            # one arithmetic with bench_lm/bench_serving: emitted
+            # one arithmetic with tools/bench_serving.py: emitted
             # tokens are the throughput axis; rejected drafts are
             # compute, not output
             "speculation": speculative_accounting(

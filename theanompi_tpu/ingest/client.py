@@ -212,7 +212,7 @@ class RemoteBatchSource:
         #: it, and against a non-mux server every stream silently gets
         #: its own socket — so this is safe to leave on either way.
         #: ON by default (THEANOMPI_TPU_INGEST_MUX=0 opts out) since
-        #: the bench_rpc --soak byte-identity pins hold under load.
+        #: the byte-identity pins held under a sustained soak (PR 14).
         #: A v1-pinned run keeps dedicated sockets — mux streams are
         #: wire-v2 framed by construction, so honoring the operator's
         #: v1 escape hatch means never negotiating a mux hello

@@ -185,7 +185,7 @@ class ModelConfig:
     #: copy events/step).  The prefetcher stages a fresh batch per
     #: dispatch, so donation is safe on the training path; turn off
     #: when replaying the SAME staged batch through a step twice
-    #: (bench.py's pre-staged device-step leg does)
+    #: (the equivalence tests do; PERF.md §7 row c would)
     donate_batch: bool = True
     #: cross-replica BatchNorm: compute BN batch statistics over the
     #: whole DATA axis (lax.pmean inside the BN, flax ``axis_name``)
@@ -856,7 +856,7 @@ class TpuModel:
     def stacked_batch_spec(self):
         """PartitionSpec of a stacked batch (leading steps/microbatch
         axis unsharded, per-step axes per ``batch_partition``) — the
-        single source bench.py and ``begin_epoch`` stage with, for BOTH
+        single source ``begin_epoch`` and the tests stage with, for BOTH
         stacked cadences (``train_step_multi`` and
         ``train_step_accum``)."""
         from jax.sharding import PartitionSpec as P

@@ -43,12 +43,21 @@ _NON_MATMUL_KEYS = frozenset({"embedding", "pos_emb"})
 def _lm_train_flops(params, n_layers: int, seq_len: int, d_model: int,
                     expert_mask=None, n_experts: int = 1) -> float:
     """Trained FLOPs per SAMPLE (= per sequence) in the 2xMAC units the
-    CNN zoo and the chip-rate probes share: the standard 6·n_active
-    per trained token (fwd 2 + bwd 4) over matmul-applied params —
-    embedding/positional tables are excluded (gather + add, ~0 FLOPs)
-    — plus the attention score/PV term 12·n_layers·L²·d the
-    param-proportional term misses.  Computed from the REAL param count
-    so CLI-resized and sharded variants stay honest; with top-1 routing
+    CNN zoo shares: the standard 6·n_active per trained token (fwd 2 +
+    bwd 4) over matmul-applied params — embedding/positional tables are
+    excluded (gather + add, ~0 FLOPs) — plus the attention score/PV
+    term the param-proportional term misses, counted CAUSALLY:
+    position t attends to t+1 keys, so QK^T and PV cost
+    2·2·d·s(s+1)/2 forward a layer, x3 with the backward =
+    6·n_layers·d·s(s+1).  That is the work the mask leaves and, since
+    the kernel visits only the tiles the mask leaves (ops/attention.py),
+    close to the work done; a whole s x s product would count twice
+    this, the masked half of it useless.  The benchmark's
+    ``benchmarks/flops/lm_decoder.py`` counts the same way, so
+    ``tflops_per_shard`` and ``mfu.tok`` rest on one count
+    (tests/test_flops_agree.py).  Computed from the REAL param count
+    (biases and norm scales ride along, under 0.1% at GPT-2-medium) so
+    CLI-resized and sharded variants stay honest; with top-1 routing
     only 1/n_experts of each expert tensor is active per token (pass
     the MoE's ``expert_mask``)."""
     from jax import tree_util as jtu
@@ -64,7 +73,7 @@ def _lm_train_flops(params, n_layers: int, seq_len: int, d_model: int,
             continue
         active += int(leaf.size) // (n_experts if is_exp else 1)
     return float(6 * active * seq_len
-                 + 12 * n_layers * seq_len * seq_len * d_model)
+                 + 6 * n_layers * d_model * seq_len * (seq_len + 1))
 
 
 class Block(nn.Module):

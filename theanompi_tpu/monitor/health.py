@@ -5,8 +5,8 @@ The r04 bench spent 240 s wedged in device init with zero structured
 signal about where; its only output was silence.  The heartbeat closes
 that class of blind spot: a reporter thread writes a small per-rank
 JSON file every few seconds carrying (phase, step, seconds since last
-progress), so any outside observer — an operator, the preflight gate,
-a cluster babysitter — can distinguish "slow" from "stuck" without
+progress), so any outside observer — an operator, a cluster
+babysitter — can distinguish "slow" from "stuck" without
 attaching a debugger.  The same thread runs the watchdog: when no
 progress has been reported for ``stall_after`` seconds it names the
 stuck phase on stderr (once per stall episode, not every tick) and

@@ -241,8 +241,8 @@ class MetricsRegistry:
     def write_jsonl(self, path: str) -> str:
         """Atomically (re)write the snapshot as JSONL — one series per
         line.  Overwrites: the file is the LATEST state, not an append
-        log (watchdogs and the preflight smoke read it whole; history
-        lives in the Recorder's per-epoch JSONL)."""
+        log (watchdogs read it whole; history lives in the Recorder's
+        per-epoch JSONL)."""
         snap = self.snapshot()
         atomic_write_text(path, "".join(json.dumps(rec) + "\n"
                                         for rec in snap))

@@ -57,9 +57,10 @@ Scope notes:
   bare ``pallas_call`` is traced and lowered to its Mosaic module
   again at every call site, on every start, before any cache key
   exists: PERF.md §6, PR 28.)
-* ``impl='auto'``: Pallas on TPU, XLA elsewhere; force with
-  ``THEANOMPI_TPU_ATTN_IMPL=pallas|xla`` (interpret mode, CPU platform
-  only, makes the Pallas path unit-testable — tests/test_ops.py).
+* ``impl=None`` or ``'auto'``: Pallas on TPU, XLA elsewhere; a caller
+  forces one with ``impl='pallas'|'xla'`` (interpret mode, CPU platform
+  only, makes the Pallas path unit-testable — tests/test_ops.py;
+  ``TransformerLM_TP`` passes ``'xla'`` under GSPMD).
   Every choice made from a shape is logged once per shape at trace
   time (logger ``theanompi_tpu.ops.attention``; a warning when a TPU
   run takes the XLA form), so no path is taken quietly: ``pallas
@@ -71,10 +72,10 @@ Scope notes:
   its innermost grid axis and accumulates their dk/dv in the same
   VMEM scratch.  The XLA fallback repeats k/v (it is the fallback).
 * The q block and the key tile are chosen per shape (``_q_block``,
-  ``_key_tile``): the configured ``THEANOMPI_TPU_ATTN_QBLOCK`` (512),
-  else its halves down to 128, the largest that divides the length
-  (and, for the q block, keeps BOTH passes inside the VMEM budget); a
-  length shorter than the block is one block.  A visit costs ~0.35 us
+  ``_key_tile``): ``_Q_BLOCK`` (512), else its halves down to 128, the
+  largest that divides the length (and, for the q block, keeps BOTH
+  passes inside the VMEM budget); a length shorter than the block is
+  one block.  A visit costs ~0.35 us
   whatever its size (two dependent matmuls and a softmax between them,
   nothing to overlap in a rolled loop), so on the chip 512 x 512 beats
   every smaller plan at both benchmark shapes although 256 x 256 skips
@@ -98,7 +99,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import os
 from typing import NamedTuple
 
 import jax
@@ -116,19 +116,16 @@ _log = logging.getLogger(__name__)
 # The single source — parallel/sequence.py imports it.
 _MASK_NEG = -1e30
 #: what one program of either pass may hold in VMEM: the compiler's
-#: own scoped limit on a v5e, against estimates that count what the
-#: pipeline really holds (``_fits_vmem*``).
-#: Env-tunable (THEANOMPI_TPU_ATTN_VMEM_MB / _ATTN_QBLOCK) so on-chip
-#: block-size sweeps need no code edits.
-_VMEM_BUDGET_BYTES = int(float(os.environ.get(
-    "THEANOMPI_TPU_ATTN_VMEM_MB", "16")) * 1024 * 1024)
-if _VMEM_BUDGET_BYTES <= 0:
-    raise ValueError("THEANOMPI_TPU_ATTN_VMEM_MB must be positive — 0 "
-                     "would silently route every shape to the XLA path")
-_Q_BLOCK = int(os.environ.get("THEANOMPI_TPU_ATTN_QBLOCK", "512"))
-if _Q_BLOCK < 8 or _Q_BLOCK % 8:
-    raise ValueError(f"THEANOMPI_TPU_ATTN_QBLOCK must be a positive "
-                     f"multiple of 8 (sublane tiling), got {_Q_BLOCK}")
+#: own scoped limit on a v5e (16 MiB), against estimates that count
+#: what the pipeline really holds (``_fits_vmem*``).
+_VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+#: the largest q block and key tile a plan takes.  Graded on the chip
+#: (PERF.md §6, PR 29; fwd+bwd of one layer): at (8, 1024, 16, 64)
+#: 512 x 512 reads 1.660 ms against 2.311 (256 x 256), 2.044
+#: (256 x 512), 1.916 (512 x 256) and 1.821 (1024 x 1024); at
+#: (4, 2048, 8|2, 128) 1.320 against 2.051 (256 x 256) and 1.443
+#: (1024 x 1024).
+_Q_BLOCK = 512
 
 
 def block_scores(q, k, scale):
@@ -420,7 +417,7 @@ def _resolve_impl(impl: str | None, q, k,
                   plan: TilePlan | None = None) -> str:
     """``plan``: the call's own; None = that of a causal mask over the
     default positions."""
-    impl = impl or os.environ.get("THEANOMPI_TPU_ATTN_IMPL", "auto")
+    impl = impl or "auto"
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
     if impl != "auto":

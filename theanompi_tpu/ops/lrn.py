@@ -19,8 +19,6 @@ uses alpha directly).
 
 from __future__ import annotations
 
-import os
-
 import jax
 import jax.numpy as jnp
 
@@ -57,16 +55,15 @@ def lrn(
 ) -> jax.Array:
     """Cross-channel LRN for NHWC input.
 
-    ``impl``: 'auto' (default), 'xla' (composed ops, fused by the
-    compiler) or 'pallas' (VMEM-tiled kernel with analytic VJP,
-    ops/lrn_pallas.py); default from the ``THEANOMPI_TPU_LRN_IMPL``
-    env var.  'auto' picks pallas on TPU and xla elsewhere
+    ``impl``: 'auto' (None, the default), 'xla' (composed ops, fused by
+    the compiler) or 'pallas' (VMEM-tiled kernel with analytic VJP,
+    ops/lrn_pallas.py).  'auto' picks pallas on TPU and xla elsewhere
     (interpret-mode pallas is for tests on the CPU platform).  There
     is no compile probe and no fallback: a refused kernel raises.
     """
     if x.ndim != 4:
         raise ValueError(f"lrn expects NHWC, got shape {x.shape}")
-    impl = impl or os.environ.get("THEANOMPI_TPU_LRN_IMPL", "auto")
+    impl = impl or "auto"
     if impl == "auto":
         # no probe, no fallback: a kernel the compiler refuses is a
         # loud error on the chip (chip_smoke.py compiles it at the

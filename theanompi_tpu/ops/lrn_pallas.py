@@ -1,7 +1,7 @@
 """Pallas TPU kernel for cross-channel LRN (forward + custom VJP).
 
 This is the TPU default for ``ops.lrn`` (see ops/lrn.py for its
-on-chip record; tools/bench_lrn.py times it).  It tiles the flattened (N*H*W, C) view into VMEM
+on-chip record).  It tiles the flattened (N*H*W, C) view into VMEM
 blocks, computes the windowed squared-sum on the VPU in one pass, and
 backs it with an analytic VJP so the backward pass reuses the same
 kernel shape instead of differentiating through the shift-and-add
@@ -12,7 +12,7 @@ chain (W^T is the adjoint window — equal to W for odd n):
 
 Runs in interpret mode on the CPU platform (ops/pallas_mode.py) so the
 numerics are unit-testable on the CPU mesh.  Select explicitly with
-``ops.lrn(..., impl=...)`` or the ``THEANOMPI_TPU_LRN_IMPL`` env var.
+``ops.lrn(..., impl=...)``.
 """
 
 from __future__ import annotations

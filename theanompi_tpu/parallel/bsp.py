@@ -112,8 +112,9 @@ def _donate_argnums(donate: bool, donate_batch: bool) -> tuple[int, ...]:
     it, so the cadences donate it by default — the prefetcher stages a
     fresh batch per dispatch and never touches one after yielding it.
     ``donate_batch`` exists for callers that deliberately replay one
-    staged batch (bench.py's device-step leg; equivalence tests that
-    re-feed a stacked batch to a second step builder)."""
+    staged batch (the equivalence tests that re-feed a stacked batch to
+    a second step builder; a device-resident replay cell, PERF.md §7
+    row c)."""
     if not donate:
         return ()
     return (0, 1) if donate_batch else (0,)

@@ -361,8 +361,8 @@ def _shard_transports(addresses: Sequence[str]) -> list | None:
     socket — halving the router's fd count — which the selector loop's
     control-pool routing of ``shard_freeze``/``shard_release`` makes
     deadlock-free (see ``ShardedServiceClient``).  ON by default
-    (``THEANOMPI_TPU_SHARD_MUX=0`` opts out) since the ``bench_rpc
-    --soak`` byte-identity pins hold under sustained load; against a
+    (``THEANOMPI_TPU_SHARD_MUX=0`` opts out) since the byte-identity
+    pins held under a sustained soak (PR 14); against a
     non-mux server the transports silently degrade to dedicated
     sockets, so the default is safe either way."""
     if os.environ.get("THEANOMPI_TPU_SHARD_MUX", "1") != "1":
@@ -614,7 +614,7 @@ class ShardedASGD(ShardedServiceClient):
 
 
 # ---------------------------------------------------------------------------
-# Shard fleet supervision (tmlocal --shards K, bench, preflight smoke)
+# Shard fleet supervision (tmlocal --shards K)
 # ---------------------------------------------------------------------------
 
 

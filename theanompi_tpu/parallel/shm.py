@@ -58,8 +58,7 @@ A peer dying mid-lease is swept by the arena owner: unacked leases
 expire after ``THEANOMPI_TPU_SHM_LEASE_S`` and are unlinked; an OWNER
 killed outright leaves ``tmshm_<pid>_*`` files that
 :func:`sweep_orphans` reclaims by liveness-probing the embedded pid
-(run at arena creation, by the conftest segment fence, and by the
-bench kill leg).
+(run at arena creation and by the conftest segment fence).
 """
 
 from __future__ import annotations

@@ -41,8 +41,7 @@ NOT pickle — with a last-resort pickle escape that is disabled by
 default on the server side of the v2 path (see ``WireOptions``).
 
 ``parallel/service.py`` negotiates v2 at HMAC-handshake time and
-falls back to v1 pickle for old peers; ``tools/bench_exchange.py``
-measures both protocols over real sockets.
+falls back to v1 pickle for old peers.
 """
 
 from __future__ import annotations

@@ -17,10 +17,10 @@ reference).  Four modules, one discipline:
 * **retry** (``retry.py``) — a reusable retry/backoff policy
   (exponential + jitter, deadline, retryable-exception classifier)
   adopted by ``ServiceClient.call`` (reconnect-with-backoff through a
-  parameter-service restart), ``Checkpointer.restore`` (transient
+  parameter-service restart) and ``Checkpointer.restore`` (transient
   read I/O; the write *fence* deliberately stays retry-free — orbax
   clears its stored async-write error after raising it once, so a
-  retried fence would mask data loss), and the bench probe loop.
+  retried fence would mask data loss).
 * **supervisor** (``supervisor.py``) — bounded restart-from-center
   supervision for the async rules' worker threads, consuming the
   monitor's StragglerDetector signal; aborts when the worker quorum is

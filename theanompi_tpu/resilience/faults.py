@@ -32,10 +32,9 @@ Spec fields:
     ``raise`` surfaces a typed server error that FAILS the trainer's
     stream fast — the client only retries typed ``Overloaded`` and
     only fails over on transport errors, so reader-death drills use a
-    real kill, e.g. ``IngestProcessGroup.kill_reader`` or the bench
-    ``--smoke`` leg) and ``ingest_pull`` (trainer-side fetch; coords
-    ``index``, ``rank`` — ``raise`` injects a trainer-side stream
-    failure).  Disaggregated serving (docs/SERVING.md "Disaggregated
+    real kill, e.g. ``IngestProcessGroup.kill_reader``) and
+    ``ingest_pull`` (trainer-side fetch; coords ``index``, ``rank`` —
+    ``raise`` injects a trainer-side stream failure).  Disaggregated serving (docs/SERVING.md "Disaggregated
     serving") adds ``router_route`` (the front-door router's
     per-request handler; coord ``op`` — ``raise`` fails a client
     stream at the router before any backend is touched) and

@@ -1,6 +1,6 @@
 """Retry/backoff policy — exponential + jitter, deadline, classifier.
 
-One policy object serves the three adopters named in docs/RESILIENCE.md:
+One policy object serves the two adopters named in docs/RESILIENCE.md:
 
 * ``ServiceClient.call`` — reconnect-with-backoff so async workers
   survive a parameter-service restart (the client drives its own
@@ -8,9 +8,7 @@ One policy object serves the three adopters named in docs/RESILIENCE.md:
   reconnect + session rejoin happens *between* attempts);
 * ``Checkpointer.restore`` — transient read-I/O retry on the resume
   path (:meth:`call`; the write fence stays retry-free — see
-  utils/checkpoint.py on why a retried fence would mask data loss);
-* ``bench.py``'s backend probe loop — :meth:`delay` replaces its
-  hand-rolled flat 30 s sleeps.
+  utils/checkpoint.py on why a retried fence would mask data loss).
 
 The policy is deliberately dependency-free and side-effect-free except
 for ``time.sleep`` in :meth:`call`; monitor counters

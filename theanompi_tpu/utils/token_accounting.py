@@ -1,9 +1,9 @@
-"""Token-throughput accounting shared by training and serving bench.
+"""Token-throughput accounting shared by training and serving.
 
-``tools/bench_lm.py`` (training tokens/s) and ``tools/bench_serving.py
---decode`` (served tokens/s) must compute the SAME quantity the same
-way, or a "serving reaches X% of training throughput" claim silently
-compares different arithmetic.  One helper, one definition:
+A training reading (tokens/s) and ``tools/bench_serving.py --decode``
+(served tokens/s) must compute the SAME quantity the same way, or a
+"serving reaches X% of training throughput" claim silently compares
+different arithmetic.  One helper, one definition:
 
 * a **token** is one position of one sequence that the model produced
   or trained on — for training, ``steps * global_batch * seq_len``

@@ -1,14 +1,16 @@
 """Op-level device-time account from a JAX profiler xplane proto.
 
-``tools/analyze_trace.py`` reads only the Perfetto ``trace.json.gz``
-export, which in the committed r3 capture carries host threads but NO
-device timeline.  The ``vm.xplane.pb`` beside it does hold the device
-planes: ``/device:TPU:0`` with an
+The Perfetto ``trace.json.gz`` export of the committed r3 capture
+carries host threads but NO device timeline.  The ``vm.xplane.pb``
+beside it does hold the device planes: ``/device:TPU:0`` with an
 "XLA Ops" line (17 790 events for 5 ResNet steps), each event carrying
 ``hlo_category``, ``flops``, ``bytes_accessed``, and the HLO text with
 shapes.  This tool turns that into the per-op MFU account (SURVEY §6 /
 §7 hard-part 2): where every slice of the step goes, at what measured
-TF/s and GB/s, and how close each slice sits to its own roofline.
+TF/s and GB/s, and how close each slice sits to its own roofline.  The
+benchmark's own reading of a step's classes is ``python3
+benchmarks/run.py --workload <cell> --seed <n> --seconds 25 --trace 1``
+(``benchmarks/trace.py``); this tool is the per-op view beneath it.
 
 Needs the TF tsl xplane proto bindings
 (``tensorflow.tsl.profiler.protobuf.xplane_pb2`` — present in this

@@ -15,8 +15,8 @@ bound.
 
 Decode (``--decode``): requests are token-generation streams against
 a ``tmlocal SERVE --decode`` server (theanompi_tpu/decode).  The
-headline numbers change axis: **tokens/s/chip** (the same accounting
-as tools/bench_lm.py — utils/token_accounting.py) and **inter-token
+headline numbers change axis: **tokens/s/chip** (accounted by
+utils/token_accounting.py) and **inter-token
 latency p50/p99** from the server's own per-token histogram, measured
 under overload when the open-loop rate exceeds capacity.  The smoke
 artifact lives at ``artifacts/BENCH_decode_smoke.json``.
@@ -1559,7 +1559,7 @@ def main(argv=None) -> int:
             **result,
         }
         if args.decode:
-            # tokens/s accounted identically to training bench_lm.py
+            # the repository's one tokens/s arithmetic
             from theanompi_tpu.utils.token_accounting import (
                 token_throughput,
             )

@@ -7,8 +7,7 @@ Loads ``theanompi_tpu.analysis`` WITHOUT executing the package
 first, so the subpackage resolves from the filesystem while the
 parent's body never runs.  The gate is therefore pure stdlib end to
 end — it runs on a cold box with a broken or absent jax install and
-can never touch (or be wedged by) a device runtime, which is the
-property preflight's first must-pass step depends on.  (The installed
+can never touch (or be wedged by) a device runtime.  (The installed
 ``tmlint`` console script imports the real package instead — same
 checkers, but it needs a working environment.)
 

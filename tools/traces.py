@@ -21,8 +21,7 @@ Usage:
         [--trace ID] [--min-spans 2] [--require-procs N]
         [--require-zero-orphans]
 
-Exit status: 0, or 1 when a ``--require-*`` assertion fails (the
-preflight collector smoke drives these).
+Exit status: 0, or 1 when a ``--require-*`` assertion fails.
 """
 
 from __future__ import annotations
@@ -241,7 +240,7 @@ def main(argv=None) -> int:
                     help="idle-all-workers gap threshold (default 50)")
     ap.add_argument("--require-procs", type=int, default=0,
                     help="exit 1 unless some trace spans >= N "
-                         "processes with zero orphans (preflight)")
+                         "processes with zero orphans")
     ap.add_argument("--require-zero-orphans", action="store_true",
                     help="exit 1 if any printed trace has orphans")
     args = ap.parse_args(argv)

@@ -107,11 +107,14 @@ def print_report(rep: dict) -> None:
 EXPECTED_MD = """\
 # Expected deltas for the ResNet-50 step's levers
 
-Committed BEFORE the chip run that grades them.  Capture a
-before/after pair of traces with `tools/perf_probe.py --batch 128
---steps 20 --variant uint8 --trace DIR` (the `after` leg adds the
-lever: `--bn-act-impl pallas` or `--xla-flags ...`), then grade this
-table with
+Committed BEFORE the chip run that grades them.  `python3
+benchmarks/run.py --workload resnet50_b128_x1 --seed N --seconds 25
+--trace 1` reads the step's classes (`conv_share`, `breakdown`) and the
+end-to-end rate for either leg.  For the per-op account capture a
+before/after pair of profiles with `THEANOMPI_TPU_PROFILE=DIR python -m
+theanompi_tpu.launcher BSP -m resnet50 --epochs 1` (the `after` leg
+adds the lever: `--set bn_act_impl=pallas`, or `XLA_FLAGS=...` in the
+environment), then grade this table with
 
     python tools/analyze_xplane.py DIR/before --copies --out /tmp/b.json
     python tools/analyze_xplane.py DIR/after  --copies --out /tmp/a.json
@@ -135,14 +138,12 @@ removed it with its knob (docs/KERNELS.md).
 
 **Not graded by a single-step pair — staged-batch donation
 (`donate_batch`, parallel/bsp.py).** It only changes the stacked
-(k>1 / grad-accum) programs, and every batch-replaying harness
-(perf_probe, bench.py's device leg) necessarily opts out with
-`donate_batch=False` — a replayed batch cannot be donated.  Grade that
-lever from a prefetcher-fed k>1 `run_bsp_session` run — e.g.
-`THEANOMPI_TPU_PROFILE=dir python -m theanompi_tpu.launcher BSP -m
-cifar10 --epochs 1 --set steps_per_call=4`.  (NOT bench.py: both its
-legs reuse ONE compiled program whose batch donation is off because
-leg 1 replays staged batches.)  Until then the donation is asserted
+(k>1 / grad-accum) programs, and a harness that replays one staged
+batch necessarily opts out with `donate_batch=False` — a replayed
+batch cannot be donated.  Grade that lever from a prefetcher-fed k>1
+`run_bsp_session` run — e.g. `THEANOMPI_TPU_PROFILE=dir python -m
+theanompi_tpu.launcher BSP -m cifar10 --epochs 1 --set
+steps_per_call=4`.  Until then the donation is asserted
 structurally by the lowering tests
 (tests/test_multi_step.py::TestStagedBatchDonation).
 
