@@ -33,11 +33,11 @@ def _load(*parts):
 
 
 def _model(devices=1, batch_size=2, held=(0, 2), dtype="float32",
-           remat=False, **overrides):
+           remat=False, label_smoothing=0.0, **overrides):
     config = ModelConfig(batch_size=batch_size, optimizer="adamw",
                          learning_rate=3e-3, weight_decay=0.01,
                          lr_schedule="constant", compute_dtype=dtype,
-                         remat=remat)
+                         remat=remat, label_smoothing=label_smoothing)
     return zaya.ZayaLM(config=config,
                        mesh=data_mesh(devices, jax.devices()[:devices]),
                        verbose=False, held_experts=list(held),
@@ -174,15 +174,29 @@ def test_eval_reports_the_training_loss():
     assert 0.0 <= float(metrics["error"]) <= 1.0
 
 
+def test_label_smoothing_is_train_time_only():
+    """``config.label_smoothing`` reaches the blocked loss in training
+    (``softmax_cross_entropy``'s smoothing over the tied head's logits)
+    and not in validation."""
+    plain, smoothed = _model(), _model(label_smoothing=0.1)
+    batch = next(plain.data.train_batches(0, 2))
+    params, state = plain.state.params, plain.state.model_state
+    h, _ = plain.module.apply({"params": params, **state}, batch[0])
+    logits = h.reshape(-1, h.shape[-1]) @ params["embed"]["embedding"].T
+    np.testing.assert_allclose(
+        smoothed.loss_fn(params, state, batch, None)[0],
+        L.softmax_cross_entropy(logits, batch[1].reshape(-1), 0.1),
+        rtol=1e-5)
+    np.testing.assert_allclose(
+        smoothed.eval_fn(params, state, batch)["loss"],
+        plain.loss_fn(params, state, batch, None)[0], rtol=1e-6)
+
+
 def test_what_the_class_refuses():
     with pytest.raises(ValueError, match="even number of key/value heads"):
         _model(n_kv_heads=1)
     with pytest.raises(ValueError, match="not among the router's 4"):
         _model(held=(3, 2))
-    config = ModelConfig(batch_size=2, label_smoothing=0.1)
-    with pytest.raises(ValueError, match="no label smoothing"):
-        zaya.ZayaLM(config=config, mesh=data_mesh(1, jax.devices()[:1]),
-                    verbose=False, **TINY)
 
 
 def test_the_models_flop_count_is_the_benchmarks():
@@ -222,8 +236,8 @@ def test_the_tied_loss_in_blocks_is_the_whole_loss(block):
     h = jax.random.normal(jax.random.fold_in(key, 1), (96, 16))
     table = jax.random.normal(jax.random.fold_in(key, 2), (50, 16))
     labels = jnp.argmax(h @ table.T, -1).at[::3].set(7)
-    blocked = lambda h, t: L.tied_softmax_cross_entropy(  # noqa: E731
-        h, t, labels, block_tokens=block)
+    blocked = lambda h, t: L.blocked_softmax_cross_entropy(  # noqa: E731
+        h, t, None, labels, vocab_axis=0, block_tokens=block)
     whole = lambda h, t: L.softmax_cross_entropy(h @ t.T, labels)  # noqa: E731
     loss, err = blocked(h, table)
     np.testing.assert_allclose(loss, whole(h, table), rtol=1e-6)
@@ -240,7 +254,8 @@ def test_the_tied_loss_keeps_no_whole_logits():
     table = jnp.zeros((50, 16))
     labels = jnp.zeros((96,), jnp.int32)
     jaxpr = jax.make_jaxpr(jax.grad(
-        lambda h, t: L.tied_softmax_cross_entropy(h, t, labels, 32)[0],
+        lambda h, t: L.blocked_softmax_cross_entropy(
+            h, t, None, labels, vocab_axis=0, block_tokens=32)[0],
         argnums=(0, 1)))(h, table)
 
     def shapes(jaxpr):
@@ -254,6 +269,7 @@ def test_the_tied_loss_keeps_no_whole_logits():
     assert (32, 50) in seen and (96, 50) not in seen
     # the table's gradient comes back in the table's dtype
     grads = jax.grad(
-        lambda h, t: L.tied_softmax_cross_entropy(h, t, labels, 32)[0],
+        lambda h, t: L.blocked_softmax_cross_entropy(
+            h, t, None, labels, vocab_axis=0, block_tokens=32)[0],
         argnums=(0, 1))(h, table)
     assert grads[0].dtype == jnp.bfloat16 and grads[1].dtype == jnp.float32

@@ -16,6 +16,7 @@ FLOPs on the MXU in bf16 while keeping fp32 master params.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
@@ -28,6 +29,8 @@ from theanompi_tpu.ops.fused_bn import scale_bias_act
 from theanompi_tpu.ops.lrn import lrn
 
 Dtype = Any
+
+_log = logging.getLogger(__name__)
 
 # -- reference-era initializers (gaussian std + constant bias) --
 
@@ -322,85 +325,163 @@ def _token_block(n: int, block: int) -> int:
     return block
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _tied_xent_sums(h, table, labels, block: int):
-    """Summed token cross-entropy and summed top-1 misses of
-    ``logits = h @ table^T``, a block of tokens at a time."""
-    table_c = table.astype(h.dtype)
+@functools.lru_cache(maxsize=None)
+def _log_block_plan(tokens: int, block: int, vocab: int) -> None:
+    """The blocked loss always engages, so its counter is its plan: one
+    line a shape (the cache is the once-a-shape memory; trace time
+    only), as ``ops/attention.py`` says "n of m tiles"."""
+    _log.info("loss in %d blocks of %d tokens x %d", tokens // block, block,
+              vocab)
 
-    def one(args):
+
+def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
+                 smoothing: float, with_grad: bool):
+    """The one scan over token blocks behind
+    ``blocked_softmax_cross_entropy``: a block's float32 logits, its
+    summed loss and top-1 misses and, ``with_grad``, its gradients in
+    the same pass (``softmax - target`` gives the block's ``d_h`` and
+    its part of the weight's and the bias's gradient, accumulated in
+    float32 across blocks), so that no block's logits outlive it.
+
+    The weight is contracted as it lies: ``vocab_axis`` only picks the
+    dimension numbers of the three products, so neither layout pays a
+    transpose of the weight or of its float32 gradient."""
+    d = h.shape[-1]
+    vocab = weight.shape[vocab_axis]
+    w_c = weight.astype(h.dtype)
+    over_d = (((1,), (1 - vocab_axis,)), ((), ()))
+    over_vocab = (((1,), (vocab_axis,)), ((), ()))
+    over_tokens = (((0,), (0,)), ((), ()))
+    dot = functools.partial(lax.dot_general,
+                            preferred_element_type=jnp.float32)
+
+    def one(carry, args):
         hb, yb = args
-        logits = jnp.dot(hb, table_c.T, preferred_element_type=jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, yb[:, None], axis=1)[:, 0]
-        miss = (jnp.argmax(logits, axis=-1) != yb).astype(jnp.float32)
-        return jnp.sum(lse - picked), jnp.sum(miss)
-
-    losses, misses = lax.map(one, (h.reshape(-1, block, h.shape[-1]),
-                                   labels.reshape(-1, block)))
-    return losses.sum(), misses.sum()
-
-
-def _tied_xent_sums_fwd(h, table, labels, block: int):
-    """The loss is the end of the program, so its gradient is taken in
-    the same pass over the blocks: ``softmax - onehot`` of a block's
-    logits gives that block's ``dh`` and its part of ``dtable`` (fp32
-    accumulation across blocks), and no block's logits outlive it.
-    The backward only scales by the incoming cotangent."""
-    table_c = table.astype(h.dtype)
-    vocab, d = table.shape
-
-    def one(d_table, args):
-        hb, yb = args
-        logits = jnp.dot(hb, table_c.T, preferred_element_type=jnp.float32)
+        logits = dot(hb, w_c, over_d)
+        if bias is not None:
+            logits = logits + bias.astype(jnp.float32)
         top = jnp.max(logits, axis=-1, keepdims=True)
         exp = jnp.exp(logits - top)
         total = jnp.sum(exp, axis=-1, keepdims=True)
-        hit = lax.broadcasted_iota(jnp.int32, logits.shape, 1) == yb[:, None]
+        column = lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        hit = column == yb[:, None]
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
-        loss = jnp.sum(jnp.log(total[:, 0]) + top[:, 0] - picked)
-        miss = jnp.sum((jnp.argmax(logits, axis=-1) != yb)
-                       .astype(jnp.float32))
-        d_logits = (exp / total - hit).astype(h.dtype)          # (blk, V)
-        d_hb = jnp.dot(d_logits, table_c,
-                       preferred_element_type=jnp.float32).astype(h.dtype)
-        d_table = d_table + lax.dot_general(
-            d_logits, hb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return d_table, (loss, miss, d_hb)
+        lse = jnp.log(total[:, 0]) + top[:, 0]
+        loss = jnp.sum(lse - picked)
+        if smoothing:
+            # (1 - eps) * nll - eps * mean_k logp_k, logp = logits - lse
+            loss = ((1.0 - smoothing) * loss
+                    + smoothing * jnp.sum(lse - jnp.mean(logits, axis=-1)))
+        # argmax's answer (the FIRST index of the maximum) as a plain
+        # float32 min-reduce, which XLA fuses with the row's other
+        # reductions; ``jnp.argmax`` is a variadic reduce and kept a
+        # pass over the block's logits to itself, as an int32 min did
+        # (2.19 ms a step at GPT-2-medium's 412 M logits, PERF.md
+        # section 6 PR 31).  A row with a NaN counts as a miss; its
+        # loss is NaN anyway
+        first = jnp.min(jnp.where(logits == top, column.astype(jnp.float32),
+                                  float(vocab)), axis=-1)
+        miss = jnp.sum((first != yb.astype(jnp.float32)).astype(jnp.float32))
+        if not with_grad:
+            return carry, (loss, miss)
+        d_weight, d_bias = carry
+        target = hit.astype(jnp.float32)
+        if smoothing:
+            target = (1.0 - smoothing) * target + smoothing / vocab
+        d_logits32 = exp / total - target                      # (blk, V)
+        d_logits = d_logits32.astype(h.dtype)
+        d_hb = dot(d_logits, w_c, over_vocab).astype(h.dtype)
+        # the operands' order gives the gradient the weight's layout
+        pair = (d_logits, hb) if vocab_axis == 0 else (hb, d_logits)
+        d_weight = d_weight + dot(*pair, over_tokens)
+        if bias is not None:
+            d_bias = d_bias + jnp.sum(d_logits32, axis=0)
+        return (d_weight, d_bias), (loss, miss, d_hb)
 
-    d_table, (losses, misses, d_h) = lax.scan(
-        one, jnp.zeros((vocab, d), jnp.float32),
-        (h.reshape(-1, block, d), labels.reshape(-1, block)))
-    return (losses.sum(), misses.sum()), (d_h.reshape(h.shape), d_table)
+    carry = None
+    if with_grad:
+        carry = (jnp.zeros(weight.shape, jnp.float32),
+                 None if bias is None else jnp.zeros(bias.shape, jnp.float32))
+    return lax.scan(one, carry, (h.reshape(-1, block, d),
+                                 labels.reshape(-1, block)))
 
 
-def _tied_xent_sums_bwd(block: int, res, cotangents):
-    del block
-    d_h, d_table = res
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _xent_sums(h, weight, bias, labels, block: int, vocab_axis: int,
+               smoothing: float):
+    """Summed token cross-entropy and summed top-1 misses."""
+    _, (losses, misses) = _xent_blocks(h, weight, bias, labels, block,
+                                       vocab_axis, smoothing, with_grad=False)
+    return losses.sum(), misses.sum()
+
+
+def _xent_sums_fwd(h, weight, bias, labels, block: int, vocab_axis: int,
+                   smoothing: float):
+    """The loss is the end of the program, so its gradient is taken in
+    the forward's pass over the blocks; the backward only scales by the
+    incoming cotangent."""
+    (d_weight, d_bias), (losses, misses, d_h) = _xent_blocks(
+        h, weight, bias, labels, block, vocab_axis, smoothing, with_grad=True)
+    return ((losses.sum(), misses.sum()),
+            (d_h.reshape(h.shape), d_weight.astype(weight.dtype),
+             None if bias is None else d_bias.astype(bias.dtype)))
+
+
+def _xent_sums_bwd(block: int, vocab_axis: int, smoothing: float, res,
+                   cotangents):
+    del block, vocab_axis, smoothing
+    d_h, d_weight, d_bias = res
     g = cotangents[0]                    # the miss count carries none
-    return ((g * d_h).astype(d_h.dtype), g * d_table, None)
+    return ((g * d_h).astype(d_h.dtype), (g * d_weight).astype(d_weight.dtype),
+            None if d_bias is None else (g * d_bias).astype(d_bias.dtype),
+            None)
 
 
-_tied_xent_sums.defvjp(_tied_xent_sums_fwd, _tied_xent_sums_bwd)
+_xent_sums.defvjp(_xent_sums_fwd, _xent_sums_bwd)
+
+#: tokens a block of the blocked loss (the largest divisor of the token
+#: count at or under it); chip readings in PERF.md §6 PR 31
+_LOSS_BLOCK_TOKENS = 2048
 
 
-def tied_softmax_cross_entropy(h: jax.Array, table: jax.Array,
-                               labels: jax.Array,
-                               block_tokens: int = 2048):
-    """Mean token cross-entropy and top-1 error of a head TIED to the
-    embedding, ``logits = h @ table^T``, without ever holding the whole
+def blocked_softmax_cross_entropy(h: jax.Array, weight: jax.Array,
+                                  bias: jax.Array | None, labels: jax.Array,
+                                  *, vocab_axis: int,
+                                  label_smoothing: float = 0.0,
+                                  block_tokens: int = _LOSS_BLOCK_TOKENS):
+    """Mean token cross-entropy and top-1 error of an output head,
+    ``logits = h @ W (+ bias)``, without ever holding the whole
     ``(tokens, vocab)`` logits: the tokens pass in blocks (the largest
     divisor of their count up to ``block_tokens``), forward and
-    gradient in one pass (``_tied_xent_sums_fwd``).
+    gradient in one pass under a ``custom_vjp`` (``_xent_blocks``).
 
-    ``h (tokens, d)`` in the compute dtype, ``table (vocab, d)`` the
-    master weights (cast to ``h.dtype`` for the products; its gradient
-    comes back in its own dtype, accumulated in float32), ``labels
-    (tokens,)`` integer ids.  Returns ``(loss, error)``, float32."""
+    * ``h (tokens, d)`` in the compute dtype; its gradient comes back
+      in that dtype.
+    * ``weight``: the head's MASTER weights, cast to ``h.dtype`` for the
+      products, in the layout the caller's tree has: ``vocab_axis=0``
+      for a ``(vocab, d)`` table (a head tied to the embedding,
+      ``ZayaLM``), ``vocab_axis=1`` for a ``(d, vocab)`` kernel
+      (``nn.Dense``, ``TransformerLM``).  That is a fact of the tree,
+      not a setting: neither layout is transposed.  ``bias (vocab,)``
+      or None, added to the float32 logits.  Both gradients are
+      accumulated in float32 and come back in the masters' dtypes.
+    * ``labels (tokens,)`` integer ids.
+    * ``label_smoothing=eps`` (a static Python float; no work at 0) is
+      ``softmax_cross_entropy``'s: target ``(1-eps) * onehot + eps/V``.
+
+    A block's logits leave the MXU's accumulator in float32 and stay so
+    through the softmax; ``softmax - target`` is cast to ``h.dtype``
+    for the two gradient products.  Returns ``(loss, error)``, float32
+    scalars; ``error`` is ``error_rate``'s (``argmax != label``, first
+    index on ties) and carries no gradient."""
+    if vocab_axis not in (0, 1) or weight.shape[1 - vocab_axis] != h.shape[-1]:
+        raise ValueError(f"weight {weight.shape} with vocab_axis={vocab_axis} "
+                         f"does not contract with h {h.shape}")
     n = h.shape[0]
-    loss, miss = _tied_xent_sums(h, table, labels.astype(jnp.int32),
-                                 _token_block(n, block_tokens))
+    block = _token_block(n, block_tokens)
+    _log_block_plan(n, block, weight.shape[vocab_axis])
+    loss, miss = _xent_sums(h, weight, bias, labels.astype(jnp.int32), block,
+                            vocab_axis, float(label_smoothing))
     return loss / n, miss / n
 
 

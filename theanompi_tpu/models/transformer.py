@@ -141,6 +141,16 @@ class TransformerLMNet(nn.Module):
     same parameters serve both the sharded training path (inside
     shard_map, where positions offset by the shard index) and
     unsharded init/inference.
+
+    Two exits, one parameter tree.  By default the head (``Dense_0``)
+    is applied and float32 logits come back: init, decode
+    (``decode/model.py``), the eval exports (``serving/export.py``) and
+    whoever wants a distribution over the vocabulary.  With
+    ``hidden=True`` the call stops after the final LayerNorm and hands
+    back ``h (B, T_local, d)`` in the compute dtype: ``TransformerLM``'s
+    training and validation steps, which give ``h`` and ``Dense_0``'s
+    kernel and bias to ``layers.blocked_softmax_cross_entropy`` so that
+    the ``(tokens, vocab)`` logits never exist whole.
     """
 
     vocab: int = 256
@@ -159,7 +169,7 @@ class TransformerLMNet(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, train: bool = False,
-                 seq_axis: str | None = None):
+                 seq_axis: str | None = None, hidden: bool = False):
         t_local = tokens.shape[1]
         offset = (lax.axis_index(seq_axis) * t_local
                   if seq_axis is not None else 0)
@@ -181,6 +191,8 @@ class TransformerLMNet(nn.Module):
                           self.sp_strategy, self.dtype, self.attn_impl,
                           name=f"Block_{i}")(x, seq_axis)
         x = nn.LayerNorm(dtype=self.dtype)(x)
+        if hidden:
+            return x
         logits = nn.Dense(self.vocab, kernel_init=L.xavier_init(),
                           dtype=self.dtype)(x)
         return logits.astype(jnp.float32)
@@ -262,27 +274,29 @@ class TransformerLM(TpuModel):
 
     # -- (data x seq) SPMD wiring -------------------------------------------
 
-    def loss_fn(self, params, model_state, batch, rng):
+    def _loss_and_error(self, params, batch, train: bool, rng=None):
+        """Mean token loss and top-1 error over this shard's tokens: the
+        module's hidden exit, then the head and its loss a block of
+        tokens at a time (no ``(tokens, vocab)`` logits on this path)."""
         tokens, targets = batch
-        logits = self.module.apply({"params": params}, tokens, train=True,
-                                   seq_axis=self._resolved_seq_axis(),
-                                   rngs={"dropout": rng})
-        v = logits.shape[-1]
-        loss = L.softmax_cross_entropy(logits.reshape(-1, v),
-                                       targets.reshape(-1),
-                                       self.config.label_smoothing)
-        err = L.error_rate(logits.reshape(-1, v), targets.reshape(-1))
+        h = self.module.apply({"params": params}, tokens, train=train,
+                              seq_axis=self._resolved_seq_axis(), hidden=True,
+                              rngs={"dropout": rng} if train else None)
+        head = params["Dense_0"]
+        with jax.named_scope("lm/loss"):
+            return L.blocked_softmax_cross_entropy(
+                h.reshape(-1, h.shape[-1]), head["kernel"], head["bias"],
+                targets.reshape(-1), vocab_axis=1,
+                label_smoothing=(self.config.label_smoothing if train
+                                 else 0.0))
+
+    def loss_fn(self, params, model_state, batch, rng):
+        loss, err = self._loss_and_error(params, batch, True, rng)
         return loss, (model_state, {"loss": loss, "error": err})
 
     def eval_fn(self, params, model_state, batch):
-        tokens, targets = batch
-        logits = self.module.apply({"params": params}, tokens, train=False,
-                                   seq_axis=self._resolved_seq_axis())
-        v = logits.shape[-1]
-        return {"loss": L.softmax_cross_entropy(logits.reshape(-1, v),
-                                                targets.reshape(-1)),
-                "error": L.error_rate(logits.reshape(-1, v),
-                                      targets.reshape(-1))}
+        loss, err = self._loss_and_error(params, batch, False)
+        return {"loss": loss, "error": err}
 
 
 class TransformerLM_TP(TransformerLM):

@@ -33,7 +33,8 @@ d)``::
   which is what goes on to the next.  No capacity, nothing dropped.
 * **Head**: tied to the embedding; the loss passes the tokens in blocks
   so that the ``(tokens, vocab)`` logits never exist whole
-  (``layers.tied_softmax_cross_entropy``).
+  (``layers.blocked_softmax_cross_entropy`` over the ``(vocab, d)``
+  table as it lies, the scan ``TransformerLM`` runs over its kernel).
 
 What the published ``config.json`` does not pin down (the value shift,
 the q-k mean, the temperature, the router MLP's depth and activation)
@@ -364,8 +365,6 @@ class ZayaLM(TpuModel):
             rotary_dim=int(head_dim * partial_rotary_factor),
             rope_theta=rope_theta, rms_eps=rms_norm_eps)
         super().__init__(*args, **kwargs)
-        if self.config.label_smoothing:
-            raise ValueError("ZayaLM's blocked loss has no label smoothing")
         self.train_flops_per_sample = zaya_train_flops(
             n_layers=n_layers, d_model=d_model, n_heads=n_heads,
             n_kv_heads=n_kv_heads, head_dim=head_dim, n_experts=n_experts,
@@ -401,9 +400,11 @@ class ZayaLM(TpuModel):
         else:
             h, routing = self.module.apply(variables, tokens)
         with jax.named_scope("zaya/loss"):
-            loss, err = L.tied_softmax_cross_entropy(
+            loss, err = L.blocked_softmax_cross_entropy(
                 h.reshape(-1, h.shape[-1]), params["embed"]["embedding"],
-                targets.reshape(-1))
+                None, targets.reshape(-1), vocab_axis=0,
+                label_smoothing=(self.config.label_smoothing if train
+                                 else 0.0))
         return loss, err, routing, model_state
 
     def loss_fn(self, params, model_state, batch, rng):
