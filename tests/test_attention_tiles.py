@@ -261,10 +261,12 @@ def one_chip():
 
 
 @pytest.mark.parametrize("q_shape,kv_heads,plan", [
-    # the three LM cells' shapes
+    # the four LM cells' shapes (the last is the looped model's: twice
+    # ZAYA1's batch x heads at head 128, equal head counts)
     ((8, 1024, 16, 64), 16, "q block 512, key tile 512, 3 of 4 tiles"),
     ((4, 2048, 8, 128), 2, "q block 512, key tile 512, 10 of 16 tiles"),
     ((64, 128, 16, 64), 16, "q block 128, key tile 128, 1 of 1 tiles"),
+    ((4, 2048, 16, 128), 16, "q block 512, key tile 512, 10 of 16 tiles"),
     # long lengths the budget admits, at a batch x heads where the
     # compiler asks more than the estimate (18.0 MiB at the first), and
     # one that takes a smaller q block than key tile

@@ -275,3 +275,134 @@ def test_transformer_lm_tree_and_default_exit_are_the_parents(lm):
     head = params["Dense_0"]
     np.testing.assert_allclose(h @ head["kernel"] + head["bias"], logits,
                                rtol=1e-5, atol=1e-6)
+
+
+# -- per-token weights (a looped model's exit distribution) ----------------
+
+
+def _weights():
+    """Positive, summing to 1 over the tokens, far from uniform."""
+    raw = jax.random.uniform(jax.random.key(9), (TOKENS,)) ** 3
+    return raw / raw.sum()
+
+
+def _weighted_whole(h, w, b, labels, weights, vocab_axis, smoothing):
+    """``sum_i w_i l_i`` over whole logits, float32, by autodiff."""
+    w = _as_laid_out(w, vocab_axis)
+    logits = h.astype(jnp.float32) @ w + (0.0 if b is None else b)
+    logp = jax.nn.log_softmax(logits)
+    token = -jnp.take_along_axis(logp, labels[:, None], axis=1)[:, 0]
+    if smoothing:
+        token = (1 - smoothing) * token - smoothing * jnp.mean(logp, axis=-1)
+    return jnp.sum(weights * token), token
+
+
+@pytest.mark.parametrize("block", [2048, 40])
+@pytest.mark.parametrize("vocab_axis", [0, 1])
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("smoothing", [0.0, 0.1])
+def test_weighted_blocked_loss_is_the_whole_weighted_loss(
+        block, vocab_axis, with_bias, smoothing):
+    """With ``weights``: the weighted sum, every token's own loss and
+    miss, and the gradients of ``h``, the weight, the bias and the
+    WEIGHTS (the tokens' losses) equal the composed form's to 1e-5."""
+    h, w, b, labels = _problem()
+    w = _as_laid_out(w, vocab_axis)
+    b = b if with_bias else None
+    weights = _weights()
+    blocked = lambda h, w, b, q: L.blocked_softmax_cross_entropy(  # noqa: E731
+        h, w, b, labels, vocab_axis=vocab_axis, weights=q,
+        label_smoothing=smoothing, block_tokens=block)
+    whole = lambda h, w, b, q: _weighted_whole(  # noqa: E731
+        h, w, b, labels, q, vocab_axis, smoothing)
+    loss, token_loss, token_miss = blocked(h, w, b, weights)
+    want_loss, want_token = whole(h, w, b, weights)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-5)
+    np.testing.assert_allclose(token_loss, want_token, rtol=1e-5, atol=1e-6)
+    logits = h @ _as_laid_out(w, vocab_axis) + (0.0 if b is None else b)
+    np.testing.assert_array_equal(
+        token_miss, (jnp.argmax(logits, -1) != labels).astype(jnp.float32))
+    argnums = (0, 1, 2, 3) if with_bias else (0, 1, 3)
+    got = jax.grad(lambda *a: 3.0 * blocked(*a)[0], argnums=argnums)(
+        h, w, b, weights)
+    want = jax.grad(lambda *a: 3.0 * whole(*a)[0], argnums=argnums)(
+        h, w, b, weights)
+    for a, e in zip(got, want):
+        assert a.shape == e.shape and a.dtype == e.dtype
+        np.testing.assert_allclose(a, e, rtol=1e-5, atol=1e-6)
+    # the weights' gradient IS the tokens' losses (times the cotangent)
+    np.testing.assert_allclose(got[-1], 3.0 * token_loss, rtol=1e-6)
+
+
+def test_uniform_weights_give_the_plain_mean():
+    """``weights = 1 / n`` is the unweighted loss, and the tokens' own
+    values carry no gradient of their own."""
+    h, w, b, labels = _problem()
+    uniform = jnp.full((TOKENS,), 1.0 / TOKENS)
+    plain = L.blocked_softmax_cross_entropy(h, w, b, labels, vocab_axis=1,
+                                            block_tokens=30)
+    loss, token_loss, token_miss = L.blocked_softmax_cross_entropy(
+        h, w, b, labels, vocab_axis=1, weights=uniform, block_tokens=30)
+    np.testing.assert_allclose(loss, plain[0], rtol=1e-6)
+    np.testing.assert_allclose(token_miss.mean(), plain[1])
+    through_tokens = jax.grad(lambda h: L.blocked_softmax_cross_entropy(
+        h, w, b, labels, vocab_axis=1, weights=uniform,
+        block_tokens=30)[1].sum())(h)
+    assert not np.asarray(through_tokens).any()
+    with pytest.raises(ValueError, match="one weight a token"):
+        L.blocked_softmax_cross_entropy(h, w, b, labels, vocab_axis=1,
+                                        weights=uniform[:-1])
+
+
+def test_weighted_loss_in_bf16_keeps_float32_weights_and_masters():
+    h, w, b, labels = _problem(jnp.bfloat16)
+    weights = _weights()
+    grads = jax.grad(lambda *a: L.blocked_softmax_cross_entropy(
+        a[0], a[1], None, labels, vocab_axis=1, weights=a[2],
+        block_tokens=30)[0], argnums=(0, 1, 2))(h, w, weights)
+    assert [g.dtype for g in grads] == [jnp.bfloat16, jnp.float32,
+                                        jnp.float32]
+    want = jax.grad(lambda *a: _weighted_whole(
+        a[0], a[1], None, labels, a[2], 1, 0.0)[0], argnums=(0, 1, 2))(
+        h.astype(jnp.float32), w, weights)
+    for a, e in zip(grads, want):
+        err = (jnp.linalg.norm(a.astype(jnp.float32) - e)
+               / jnp.linalg.norm(e))
+        assert float(err) < 2e-2
+
+
+#: sha256 (first 16 hex digits) of ``str(jax.make_jaxpr(...))`` of loss
+#: and gradients WITHOUT weights, taken at PR 31's commit (ddd7777, the
+#: parent of the PR that brought ``weights``) by the function below,
+#: under the installation the verify skill states: the three accepted LM
+#: cells run this program, and an optional argument of another model
+#: must not move it.  A change of JAX re-takes them from that commit.
+_UNWEIGHTED_PROGRAM = {
+    (0, False): "fdd19bec0a76bcf3", (0, True): "b8e095232e8a2878",
+    (1, False): "ee83c1b596e18ad2", (1, True): "1a561a2617c2d9b6"}
+
+
+@pytest.mark.parametrize("vocab_axis,with_bias", sorted(_UNWEIGHTED_PROGRAM))
+def test_without_weights_the_program_is_the_parents(vocab_axis, with_bias):
+    """``weights=None`` is a static branch: the jaxpr of the loss and
+    its gradients (bf16 ``h``, float32 masters, four blocks) is, to the
+    letter, what PR 31 traced for ``ZayaLM``'s tied table (axis 0, no
+    bias) and ``TransformerLM``'s kernel and bias (axis 1)."""
+    import hashlib
+
+    n, d, v = 64, 16, 40
+    h = jnp.zeros((n, d), jnp.bfloat16)
+    w = jnp.zeros((v, d) if vocab_axis == 0 else (d, v), jnp.float32)
+    b = jnp.zeros((v,), jnp.float32) if with_bias else None
+    y = jnp.zeros((n,), jnp.int32)
+
+    def f(h, w, b):
+        return L.blocked_softmax_cross_entropy(
+            h, w, b, y, vocab_axis=vocab_axis, label_smoothing=0.0,
+            block_tokens=16)
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        f, argnums=(0, 1, 2) if with_bias else (0, 1), has_aux=True))(
+        h, w, b))
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16]
+            == _UNWEIGHTED_PROGRAM[vocab_axis, with_bias])

@@ -23,6 +23,9 @@ MODEL_ZOO = {
     # a current block: compressed convolutional attention + a dropless
     # expert layer that is told which experts it holds (ZAYA1 family)
     "zaya_lm": ("theanompi_tpu.models.zaya", "ZayaLM"),
+    # a looped LM: one stack of layers run several times over shared
+    # weights, an exit gate and the head after every pass (Ouro family)
+    "ouro_lm": ("theanompi_tpu.models.ouro", "OuroLM"),
     # zoo variants (reference lasagne_model_zoo equivalents)
     "vgg19": ("theanompi_tpu.models.model_zoo", "VGG19"),
     "resnet101": ("theanompi_tpu.models.model_zoo", "ResNet101"),

@@ -334,14 +334,20 @@ def _log_block_plan(tokens: int, block: int, vocab: int) -> None:
               vocab)
 
 
-def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
-                 smoothing: float, with_grad: bool):
+def _xent_blocks(h, weight, bias, labels, weights, block: int,
+                 vocab_axis: int, smoothing: float, with_grad: bool):
     """The one scan over token blocks behind
     ``blocked_softmax_cross_entropy``: a block's float32 logits, its
     summed loss and top-1 misses and, ``with_grad``, its gradients in
     the same pass (``softmax - target`` gives the block's ``d_h`` and
     its part of the weight's and the bias's gradient, accumulated in
     float32 across blocks), so that no block's logits outlive it.
+
+    ``weights (tokens,)`` or None: with them a block's loss is ``sum_i
+    w_i l_i``, its ``d_logits`` is ``w_i (softmax - target)_i``, and
+    each token's own loss and miss come back beside the block's sums
+    (the losses are the weights' cotangent).  None is a static branch
+    that leaves the unweighted program as it was.
 
     The weight is contracted as it lies: ``vocab_axis`` only picks the
     dimension numbers of the three products, so neither layout pays a
@@ -356,7 +362,7 @@ def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
                             preferred_element_type=jnp.float32)
 
     def one(carry, args):
-        hb, yb = args
+        hb, yb, *wb = args                  # wb: [] or the block's weights
         logits = dot(hb, w_c, over_d)
         if bias is not None:
             logits = logits + bias.astype(jnp.float32)
@@ -367,11 +373,12 @@ def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
         hit = column == yb[:, None]
         picked = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
         lse = jnp.log(total[:, 0]) + top[:, 0]
-        loss = jnp.sum(lse - picked)
+        token_loss = lse - picked
         if smoothing:
             # (1 - eps) * nll - eps * mean_k logp_k, logp = logits - lse
-            loss = ((1.0 - smoothing) * loss
-                    + smoothing * jnp.sum(lse - jnp.mean(logits, axis=-1)))
+            token_loss = ((1.0 - smoothing) * token_loss
+                          + smoothing * (lse - jnp.mean(logits, axis=-1)))
+        loss = jnp.sum(wb[0] * token_loss if wb else token_loss)
         # argmax's answer (the FIRST index of the maximum) as a plain
         # float32 min-reduce, which XLA fuses with the row's other
         # reductions; ``jnp.argmax`` is a variadic reduce and kept a
@@ -381,14 +388,19 @@ def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
         # loss is NaN anyway
         first = jnp.min(jnp.where(logits == top, column.astype(jnp.float32),
                                   float(vocab)), axis=-1)
-        miss = jnp.sum((first != yb.astype(jnp.float32)).astype(jnp.float32))
+        token_miss = (first != yb.astype(jnp.float32)).astype(jnp.float32)
+        # per token only where the caller weighs tokens
+        sums = (loss, jnp.sum(token_miss), *((token_loss, token_miss)
+                                             if wb else ()))
         if not with_grad:
-            return carry, (loss, miss)
+            return carry, sums
         d_weight, d_bias = carry
         target = hit.astype(jnp.float32)
         if smoothing:
             target = (1.0 - smoothing) * target + smoothing / vocab
         d_logits32 = exp / total - target                      # (blk, V)
+        if wb:
+            d_logits32 = wb[0][:, None] * d_logits32
         d_logits = d_logits32.astype(h.dtype)
         d_hb = dot(d_logits, w_c, over_vocab).astype(h.dtype)
         # the operands' order gives the gradient the weight's layout
@@ -396,45 +408,56 @@ def _xent_blocks(h, weight, bias, labels, block: int, vocab_axis: int,
         d_weight = d_weight + dot(*pair, over_tokens)
         if bias is not None:
             d_bias = d_bias + jnp.sum(d_logits32, axis=0)
-        return (d_weight, d_bias), (loss, miss, d_hb)
+        return (d_weight, d_bias), (*sums, d_hb)
 
     carry = None
     if with_grad:
         carry = (jnp.zeros(weight.shape, jnp.float32),
                  None if bias is None else jnp.zeros(bias.shape, jnp.float32))
-    return lax.scan(one, carry, (h.reshape(-1, block, d),
-                                 labels.reshape(-1, block)))
+    blocks = (h.reshape(-1, block, d), labels.reshape(-1, block))
+    if weights is not None:
+        blocks += (weights.astype(jnp.float32).reshape(-1, block),)
+    return lax.scan(one, carry, blocks)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _xent_sums(h, weight, bias, labels, block: int, vocab_axis: int,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _xent_sums(h, weight, bias, labels, weights, block: int, vocab_axis: int,
                smoothing: float):
-    """Summed token cross-entropy and summed top-1 misses."""
-    _, (losses, misses) = _xent_blocks(h, weight, bias, labels, block,
-                                       vocab_axis, smoothing, with_grad=False)
-    return losses.sum(), misses.sum()
+    """Summed token cross-entropy and summed top-1 misses; with
+    ``weights`` the sum is weighted and each token's loss and miss
+    follow, ``(tokens,)`` each."""
+    _, (loss, miss, *per_token) = _xent_blocks(
+        h, weight, bias, labels, weights, block, vocab_axis, smoothing,
+        with_grad=False)
+    return (loss.sum(), miss.sum(), *(x.reshape(-1) for x in per_token))
 
 
-def _xent_sums_fwd(h, weight, bias, labels, block: int, vocab_axis: int,
-                   smoothing: float):
-    """The loss is the end of the program, so its gradient is taken in
-    the forward's pass over the blocks; the backward only scales by the
-    incoming cotangent."""
-    (d_weight, d_bias), (losses, misses, d_h) = _xent_blocks(
-        h, weight, bias, labels, block, vocab_axis, smoothing, with_grad=True)
-    return ((losses.sum(), misses.sum()),
+def _xent_sums_fwd(h, weight, bias, labels, weights, block: int,
+                   vocab_axis: int, smoothing: float):
+    """The loss is the end of the program (or, weighted, a term of its
+    last sum), so its gradient is taken in the forward's pass over the
+    blocks; the backward only scales by the incoming cotangent.  The
+    tokens' own losses are the weights' gradient."""
+    (d_weight, d_bias), (loss, miss, *per_token, d_h) = _xent_blocks(
+        h, weight, bias, labels, weights, block, vocab_axis, smoothing,
+        with_grad=True)
+    per_token = tuple(x.reshape(-1) for x in per_token)
+    return ((loss.sum(), miss.sum(), *per_token),
             (d_h.reshape(h.shape), d_weight.astype(weight.dtype),
-             None if bias is None else d_bias.astype(bias.dtype)))
+             None if bias is None else d_bias.astype(bias.dtype),
+             per_token[0].astype(weights.dtype) if per_token else None))
 
 
 def _xent_sums_bwd(block: int, vocab_axis: int, smoothing: float, res,
                    cotangents):
     del block, vocab_axis, smoothing
-    d_h, d_weight, d_bias = res
-    g = cotangents[0]                    # the miss count carries none
+    d_h, d_weight, d_bias, d_weights = res
+    g = cotangents[0]     # misses and the tokens' own values carry none
     return ((g * d_h).astype(d_h.dtype), (g * d_weight).astype(d_weight.dtype),
             None if d_bias is None else (g * d_bias).astype(d_bias.dtype),
-            None)
+            None,
+            None if d_weights is None else (g * d_weights).astype(
+                d_weights.dtype))
 
 
 _xent_sums.defvjp(_xent_sums_fwd, _xent_sums_bwd)
@@ -447,6 +470,7 @@ _LOSS_BLOCK_TOKENS = 2048
 def blocked_softmax_cross_entropy(h: jax.Array, weight: jax.Array,
                                   bias: jax.Array | None, labels: jax.Array,
                                   *, vocab_axis: int,
+                                  weights: jax.Array | None = None,
                                   label_smoothing: float = 0.0,
                                   block_tokens: int = _LOSS_BLOCK_TOKENS):
     """Mean token cross-entropy and top-1 error of an output head,
@@ -469,20 +493,37 @@ def blocked_softmax_cross_entropy(h: jax.Array, weight: jax.Array,
     * ``label_smoothing=eps`` (a static Python float; no work at 0) is
       ``softmax_cross_entropy``'s: target ``(1-eps) * onehot + eps/V``.
 
+    * ``weights (tokens,)`` or None (a static choice; None is the plain
+      mean): each token's loss weighed by a number the caller
+      differentiates too, as a looped model's exit distribution over
+      its passes' stacked states (``OuroLM``).  ``softmax - target`` is
+      scaled by it in the same pass, and the tokens' own losses are its
+      gradient.
+
     A block's logits leave the MXU's accumulator in float32 and stay so
     through the softmax; ``softmax - target`` is cast to ``h.dtype``
     for the two gradient products.  Returns ``(loss, error)``, float32
     scalars; ``error`` is ``error_rate``'s (``argmax != label``, first
-    index on ties) and carries no gradient."""
+    index on ties) and carries no gradient.  With ``weights`` it
+    returns ``(sum_i w_i l_i, token losses, token misses)``: the sum as
+    it is (the weights carry the caller's normalisation) and, float32
+    ``(tokens,)`` each and without a gradient of their own, every
+    token's ``l_i`` and whether its argmax missed."""
     if vocab_axis not in (0, 1) or weight.shape[1 - vocab_axis] != h.shape[-1]:
         raise ValueError(f"weight {weight.shape} with vocab_axis={vocab_axis} "
                          f"does not contract with h {h.shape}")
     n = h.shape[0]
+    if weights is not None and weights.shape != (n,):
+        raise ValueError(f"weights {weights.shape} for {n} tokens: one "
+                         "weight a token")
     block = _token_block(n, block_tokens)
     _log_block_plan(n, block, weight.shape[vocab_axis])
-    loss, miss = _xent_sums(h, weight, bias, labels.astype(jnp.int32), block,
-                            vocab_axis, float(label_smoothing))
-    return loss / n, miss / n
+    loss, miss, *per_token = _xent_sums(
+        h, weight, bias, labels.astype(jnp.int32), weights, block, vocab_axis,
+        float(label_smoothing))
+    if weights is None:
+        return loss / n, miss / n
+    return (loss, *per_token)
 
 
 def error_rate(logits: jax.Array, labels: jax.Array) -> jax.Array:
