@@ -275,7 +275,7 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
     import jax
     import jax.numpy as jnp
 
-    from theanompi_tpu.ops.attention import fused_attention
+    from theanompi_tpu.ops.attention import fused_attention, rotary_table
     from theanompi_tpu.ops.fused_bn import scale_bias_act
     from theanompi_tpu.ops.lrn import lrn
 
@@ -308,6 +308,15 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
             q, k, v, causal=True, impl=impl),
         _normal((q_shape, bf16), (kv_shape, bf16), (kv_shape, bf16)),
         rtol=2e-2, atol=2e-2))
+    # OuroLM's: 16 over 16 heads of 128, picked by index map from the
+    # projections' own layout, q and k rotated inside the kernels
+    shape = (4, 2048, 16, 128) if full else (1, 128, 2, 128)
+    cases.append(KernelCase(
+        f"attention_rotary{shape}",
+        lambda impl: lambda q, k, v: fused_attention(
+            q, k, v, causal=True, impl=impl, rotary=rotary_table(
+                jnp.arange(q.shape[1]), q.shape[3], 1e6)),
+        _normal(*[(shape, bf16)] * 3), rtol=2e-2, atol=2e-2))
     n, d, held, routed = (8192, 2048, 8, 16) if full else (200, 16, 2, 4)
 
     def experts_layer(impl):

@@ -112,11 +112,11 @@ def test_both_passes_take_the_kernel_at_the_zaya_shape(monkeypatch, caplog):
     with caplog.at_level(logging.INFO, logger=A.__name__):
         assert A._resolve_impl(None, q, k, plan) == "pallas"
         A._fused_bwd(128 ** -0.5, True, False, "n", plan,
-                     (q, k, k, pos, pos, q, lse), q)
+                     (q, k, k, pos, pos, None, q, lse), q)
     A._log_choice.cache_clear()
     assert "bwd" in seen
     said = [r.getMessage() for r in caplog.records]
-    named = "q block 512, key tile 512, 10 of 16 tiles"
+    named = "q block 512, key tile 512, 10 of 16 tiles)"
     assert any("attention fwd" in m and "pallas" in m and named in m
                for m in said)
     assert any("attention bwd" in m and "pallas" in m and named in m

@@ -260,21 +260,37 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("q_shape,kv_heads,plan", [
+@pytest.mark.parametrize("q_shape,kv_heads,rotary,plan", [
     # the four LM cells' shapes (the last is the looped model's: twice
-    # ZAYA1's batch x heads at head 128, equal head counts)
-    ((8, 1024, 16, 64), 16, "q block 512, key tile 512, 3 of 4 tiles"),
-    ((4, 2048, 8, 128), 2, "q block 512, key tile 512, 10 of 16 tiles"),
-    ((64, 128, 16, 64), 16, "q block 128, key tile 128, 1 of 1 tiles"),
-    ((4, 2048, 16, 128), 16, "q block 512, key tile 512, 10 of 16 tiles"),
+    # ZAYA1's batch x heads at head 128, equal head counts, and the
+    # kernels rotate q and k)
+    ((8, 1024, 16, 64), 16, False,
+     "q block 512, key tile 512, 3 of 4 tiles"),
+    ((4, 2048, 8, 128), 2, False,
+     "q block 512, key tile 512, 10 of 16 tiles"),
+    ((64, 128, 16, 64), 16, False,
+     "q block 128, key tile 128, 1 of 1 tiles"),
+    ((4, 2048, 16, 128), 16, True,
+     "q block 512, key tile 512, 10 of 16 tiles, heads by index map, "
+     "rotary in kernel"),
     # long lengths the budget admits, at a batch x heads where the
     # compiler asks more than the estimate (18.0 MiB at the first), and
     # one that takes a smaller q block than key tile
-    ((8, 4096, 16, 64), 16, "q block 512, key tile 512, 36 of 64 tiles"),
-    ((16, 2560, 8, 128), 2, "q block 256, key tile 512, 30 of 50 tiles"),
+    ((8, 4096, 16, 64), 16, False,
+     "q block 512, key tile 512, 36 of 64 tiles"),
+    ((16, 2560, 8, 128), 2, False,
+     "q block 256, key tile 512, 30 of 50 tiles"),
+    # the rotation with grouped heads (K rotated once a group), and
+    # with a head of two lane tiles
+    ((4, 2048, 8, 128), 2, True,
+     "q block 512, key tile 512, 10 of 16 tiles, heads by index map, "
+     "rotary in kernel"),
+    ((2, 1024, 4, 256), 4, True,
+     "q block 512, key tile 512, 3 of 4 tiles, heads by index map, "
+     "rotary in kernel"),
 ])
 def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
-                                       kv_heads, plan):
+                                       kv_heads, rotary, plan):
     """Mosaic takes both kernels at the plans the budget functions
     admit, bf16, causal."""
     from jax.experimental.compilation_cache import compilation_cache
@@ -283,15 +299,18 @@ def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
 
     monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
     b, t, h, d = q_shape
-    assert str(A.tile_plan(t, t, d, jnp.bfloat16, True)) == plan
-    assert A._fits_vmem_bwd(t, t, d, jnp.bfloat16,
-                            A._q_block(t, t, d, jnp.bfloat16))
+    chosen = A.tile_plan(t, t, d, jnp.bfloat16, True, rotary=rotary)
+    assert str(chosen) == plan
+    assert A._fits_vmem_bwd(t, t, d, jnp.bfloat16, chosen.q_block,
+                            chosen.positions, chosen.rotates)
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
                              sharding=one_chip)
-    loss = lambda q, k, v: A.fused_attention(  # noqa: E731
-        q, k, v, causal=True, impl="pallas",
-        name="test_attention").astype(jnp.float32).sum()
+    table = (jax.ShapeDtypeStruct((t, d), jnp.float32, sharding=one_chip)
+             if rotary else None)
+    loss = lambda q, k, v, table: A.fused_attention(  # noqa: E731
+        q, k, v, causal=True, impl="pallas", name="test_attention",
+        rotary=table).astype(jnp.float32).sum()
     # a program compiled for a described chip cannot be read back from
     # the persistent cache without one: keep it out
     cached = jax.config.jax_enable_compilation_cache
@@ -299,7 +318,7 @@ def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
     compilation_cache.reset_cache()
     try:
         text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-            q, k, k).compile().as_text()
+            q, k, k, table).compile().as_text()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()

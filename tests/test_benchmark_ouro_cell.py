@@ -239,9 +239,9 @@ def test_the_reference_check_tells_a_planted_fault(dry_run_model, fault,
 
     class NoNormBetween(ouro.OuroStack):
         @nn.compact
-        def __call__(self, u):
+        def __call__(self, u, rotary):
             for i in range(self.n_layers):
-                u = ouro.OuroLayer(**self.layer, name=f"Layer_{i}")(u)
+                u = ouro.OuroLayer(**self.layer, name=f"Layer_{i}")(u, rotary)
             h = nn.RMSNorm(epsilon=self.layer["rms_eps"],
                            dtype=self.layer["dtype"], name="final_norm")(u)
             return u, h          # the next pass starts from the un-normed u

@@ -16,6 +16,7 @@ from theanompi_tpu import monitor
 from theanompi_tpu.models import layers as L
 from theanompi_tpu.models import ouro
 from theanompi_tpu.models.base import ModelConfig
+from theanompi_tpu.ops.attention import rotary_table
 from theanompi_tpu.parallel.mesh import data_mesh
 from theanompi_tpu.utils.recorder import Recorder
 
@@ -188,6 +189,8 @@ def test_the_scanned_body_is_the_stack_applied_pass_after_pass(remat):
     params = _trained_gate(model.state.params)
     net = model.module
     stack = ouro.OuroStack(net.n_layers, net.layer, remat)
+    rotary = rotary_table(jnp.arange(batch[0].shape[1]),
+                          net.layer["head_dim"], net.rope_theta)
 
     def scanned(params):
         return net.apply({"params": params}, batch[0])[0]
@@ -196,7 +199,7 @@ def test_the_scanned_body_is_the_stack_applied_pass_after_pass(remat):
         h = params["embed"]["embedding"][batch[0]]
         states = []
         for _ in range(net.total_ut_steps):
-            h, out = stack.apply({"params": params["stack"]}, h)
+            h, out = stack.apply({"params": params["stack"]}, h, rotary)
             states.append(out)
         return jnp.stack(states)
 
@@ -212,6 +215,50 @@ def test_the_scanned_body_is_the_stack_applied_pass_after_pass(remat):
                         jax.tree.leaves(want[1][key])):
             np.testing.assert_allclose(
                 a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_the_layer_with_the_kernels_rotation_is_the_layer_with_rope(
+        monkeypatch, remat):
+    """``OuroLayer`` at the published head of 128 with both kernels
+    (interpret mode: heads by index map, q and k rotated inside)
+    against the same layer on the composed form behind ``zaya.rope``,
+    the way the layer was written before the kernels rotated: the
+    output and the gradient of the input and of every weight."""
+    import flax.linen as nn
+
+    import theanompi_tpu.ops.attention as A
+    from theanompi_tpu.models.zaya import rope
+
+    monkeypatch.setattr(A, "_Q_BLOCK", 32)
+    theta, t = 1e6, 64
+    cls = nn.remat(ouro.OuroLayer) if remat else ouro.OuroLayer
+    layer = cls(d_model=256, n_heads=2, head_dim=128, d_ff=64)
+    u = jax.random.normal(jax.random.key(1), (2, t, 256))
+    table = rotary_table(jnp.arange(t), 128, theta)
+    params = layer.init(jax.random.key(0), u, table)
+    weigh = jax.random.normal(jax.random.key(2), u.shape)
+    run = lambda p, u: jnp.sum(layer.apply(p, u, table) * weigh)  # noqa: E731
+
+    plans = []
+    real_plan = A.tile_plan
+    monkeypatch.setattr(A, "tile_plan", lambda *a, **kw: (
+        plans.append(real_plan(*a, **kw)), plans[-1])[1])
+    monkeypatch.setattr(A, "_resolve_impl", lambda *a, **kw: "pallas")
+    got = jax.value_and_grad(run, argnums=(0, 1))(params, u)
+    assert str(plans[-1]) == ("q block 32, key tile 32, 3 of 4 tiles, "
+                              "heads by index map, rotary in kernel")
+
+    def composed(q, k, v, causal, scale, name, rotary):
+        positions = jnp.arange(q.shape[1])
+        return A.fused_attention(rope(q, positions, 128, theta),
+                                 rope(k, positions, 128, theta), v,
+                                 causal=causal, scale=scale, impl="xla")
+    monkeypatch.setattr(ouro, "fused_attention", composed)
+    want = jax.value_and_grad(run, argnums=(0, 1))(params, u)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * max(float(jnp.abs(b).max()), 1.0))
 
 
 def test_a_start_traces_one_stack_whatever_the_passes():

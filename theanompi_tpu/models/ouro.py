@@ -15,7 +15,7 @@ the rest of the zoo (``TpuModel``: ``begin_epoch`` / ``train_iter`` /
         for l = 1..L:                               the SAME L layers every pass
             a = RMSNorm_l1(u);  q, k, v = a Wq, a Wk, a Wv
             q, k = RoPE(q), RoPE(k)                 whole head, positions 0..S-1
-            o = causal_softmax(q k^T / sqrt(D)) v
+            o = causal_softmax(q k^T / sqrt(D)) v   (the rotation inside the kernel)
             u = u + RMSNorm_l2(o Wo)                norms on both sides
             m = RMSNorm_l3(u);  f = (silu(m Wgate) * (m Wup)) Wdown
             u = u + RMSNorm_l4(f)
@@ -37,7 +37,12 @@ the rest of the zoo (``TpuModel``: ``begin_epoch`` / ``train_iter`` /
   applications of 8 192 tokens the stored activations would not fit).
 * **Attention** is ``ops/attention.py``'s fused kernel under the name
   ``ouro_attention`` (``ouro_attention_fwd`` / ``ouro_attention_bwd``
-  in a trace), equal query and key/value head counts.
+  in a trace), equal query and key/value head counts.  The kernels
+  take q, k and v as the projections leave them and rotate q and k
+  themselves (``rotary=``: ONE table a step, made outside the scanned
+  body), so no XLA pass stands between a projection and the kernel
+  where a head fills whole lanes (the published 128); smaller heads
+  are rotated by XLA, to the same numbers.
 * **Head and loss**: the T passes' states stacked to ``(T x tokens,
   d)`` and ONE ``layers.blocked_softmax_cross_entropy`` over the untied
   ``(d, vocab)`` kernel with the exit distribution as the tokens'
@@ -77,8 +82,7 @@ from jax.sharding import PartitionSpec as P
 from theanompi_tpu.data.lm import SeqLM_data
 from theanompi_tpu.models import layers as L
 from theanompi_tpu.models.base import ModelConfig, TpuModel
-from theanompi_tpu.models.zaya import rope
-from theanompi_tpu.ops.attention import fused_attention
+from theanompi_tpu.ops.attention import fused_attention, rotary_table
 from theanompi_tpu.parallel.mesh import AXIS_DATA
 
 _log = logging.getLogger(__name__)
@@ -92,12 +96,11 @@ class OuroLayer(nn.Module):
     n_heads: int
     head_dim: int
     d_ff: int
-    rope_theta: float = 1e6
     rms_eps: float = 1e-6
     dtype: jnp.dtype = jnp.float32
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, rotary):
         b, t, d = u.shape
         heads = (b, t, self.n_heads, self.head_dim)
 
@@ -111,14 +114,12 @@ class OuroLayer(nn.Module):
                             dtype=self.dtype, name=name)
 
         a = norm("attn_norm")(u)
-        positions = jnp.arange(t)
-        q = rope(dense(d, "q_proj")(a).reshape(heads), positions,
-                 self.head_dim, self.rope_theta)
-        k = rope(dense(d, "k_proj")(a).reshape(heads), positions,
-                 self.head_dim, self.rope_theta)
-        v = dense(d, "v_proj")(a).reshape(heads)
+        q, k, v = (dense(d, name)(a).reshape(heads)
+                   for name in ("q_proj", "k_proj", "v_proj"))
+        # the kernels rotate q and k themselves: nothing stands between
+        # a projection and the kernel
         o = fused_attention(q, k, v, causal=True, scale=self.head_dim ** -0.5,
-                            name="ouro_attention")
+                            name="ouro_attention", rotary=rotary)
         u = u + norm("attn_out_norm")(dense(d, "o_proj")(o.reshape(b, t, d)))
         m = norm("mlp_norm")(u)
         f = dense(d, "down_proj")(nn.silu(dense(self.d_ff, "gate_proj")(m))
@@ -128,19 +129,20 @@ class OuroLayer(nn.Module):
 
 class OuroStack(nn.Module):
     """One pass: the L layers, then the final norm.  Scan-shaped
-    (``carry -> (carry, out)``): ``OuroLMNet`` runs it as the body of
-    ``nn.scan`` over the T passes, on one set of parameters."""
+    (``carry, rotary table -> (carry, out)``): ``OuroLMNet`` runs it as
+    the body of ``nn.scan`` over the T passes, on one set of parameters
+    and one table."""
 
     n_layers: int
     layer: dict          # OuroLayer's fields
     remat: bool = False
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, rotary):
         # explicit names pin the tree to the layout without remat
         layer_cls = nn.remat(OuroLayer) if self.remat else OuroLayer
         for i in range(self.n_layers):
-            u = layer_cls(**self.layer, name=f"Layer_{i}")(u)
+            u = layer_cls(**self.layer, name=f"Layer_{i}")(u, rotary)
         h = nn.RMSNorm(epsilon=self.layer["rms_eps"],
                        dtype=self.layer["dtype"], name="final_norm")(u)
         return h, h
@@ -168,6 +170,7 @@ class OuroLMNet(nn.Module):
     n_layers: int
     total_ut_steps: int
     layer: dict          # OuroLayer's fields
+    rope_theta: float = 1e6
     remat: bool = False
 
     @nn.compact
@@ -175,6 +178,10 @@ class OuroLMNet(nn.Module):
         del train  # no dropout, no batch statistics
         d, dtype = self.layer["d_model"], self.layer["dtype"]
         steps = self.total_ut_steps
+        # every layer of every pass rotates by the same angles: one
+        # table a step, outside the scanned body
+        rotary = rotary_table(jnp.arange(tokens.shape[1]),
+                              self.layer["head_dim"], self.rope_theta)
         x = nn.Embed(self.vocab, d, embedding_init=L.gaussian_init(0.02),
                      name="embed")(tokens).astype(dtype)
         OuroHead(d, self.vocab, name="head")()
@@ -182,12 +189,13 @@ class OuroLMNet(nn.Module):
                      remat=self.remat, name="stack")
         if self.is_initializing():
             # one pass makes every parameter
-            states = OuroStack(**stack)(x)[1][None]
+            states = OuroStack(**stack)(x, rotary)[1][None]
         else:
             with jax.named_scope("ouro/pass"):
                 _, states = nn.scan(
                     OuroStack, variable_broadcast="params",
-                    split_rngs={"params": False}, length=steps)(**stack)(x)
+                    split_rngs={"params": False}, in_axes=nn.broadcast,
+                    length=steps)(**stack)(x, rotary)
         with jax.named_scope("ouro/exit_gate"):
             # zeros: the exit distribution starts at 1/2, 1/4, ... and
             # the last pass takes what is left
@@ -292,7 +300,8 @@ class OuroLM(TpuModel):
         del c["seq_len"]
         return OuroLMNet(
             vocab=c.pop("vocab"), n_layers=c.pop("n_layers"),
-            total_ut_steps=c.pop("total_ut_steps"), remat=self.config.remat,
+            total_ut_steps=c.pop("total_ut_steps"),
+            rope_theta=c.pop("rope_theta"), remat=self.config.remat,
             layer=dict(c, dtype=self._compute_dtype()))
 
     def _states(self, params, tokens):

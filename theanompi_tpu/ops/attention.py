@@ -33,8 +33,9 @@ Scope notes:
   (1, q block) vectors.  As (q block, 1) columns they cost a tile's
   worth of vector work a visit and 512 bytes a row in VMEM and HBM.
   The forward contracts a V tile over its rows as it lies, and ``out``
-  leaves it as (D, q block), turned by XLA beside the head fold (turned
-  in the kernel it cost the forward 13%, 40% at one tile).
+  leaves it as (D, q block), turned by XLA as the operand of the next
+  matmul (turned in the kernel it cost the forward 13%, 40% at one
+  tile).
 * K/V for one (batch, head) must fit VMEM (checked; oversize shapes
   fall back to the XLA path) — local shard lengths up to a few
   thousand, which is the regime this framework runs attention at:
@@ -47,9 +48,56 @@ Scope notes:
   scratch — the (Tq, Tk) matrix never exists in either direction.  A
   row's sum of dp * p over all its keys, which no tile sees, is
   g . out (so ``out`` is a residual; the next layer's matmul keeps it
-  anyway).  Its products take float32 operands (the next kernel
-  lever, ROADMAP A3).  Ragged q-blocks or oversize shapes fall back
-  to the composed-XLA VJP.
+  anyway).  Its five products take float32 operands, which on the
+  chip cost nothing to speak of: over the tiles visited a backward
+  call runs at 84.7% of the bf16 peak at head 128 ((4, 2048, 16|16,
+  128): 1.287 ms for 0.2147 TFLOP) and at 40% at head 64, where a
+  contraction of 64 half-fills the 128-wide array and the shape allows
+  ~50% (ledger, PR 32; PERF.md section 7).  Ragged q-blocks or
+  oversize shapes fall back to the composed-XLA VJP.
+* HOW A HEAD IS REACHED follows from what the caller hands over (no
+  option; ``TilePlan.rotates``).  A caller that passes ``rotary=``
+  hands q, k, v as its projections leave them; where a head fills
+  whole lanes (``head_dim % 128 == 0``) both passes then take them
+  (and g) as (B, T, H * D), a free reshape of (B, T, H, D), and pick a
+  head's lanes in the ``BlockSpec``'s index map; the backward writes
+  dq, dk, dv the same way: no XLA transpose on either side of either
+  kernel (ten of 33.5 MB a layer at (4, 2048, 16, 128) before).  The
+  forward's ``out`` stays (B * H, D, T), ``lse`` and ``delta`` keep
+  their rows.  These kernels are handed the positions only where a
+  mask has to read them (explicit ones under ``causal``): over the
+  defaults a masked tile's mask comes from the block's and the tile's
+  indices, and the key-position column (512 bytes a key in VMEM) is
+  not held.  Every other call keeps the folded kernels, (B * H, T, D)
+  through ``_fold`` / ``unfold``, to the parent's jaxpr
+  (tests/test_attention_contract.py): a head of 64 (the ``gpt2m``
+  cells) because a 64-lane block of a 1 024-lane row is not a block
+  Mosaic takes (two heads of 64 in one 128-lane block is the next
+  step there; it needs lane slices inside the kernel bodies); and a
+  head of 128 whose caller rotates in XLA (``ZayaLM``: partial rotary
+  behind a unit norm and per-head convolutions) because there the
+  head-major layout is what XLA's own passes before the kernel write
+  anyway: by index map that cell's step read 1.4% LONGER on the chip
+  (copies and the rotation's fusions +2.3 ms, the kernels +3.5% on
+  rows of 256 bytes at a stride; PERF.md section 6, PR 33).
+* ROTARY EMBEDDING: ``fused_attention(..., rotary=rotary_table(...))``
+  rotates q and k before the score product (halves paired, float32,
+  rounded once to the input dtype).  Where a head fills whole lanes
+  the KERNELS do it: ``x * cos + roll(x, D / 2 lanes) * (-sin | sin)``,
+  one lane roll where XLA splits a head at lane 64, relays out and
+  concatenates (four to six passes over q and k; with the head folds
+  1.58 ms of XLA passes a layer forward and 1.36 backward at (4,
+  2048, 16, 128), beside kernels of 0.83 and 1.29: PERF.md section
+  5.3c).  The q block is rotated by its program, K once a (batch,
+  key/value head) into a VMEM scratch the later programs read (the
+  grid runs in order on one core); the backward rotates both
+  again for its recomputed scores and turns dq and dk back in float32
+  before their one rounding (one rounding fewer than ``rope``'s VJP
+  behind the kernel).  The table is ONE (T, D) float32 array, cos |
+  sin, held whole in VMEM (1 MiB at (2048, 128), twice for the
+  pipeline: what the key-position column freed); a caller makes it
+  once a step.  Elsewhere (a head under 128, a ragged q tail, the
+  composed form) ``rotary_xla`` rotates, to the same numbers.
 * Each pass is a module-level ``jax.jit`` with the plan among its
   static arguments: the 24 call sites of a step program share one
   traced jaxpr and one lowered function a pass, and a model built
@@ -64,7 +112,9 @@ Scope notes:
   Every choice made from a shape is logged once per shape at trace
   time (logger ``theanompi_tpu.ops.attention``; a warning when a TPU
   run takes the XLA form), so no path is taken quietly: ``pallas
-  (fits, q block 512, key tile 512, 3 of 4 tiles)``.
+  (fits, q block 512, key tile 512, 3 of 4 tiles)``, and at head 128
+  ``pallas (fits, q block 512, key tile 512, 10 of 16 tiles, heads by
+  index map, rotary in kernel)``.
 * Grouped-query heads: k/v may carry FEWER heads than q (Hq a
   multiple of Hkv; query head h reads key/value head h // (Hq/Hkv)).
   The kernels pick the shared head by index map — k/v are never
@@ -83,16 +133,19 @@ Scope notes:
 * ``name=`` labels the two ``pallas_call``s (``<name>_fwd`` /
   ``<name>_bwd``) so a trace reducer can tell one model's attention
   from another custom call; None keeps Pallas's default.
-* On-chip status (TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34, PR 29): fwd
+* On-chip status (TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34, PR 33): fwd
   and the fused bwd compile and match the XLA form at (8, 1024, 12,
-  64) and, with 8 query over 2 key/value heads of 128, at (4, 2048,
-  8|2, 128), bf16 causal, 3 of 4 and 10 of 16 tiles of 512 x 512
-  (chip_smoke.py's two checks); the three LM cells' reference checks
+  64), with 8 query over 2 key/value heads of 128 at (4, 2048, 8|2,
+  128), and by index map with the rotation inside at (4, 2048, 16|16,
+  128), bf16 causal, 3 of 4 and 10 of 16 tiles of 512 x 512
+  (chip_smoke.py's three checks); the four LM cells' reference checks
   run both passes against float32 at (8, 1024, 16, 64), (64, 128, 16,
-  64) and (4, 2048, 8|2, 128).  Explicit positions, ``causal=False``
-  and lengths that take a q block under the key tile are compiled for
-  the chip (tests/test_attention_tiles.py) and have not run on it; the
-  ragged-q-tail path has not been compiled.
+  64), (4, 2048, 8|2, 128) and (4, 2048, 16|16, 128) rotating.
+  Explicit positions, ``causal=False``, the rotation with grouped
+  heads or a head of 256, and lengths that take a q block under the
+  key tile are compiled for the chip (tests/test_attention_tiles.py)
+  and have not run on it; the ragged-q-tail path has not been
+  compiled.
 """
 
 from __future__ import annotations
@@ -143,17 +196,33 @@ class TilePlan(NamedTuple):
     """How both passes tile one (Tq, Tk) score square: the q block, the
     key tile, whether tiles above the diagonal are left out (``skip``:
     a causal mask over the default positions), and the mechanism's
-    counter, ``visited`` of ``total`` tiles."""
+    counter, ``visited`` of ``total`` tiles; then the kernels' contract
+    with their caller: where the rotary embedding a caller asked for
+    runs (``rotary``: ``'kernel'``, ``'XLA'`` or None = none was asked
+    for; the kernels that rotate also pick a head by index map from
+    (B, T, H * D), the others are handed (B * H, T, D)), and whether
+    they are handed the positions (``positions``: always to the folded
+    kernels; to the others only where a mask has to read them)."""
 
     q_block: int
     key_tile: int
     skip: bool
     visited: int
     total: int
+    positions: bool = True
+    rotary: str | None = None
+
+    @property
+    def rotates(self) -> bool:
+        """Whether the kernels are handed the projections' outputs and
+        the table: they pick heads by index map and rotate."""
+        return self.rotary == "kernel"
 
     def __str__(self):
         return (f"q block {self.q_block}, key tile {self.key_tile}, "
-                f"{self.visited} of {self.total} tiles")
+                f"{self.visited} of {self.total} tiles"
+                + (", heads by index map" if self.rotates else "")
+                + (f", rotary in {self.rotary}" if self.rotary else ""))
 
 
 def _walk_bounds(j, q_block: int, key_tile: int, n_tiles: int,
@@ -183,16 +252,28 @@ def _key_tile(tk: int) -> int:
 
 
 def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
-              default_positions: bool = True) -> TilePlan:
-    """The plan of a shape: a pure function of shape, dtype, mask and
-    the module's configured sizes, which the kernels, the log line,
-    the tests and PERF.md all read."""
-    q_block, key_tile = _q_block(tq, tk, d, dtype), _key_tile(tk)
+              default_positions: bool = True,
+              rotary: bool = False) -> TilePlan:
+    """The plan of a shape: a pure function of shape, dtype, mask,
+    whether the caller passed a rotary table, and the module's
+    configured sizes, which the kernels, the log line, the tests and
+    PERF.md all read."""
     skip = causal and default_positions
+    # a head of whole 128-lane rows is a block of (B, T, H * D) that
+    # Mosaic takes; a 64-lane block of a 1 024-lane row is not
+    where = ("kernel" if d % 128 == 0 else "XLA") if rotary else None
+    positions = where != "kernel" or (causal and not skip)
+    q_block = _q_block(tq, tk, d, dtype, positions, where == "kernel")
+    if where == "kernel" and tq % q_block:
+        # a ragged tail's q block would read past the table's rows
+        where = "XLA"
+        positions, q_block = True, _q_block(tq, tk, d, dtype)
+    key_tile = _key_tile(tk)
     n_blocks, n_tiles = pl.cdiv(tq, q_block), tk // key_tile
     visited = sum(_walk_bounds(j, q_block, key_tile, n_tiles, causal,
                                skip)[1] for j in range(n_blocks))
-    return TilePlan(q_block, key_tile, skip, visited, n_blocks * n_tiles)
+    return TilePlan(q_block, key_tile, skip, visited, n_blocks * n_tiles,
+                    positions, where)
 
 
 def _walk(j, body, carry, plan: TilePlan, n_tiles: int, causal: bool):
@@ -217,28 +298,95 @@ def _tile(t, size: int, extent: int):
     return pl.ds(pl.multiple_of(t * size, size), size)
 
 
-def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref, *,
-            scale, causal, plan):
+def _operands(refs, plan: TilePlan):
+    """A kernel's refs by name: ``(q, k, v, table, qpos, kpos, the
+    rest)``, None for what the plan does not hand it."""
+    q, k, v, *rest = refs
+    table = rest.pop(0) if plan.rotates else None
+    qpos, kpos = ((rest.pop(0), rest.pop(0)) if plan.positions
+                  else (None, None))
+    return q, k, v, table, qpos, kpos, rest
+
+
+def _default_mask(j, t, plan: TilePlan):
+    """The (key tile, q block) causal mask of q block ``j`` against
+    key tile ``t`` over the default positions, from their indices."""
+    shape = (plan.key_tile, plan.q_block)
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    return ahead >= t * plan.key_tile - j * plan.q_block
+
+
+def _rotate(x, table, inverse: bool = False):
+    """The rotary embedding of ``x (rows, D)`` float32 by ``table
+    (rows, D)`` (``rotary_table``: cos | sin), halves paired: ``x * cos
+    + roll(x, D / 2) * (-sin | sin)``, one lane roll where XLA splits,
+    relays out and concatenates.  ``inverse``: by the negative angle,
+    the rotation's transpose, for a gradient."""
+    half = x.shape[1] // 2
+    swapped = pltpu.roll(table, half, 1)              # sin | cos
+    first = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) < half
+    cos = jnp.where(first, table, swapped)
+    sin = (jnp.where(first, swapped, -table) if inverse
+           else jnp.where(first, -swapped, table))
+    return x * cos + pltpu.roll(x, half, 1) * sin
+
+
+def _rotate_keys(k_ref, table_ref, rotated, plan: TilePlan):
+    """A head's K, rotated a key tile at a time and rounded once to its
+    own dtype (where ``rotary_xla`` rounds), into the scratch both
+    passes read their key tiles from."""
+    tk = k_ref.shape[1]
+
+    def one(t, _):
+        ks = _tile(t, plan.key_tile, tk)
+        rotated[ks] = _rotate(k_ref[0, ks].astype(jnp.float32),
+                              table_ref[ks]).astype(rotated.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, tk // plan.key_tile, one, 0)
+
+
+def _kernel(*refs, scale, causal, plan, group):
     """One q block of one (batch, head) against that head's resident
     K/V, walked in key tiles with an online softmax.  Scores are held
     TRANSPOSED, (key tile, q block): a row of the softmax runs down the
     sublanes, so its max, sum and rescale are lane-dense (1, q block)
     vectors (as (q block, 1) columns they cost a tile's worth of work a
     visit), and they and the fp32 (D, q block) accumulator are the
-    loop's carry."""
+    loop's carry.  With a rotary table the q block is rotated here and
+    K once a (batch, key/value head), at the first q block of the
+    group's first query head, into a scratch the later programs read."""
+    q_ref, k_ref, v_ref, table_ref, qpos_ref, kpos_ref, rest = _operands(
+        refs, plan)
+    o_ref, lse_ref, *scratch = rest
     d, tq_blk = o_ref.shape[1:]
     tile = plan.key_tile
     q = q_ref[0]                                      # (TQB, D)
+    j = pl.program_id(1)
+    keys = lambda ks: k_ref[0, ks]  # noqa: E731
+    if table_ref is not None:
+        rotated, = scratch
+        keys = lambda ks: rotated[ks]  # noqa: E731
+
+        @pl.when(jnp.logical_and(j == 0, pl.program_id(0) % group == 0))
+        def _():
+            _rotate_keys(k_ref, table_ref, rotated, plan)
+
+        q = _rotate(q.astype(jnp.float32),
+                    table_ref[_tile(j, tq_blk, table_ref.shape[0])]
+                    ).astype(q.dtype)
 
     def body(masked):
         def step(t, carry):
             m, l, acc = carry
             ks = _tile(t, tile, k_ref.shape[1])
             s = jax.lax.dot_general(
-                k_ref[0, ks], q, (((1,), (1,)), ((), ())),
+                keys(ks), q, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (TILE, TQB)
             if masked:
-                mask = qpos_ref[:] >= kpos_ref[ks]    # (1,TQB)>=(TILE,1)
+                mask = (qpos_ref[:] >= kpos_ref[ks]   # (1,TQB)>=(TILE,1)
+                        if plan.positions else _default_mask(j, t, plan))
                 s = jnp.where(mask, s, _MASK_NEG)
             m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
             p = jnp.exp(s - m_new)
@@ -255,7 +403,7 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref, lse_ref, *,
     # and comes out uniform over every key visited, as the one-pass
     # softmax gave it
     m, l, acc = _walk(
-        pl.program_id(1), body,
+        j, body,
         (jnp.full((1, tq_blk), _MASK_NEG, jnp.float32),
          jnp.zeros((1, tq_blk), jnp.float32),
          jnp.zeros((d, tq_blk), jnp.float32)),
@@ -277,44 +425,77 @@ def _fold(x):                                # (B,T,H,D) -> (B*H,T,D)
     return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
 
 
+def _heads(x, plan: TilePlan):
+    """``x (B, T, H, D)`` as the kernels index it: (B, T, H * D), which
+    is ``x`` as a projection leaves it, where they rotate and pick a
+    head's lanes by index map; else (B * H, T, D), a transpose in HBM."""
+    if plan.rotates:
+        return x.reshape(*x.shape[:2], -1)
+    return _fold(x)
+
+
+def _table_spec(table):
+    """The rotary table whole, at one block index for every program:
+    fetched once a call."""
+    return pl.BlockSpec(table.shape, lambda i, j: (0, 0),
+                        memory_space=pltpu.VMEM)
+
+
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "interpret", "name", "plan"))
-def _pallas_attention(q, k, v, q_pos, k_pos, *, scale, causal, interpret,
-                      plan: TilePlan, name: str | None = None):
+def _pallas_attention(q, k, v, q_pos, k_pos, table=None, *, scale, causal,
+                      interpret, plan: TilePlan, name: str | None = None):
     """The forward pass -> (out (B,Tq,H,D), lse (B*H,1,Tq) fp32: a
     row a head, which HBM holds as it is; a (Tq, 1) column there pads
     every row to 128 lanes, 64 MB a layer at (8, 1024, 16, 64)).
     Jitted at module level with the plan static: every call site of a
     shape shares one traced jaxpr and one lowered function, and an
-    eager caller compiles it once a process."""
+    eager caller compiles it once a process.  ``q_pos``/``k_pos`` are
+    None where the plan hands the kernel no positions, ``table`` is the
+    rotary table where it rotates."""
     b, tq, h, d = q.shape
     tk, h_kv = k.shape[1:3]
     bh = b * h
     group = h // h_kv          # query heads per key/value head
     tq_blk = plan.q_block
 
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    qp = q_pos.astype(jnp.int32).reshape(1, tq)
-    kp = k_pos.astype(jnp.int32).reshape(tk, 1)
-
-    kern = functools.partial(_kernel, scale=scale, causal=causal,
-                             plan=plan)
-    # folded query row b*Hq + h reads folded key/value row
-    # b*Hkv + h // group, which is (b*Hq + h) // group
-    shared = lambda i, j: (i // group, 0, 0)  # noqa: E731
-    out, lse = pl.pallas_call(
-        kern,
-        grid=(bh, pl.cdiv(tq, tq_blk)),
-        in_specs=[
-            pl.BlockSpec((1, tq_blk, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+    operands = [_heads(q, plan), _heads(k, plan), _heads(v, plan)]
+    if plan.rotates:
+        # program i is query head i % H of batch i // H, in the lanes
+        # [head * D, (head + 1) * D) of its row
+        query = lambda i, j: (i // h, j, i % h)  # noqa: E731
+        shared = lambda i, j: (i // h, 0, i % h // group)  # noqa: E731
+    else:
+        # folded query row b*Hq + h reads folded key/value row
+        # b*Hkv + h // group, which is (b*Hq + h) // group
+        query = lambda i, j: (i, j, 0)  # noqa: E731
+        shared = lambda i, j: (i // group, 0, 0)  # noqa: E731
+    in_specs = [
+        pl.BlockSpec((1, tq_blk, d), query, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+    ]
+    scratch = []
+    if plan.rotates:
+        operands.append(table)
+        in_specs.append(_table_spec(table))
+        scratch.append(pltpu.VMEM((tk, d), k.dtype))
+    if plan.positions:
+        operands += [q_pos.astype(jnp.int32).reshape(1, tq),
+                     k_pos.astype(jnp.int32).reshape(tk, 1)]
+        in_specs += [
             pl.BlockSpec((1, tq_blk), lambda i, j: (0, j),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((tk, 1), lambda i, j: (0, 0),
                          memory_space=pltpu.VMEM),
-        ],
+        ]
+
+    kern = functools.partial(_kernel, scale=scale, causal=causal,
+                             plan=plan, group=group)
+    out, lse = pl.pallas_call(
+        kern,
+        grid=(bh, pl.cdiv(tq, tq_blk)),
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, d, tq_blk), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
@@ -325,10 +506,11 @@ def _pallas_attention(q, k, v, q_pos, k_pos, *, scale, causal, interpret,
             jax.ShapeDtypeStruct((bh, d, tq), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
+        scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=interpret,
         name=name and name + "_fwd",
-    )(qf, kf, vf, qp, kp)
+    )(*operands)
     return out.reshape(b, h, d, tq).transpose(0, 3, 1, 2), lse
 
 
@@ -338,6 +520,33 @@ def _repeat_kv(q, k, v):
     if group == 1:
         return k, v
     return jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+
+
+def rotary_table(positions, dim: int, theta: float):
+    """The rotary embedding's table for ``fused_attention(...,
+    rotary=)``: ``(T, dim)`` float32, a row a position, its first half
+    the cosines and its second the sines of ``position * theta **
+    (-i / (dim / 2))``, i = 0 .. dim / 2 - 1.  Made once a step: every
+    layer and pass of a model rotates by the same angles."""
+    half = dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]
+    return jnp.concatenate([jnp.cos(angle), jnp.sin(angle)], -1)
+
+
+def rotary_xla(x, table):
+    """``x (B, T, H, D)`` rotated by ``table (T, D)`` (``rotary_table``)
+    in plain XLA, halves paired (i with i + D / 2), in float32, rounded
+    once to ``x``'s dtype: what the kernels do to q and k themselves
+    where a head fills whole lanes, and the form for every other
+    shape."""
+    half = x.shape[-1] // 2
+    cos, sin = (t[None, :, None, :] for t in (table[:, :half],
+                                              table[:, half:]))
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
 
 
 def _xla_attention(q, k, v, q_pos, k_pos, scale, causal):
@@ -350,34 +559,47 @@ def _xla_attention(q, k, v, q_pos, k_pos, scale, causal):
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
-def _fits_vmem(tk, d, dtype, tq_blk: int) -> bool:
-    """The forward holds K and V whole, the key positions as a column
-    (512 bytes a key in VMEM), one q block and its output, each TWICE
-    (the pipeline's two buffers), and two (key tile, q block) fp32
-    blocks of scores beside the fp32 accumulator."""
+def _fits_vmem(tk, d, dtype, tq_blk: int, positions: bool = True,
+               rotary: bool = False) -> bool:
+    """The forward holds K and V whole, one q block and its output,
+    each TWICE (the pipeline's two buffers), and two (key tile, q
+    block) fp32 blocks of scores beside the fp32 accumulator; where it
+    is handed the positions (``TilePlan.positions``), the keys' as a
+    column (512 bytes a key in VMEM), twice; where it rotates, the
+    float32 table whole, twice, and the rotated K once (the rotation's
+    own float32 blocks live before the walk's and are smaller)."""
     itemsize, tile = jnp.dtype(dtype).itemsize, _key_tile(tk)
     need = (2 * (2 * tk * d * itemsize         # K, V
-                 + tk * 512                    # key positions
+                 + positions * tk * 512        # key positions
+                 + rotary * tk * d * 4         # rotary table
                  + 2 * tq_blk * d * itemsize)  # Q block, out
+            + rotary * tk * d * itemsize       # rotated K
             + 2 * tile * tq_blk * 4            # fp32 scores, exp
             + 2 * tq_blk * d * 4)              # accumulator, v p
     return need <= _VMEM_BUDGET_BYTES
 
 
-def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int) -> bool:
-    """The fused bwd holds whole Q/G/dq plus K/V/dk/dv per (b*h) and
-    the key positions as a column, each TWICE (the pipeline's two
-    buffers), fp32 dk/dv scratch and, per (key tile, q block), fp32
-    casts of the operands and two score blocks.  At few heads this is
+def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int, positions: bool = True,
+                   rotary: bool = False) -> bool:
+    """The fused bwd holds whole Q/G/dq plus K/V/dk/dv per (b*h), each
+    TWICE (the pipeline's two buffers), fp32 dk/dv scratch and, per
+    (key tile, q block), fp32 casts of the operands and two score
+    blocks; the key positions, the rotary table and the rotated K as
+    the forward does.  At few heads this is
     the v5e compiler's own count (under its default 16 MiB it refused
     (2, 5120, 16, 64) bf16 at 17.50 MiB; this says 17.6); with more
     batch x heads the compiler asks more, which ``_compiler_params``
     leaves room for, so every shape admitted here compiles
-    (tests/test_attention_tiles.py)."""
+    (tests/test_attention_tiles.py).  At (2048, 2048, 128) bf16, q
+    block 512: 14.25 MiB with the positions, 12.25 without, 14.75 with
+    the rotation and without the positions; with both, 16.75, a plan
+    takes a q block of 256."""
     itemsize, tile = jnp.dtype(dtype).itemsize, _key_tile(tk)
     need = (2 * (3 * tq * d * itemsize         # Q, G, dq
                  + 4 * tk * d * itemsize       # K, V, dk, dv
-                 + tk * 512)                   # key positions
+                 + positions * tk * 512        # key positions
+                 + rotary * tk * d * 4)        # rotary table
+            + rotary * tk * d * itemsize       # rotated K
             + 2 * tk * d * 4                   # fp32 dk/dv scratch
             + 3 * tq_blk * d * 4               # q/g casts, dq carry
             + 2 * tile * d * 4                 # k/v tile casts
@@ -385,7 +607,8 @@ def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int) -> bool:
     return need <= _VMEM_BUDGET_BYTES
 
 
-def _q_block(tq, tk, d, dtype) -> int:
+def _q_block(tq, tk, d, dtype, positions: bool = True,
+             rotary: bool = False) -> int:
     """The q block of a shape: the configured block, else its halves
     down to 128, the largest that divides ``tq`` and keeps the forward
     AND the fused backward inside the VMEM budget.  Where none does,
@@ -394,8 +617,10 @@ def _q_block(tq, tk, d, dtype) -> int:
     top = min(_Q_BLOCK, tq)
     blk = top
     while blk >= 128 and blk % 8 == 0:
-        if (tq % blk == 0 and _fits_vmem(tk, d, dtype, blk)
-                and _fits_vmem_bwd(tq, tk, d, dtype, blk)):
+        if (tq % blk == 0
+                and _fits_vmem(tk, d, dtype, blk, positions, rotary)
+                and _fits_vmem_bwd(tq, tk, d, dtype, blk, positions,
+                                   rotary)):
             return blk
         blk //= 2
     return top
@@ -426,7 +651,8 @@ def _resolve_impl(impl: str | None, q, k,
     plan = plan or tile_plan(tq, k.shape[1], d, q.dtype, causal=True)
     if jax.default_backend() != "tpu":
         choice, why = "xla", "not a TPU"
-    elif not _fits_vmem(k.shape[1], d, q.dtype, plan.q_block):
+    elif not _fits_vmem(k.shape[1], d, q.dtype, plan.q_block,
+                        plan.positions, plan.rotates):
         choice, why = "xla", "K/V + score block exceed the VMEM budget"
     elif tq % plan.q_block != 0:
         # ragged q-tails rely on Pallas out-of-range block padding,
@@ -440,29 +666,44 @@ def _resolve_impl(impl: str | None, q, k,
     return choice
 
 
-def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
-                delta_ref, dq_ref, dk_ref, dv_ref, dk_s, dv_s, *, scale,
-                causal, plan):
+def _bwd_kernel(*refs, scale, causal, plan):
     """Flash-style backward for one (batch * key/value head, query head
     of its group): loop q-blocks and, inside, the key tiles each one
     visits; recompute p from (q, k, lse) — no stored score matrix
     anywhere, blocks held transposed as in the forward — accumulating
     dq in the inner loop's carry and dk/dv in fp32 VMEM scratch over
     the q-blocks AND over the group's query heads (the innermost grid
-    axis; one head when q and k/v have the same count)."""
+    axis; one head when q and k/v have the same count).  With a rotary
+    table q and K are rotated as the forward rotated them (K once, at
+    the group's first head), and dq and dk, which are gradients of the
+    ROTATED q and k, are turned back in float32 before their one
+    rounding."""
+    q_ref, k_ref, v_ref, table_ref, qpos_ref, kpos_ref, rest = _operands(
+        refs, plan)
+    (g_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dk_s, dv_s,
+     *scratch) = rest
     member = pl.program_id(1)
     tq_blk, tile = plan.q_block, plan.key_tile
     tq, d = q_ref.shape[1:]
     tk = k_ref.shape[1]
+    keys = lambda ks: k_ref[0, ks]  # noqa: E731
+    if table_ref is not None:
+        rotated, = scratch
+        keys = lambda ks: rotated[ks]  # noqa: E731
 
     @pl.when(member == 0)
     def _():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
+        if table_ref is not None:
+            _rotate_keys(k_ref, table_ref, rotated, plan)
 
     def q_block(i, _):
         sl = _tile(i, tq_blk, tq)
         q = q_ref[0, sl].astype(jnp.float32)          # (TQB, D)
+        if table_ref is not None:   # rounded where the forward rounds
+            q = _rotate(q, table_ref[sl]).astype(q_ref.dtype).astype(
+                jnp.float32)
         g = g_ref[0, sl].astype(jnp.float32)
         lse, delta = lse_ref[0, i], delta_ref[0, i]   # (1, TQB)
         # a FULLY-masked row (explicit positions alone can make one)
@@ -475,12 +716,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
         def body(masked):
             def step(t, dq):
                 ks = _tile(t, tile, tk)
-                kmat = k_ref[0, ks].astype(jnp.float32)   # (TILE, D)
+                kmat = keys(ks).astype(jnp.float32)       # (TILE, D)
                 vmat = v_ref[0, ks].astype(jnp.float32)
                 s = jax.lax.dot_general(
                     kmat, q, (((1,), (1,)), ((), ()))) * scale
                 if masked:
-                    mask = qpos_ref[i] >= kpos_ref[ks]  # (1,TQB)>=(TILE,1)
+                    mask = (qpos_ref[i] >= kpos_ref[ks]   # (1,TQB)>=(TILE,1)
+                            if plan.positions
+                            else _default_mask(i, t, plan))
                     s = jnp.where(mask, s, _MASK_NEG)
                 p = jnp.exp(s - lse)                  # (TILE, TQB)
                 if share is not None:
@@ -498,6 +741,8 @@ def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
 
         dq = _walk(i, body, jnp.zeros((tq_blk, d), jnp.float32), plan,
                    tk // tile, causal)
+        if table_ref is not None:
+            dq = _rotate(dq, table_ref[sl], inverse=True)
         dq_ref[0, sl] = (dq * scale).astype(dq_ref.dtype)
         return 0
 
@@ -505,14 +750,23 @@ def _bwd_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, g_ref, lse_ref,
 
     @pl.when(member == pl.num_programs(1) - 1)
     def _():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        if table_ref is None:
+            dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        else:
+            def one(t, _):
+                ks = _tile(t, tile, tk)
+                dk_ref[0, ks] = _rotate(dk_s[ks], table_ref[ks],
+                                        inverse=True).astype(dk_ref.dtype)
+                return 0
+
+            jax.lax.fori_loop(0, tk // tile, one, 0)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "scale", "causal", "interpret", "name", "plan"))
-def _pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g, *, scale,
-                          causal, interpret, plan: TilePlan,
+def _pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g, table=None, *,
+                          scale, causal, interpret, plan: TilePlan,
                           name: str | None = None):
     """The fused backward, jitted once a shape like the forward.
     ``delta``, a row's sum of dp * p over ALL its keys, which no one
@@ -522,57 +776,76 @@ def _pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g, *, scale,
     group = h // h_kv
     tq_blk = plan.q_block
 
-    qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
+    operands = [_heads(q, plan), _heads(k, plan), _heads(v, plan)]
+    gf = _heads(g, plan)
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
     # what a q block reads whole lies along the lanes, one row a block
     # (the kernel picks the row by its leading index)
     rows = (tq // tq_blk, 1, tq_blk)
     delta = delta.transpose(0, 2, 1).reshape(b * h, *rows)
-    qp = q_pos.astype(jnp.int32).reshape(rows)
-    kp = k_pos.astype(jnp.int32).reshape(tk, 1)
 
     # grid: (batch * key/value heads, query heads of a group); the
     # key/value blocks stay put while the group's query heads pass
-    query = lambda i, m: (i * group + m, 0, 0)  # noqa: E731
+    if plan.rotates:
+        query = lambda i, m: (  # noqa: E731
+            i // h_kv, 0, i % h_kv * group + m)
+        shared = lambda i, m: (i // h_kv, 0, i % h_kv)  # noqa: E731
+        heads = lambda n, t: (b, t, n * d)  # noqa: E731
+    else:
+        query = lambda i, m: (i * group + m, 0, 0)  # noqa: E731
+        shared = lambda i, m: (i, 0, 0)  # noqa: E731
+        heads = lambda n, t: (b * n, t, d)  # noqa: E731
     query_rows = lambda i, m: (i * group + m, 0, 0, 0)  # noqa: E731
-    shared = lambda i, m: (i, 0, 0)  # noqa: E731
     row_block = (1,) + rows
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                          plan=plan),
-        grid=(b * h_kv, group),
-        in_specs=[
-            pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+    in_specs = [
+        pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+        pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
+    ]
+    scratch = [pltpu.VMEM((tk, d), jnp.float32),
+               pltpu.VMEM((tk, d), jnp.float32)]
+    if plan.rotates:
+        operands.append(table)
+        in_specs.append(_table_spec(table))
+        scratch.append(pltpu.VMEM((tk, d), k.dtype))
+    if plan.positions:
+        operands += [q_pos.astype(jnp.int32).reshape(rows),
+                     k_pos.astype(jnp.int32).reshape(tk, 1)]
+        in_specs += [
             pl.BlockSpec(rows, lambda i, m: (0, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((tk, 1), lambda i, m: (0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
-            pl.BlockSpec(row_block, query_rows, memory_space=pltpu.VMEM),
-            pl.BlockSpec(row_block, query_rows, memory_space=pltpu.VMEM),
-        ],
+        ]
+    in_specs += [
+        pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
+        pl.BlockSpec(row_block, query_rows, memory_space=pltpu.VMEM),
+        pl.BlockSpec(row_block, query_rows, memory_space=pltpu.VMEM),
+    ]
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                          plan=plan),
+        grid=(b * h_kv, group),
+        in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, tq, d), query, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, tk, d), shared, memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h_kv, tk, d), v.dtype),
+            jax.ShapeDtypeStruct(heads(h, tq), q.dtype),
+            jax.ShapeDtypeStruct(heads(h_kv, tk), k.dtype),
+            jax.ShapeDtypeStruct(heads(h_kv, tk), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((tk, d), jnp.float32),
-            pltpu.VMEM((tk, d), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         compiler_params=_compiler_params(),
         interpret=interpret,
         name=name and name + "_bwd",
-    )(qf, kf, vf, qp, kp, gf, lse.reshape(b * h, *rows), delta)
+    )(*operands, gf, lse.reshape(b * h, *rows), delta)
 
     def unfold(x, t, heads):
+        if plan.rotates:
+            return x.reshape(b, t, heads, d)
         return x.reshape(b, heads, t, d).transpose(0, 2, 1, 3)
 
     return unfold(dq, tq, h), unfold(dk, tk, h_kv), unfold(dv, tk, h_kv)
@@ -604,38 +877,48 @@ def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g):
     return dq, shared(dk).astype(k.dtype), shared(dv).astype(v.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _fused(q, k, v, q_pos, k_pos, scale, causal, interpret, name, plan):
-    return _fused_fwd(q, k, v, q_pos, k_pos, scale, causal, interpret,
-                      name, plan)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
+def _fused(q, k, v, q_pos, k_pos, table, scale, causal, interpret, name,
+           plan):
+    return _fused_fwd(q, k, v, q_pos, k_pos, table, scale, causal,
+                      interpret, name, plan)[0]
 
 
-def _fused_fwd(q, k, v, q_pos, k_pos, scale, causal, interpret, name,
-               plan):
-    out, lse = _pallas_attention(q, k, v, q_pos, k_pos, scale=scale,
+def _fused_fwd(q, k, v, q_pos, k_pos, table, scale, causal, interpret,
+               name, plan):
+    out, lse = _pallas_attention(q, k, v, q_pos, k_pos, table, scale=scale,
                                  causal=causal, interpret=interpret,
                                  name=name, plan=plan)
-    return out, (q, k, v, q_pos, k_pos, out, lse)
+    return out, (q, k, v, q_pos, k_pos, table, out, lse)
 
 
 def _fused_bwd(scale, causal, interpret, name, plan, res, g):
-    q, k, v, q_pos, k_pos, out, lse = res
+    q, k, v, q_pos, k_pos, table, out, lse = res
     tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
     # the fused bwd loops exact q-blocks; ragged tails or oversize
     # VMEM needs take the composed-XLA path instead
     fused = tq % plan.q_block == 0 and _fits_vmem_bwd(
-        tq, tk, d, q.dtype, plan.q_block)
+        tq, tk, d, q.dtype, plan.q_block, plan.positions, plan.rotates)
     _log_choice("attention bwd", q.shape + k.shape[1:3], str(q.dtype),
                 "pallas" if fused else "xla",
                 f"fits, {plan}" if fused else "ragged q-tail "
                 "or over the VMEM budget")
     if fused:
         dq, dk, dv = _pallas_attention_bwd(
-            q, k, v, q_pos, k_pos, out, lse, g, scale=scale,
+            q, k, v, q_pos, k_pos, out, lse, g, table, scale=scale,
             causal=causal, interpret=interpret, name=name, plan=plan)
     else:
+        if not plan.positions:
+            q_pos, k_pos = jnp.arange(tq), jnp.arange(tk)
+        unrotate = None
+        if table is not None:
+            (q, k), unrotate = jax.vjp(
+                lambda q, k: (rotary_xla(q, table), rotary_xla(k, table)),
+                q, k)
         dq, dk, dv = _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g)
-    return dq, dk, dv, None, None
+        if unrotate:
+            dq, dk = unrotate((dq, dk))
+    return dq, dk, dv, None, None, None
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
@@ -643,12 +926,17 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 def fused_attention(q, k, v, q_pos=None, k_pos=None,
                     causal: bool = False, scale: float | None = None,
-                    impl: str | None = None, name: str | None = None):
+                    impl: str | None = None, name: str | None = None,
+                    rotary=None):
     """Softmax attention, fused on TPU.
 
     q: (B, Tq, H, D); k/v: (B, Tk, Hkv, D) with H a multiple of Hkv
     (query head h reads key/value head h // (H / Hkv)); optional global
     positions (Tq,)/(Tk,) for the causal mask (default: local aranges).
+    ``rotary``: a ``rotary_table`` over the rows of q AND k (so Tq ==
+    Tk; (T, D) float32), by which q and k are rotated before the score
+    product: inside the kernels where a head fills whole lanes, by
+    ``rotary_xla`` elsewhere, the same numbers either way.
     ``name`` labels the kernels in a trace.  Returns (B, Tq, H, D) in
     q.dtype.
     """
@@ -656,18 +944,28 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
         raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
                          f"and {v.shape[2]} value heads: the query count "
                          "must be a multiple of one shared count")
+    if rotary is not None and not (
+            rotary.shape == q.shape[1::2] == k.shape[1::2]):
+        raise ValueError(
+            f"a rotary table of {rotary.shape} for q {q.shape} and k "
+            f"{k.shape}: one row a position of q AND k, (T, D)")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     # tiles above the diagonal are skipped only where the kernel KNOWS
     # the positions: the defaults.  Explicit (traced) positions visit
     # every tile and mask it score by score, exactly as before
     plan = tile_plan(q.shape[1], k.shape[1], q.shape[-1], q.dtype, causal,
-                     default_positions=q_pos is None and k_pos is None)
+                     default_positions=q_pos is None and k_pos is None,
+                     rotary=rotary is not None)
     if q_pos is None:
         q_pos = jnp.arange(q.shape[1])
     if k_pos is None:
         k_pos = jnp.arange(k.shape[1])
     resolved = _resolve_impl(impl, q, k, plan)
+    if rotary is not None and (resolved == "xla" or not plan.rotates):
+        q, k, rotary = rotary_xla(q, rotary), rotary_xla(k, rotary), None
     if resolved == "xla":
         return _xla_attention(q, k, v, q_pos, k_pos, scale, causal)
-    return _fused(q, k, v, q_pos, k_pos, scale, causal,
+    if not plan.positions:
+        q_pos = k_pos = None
+    return _fused(q, k, v, q_pos, k_pos, rotary, scale, causal,
                   pallas_mode.interpret(), name, plan)
