@@ -67,7 +67,7 @@ def test_the_cell_is_declared_where_the_issue_says():
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "ouro_2_6b", "lm_s2048_seg1_x1", 1)
     assert "18%" in cell["why"] and "recomputed" in cell["why"]
-    assert bench["workloads"][-1] is cell and len(bench["workloads"]) == 6
+    assert bench["workloads"][5] is cell and len(bench["workloads"]) >= 6
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     config, = (c for c in bench["configs"] if c["name"] == "ouro_2_6b")
     assert config["reduced"] == ["num_hidden_layers"]
@@ -78,8 +78,9 @@ def test_the_cell_is_declared_where_the_issue_says():
               bench["end_to_end"] + bench["per_layer"]}
     assert {name for name, cells in listed.items()
             if cells and CELL in cells} == set(LISTS)
-    assert all(listed[name][-1] == CELL for name in LISTS)
-    roofline = bench["per_layer"][-1]
+    assert all(CELL in listed[name] for name in LISTS)
+    roofline, = (m for m in bench["per_layer"]
+                 if m["name"] == "attention_roofline_share")
     assert roofline == {
         "name": "attention_roofline_share", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels",

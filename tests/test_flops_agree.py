@@ -63,6 +63,7 @@ def _shapes_only_init(self, config=None, mesh=None, verbose=True,
     ("gpt2_medium", "lm_s128_x1"),
     ("zaya1_8b", "lm_s2048_x1"),
     ("ouro_2_6b", "lm_s2048_seg1_x1"),
+    ("nemotron_twotower_30b", "lm_s2048_seg4_x1"),
 ])
 def test_program_and_benchmark_count_the_same_flops(
         monkeypatch, config_name, traffic_name):

@@ -230,3 +230,137 @@ def test_rows_must_be_whole_tiles():
         G.gmm(jnp.ones((100, 8)), jnp.ones((1, 8, 8)),
               jnp.zeros(1, jnp.int32), jnp.asarray(1, jnp.int32),
               interpret=True)
+
+
+def _relu2_layer(n=300, d=16, f=24, n_experts=16, seed=11):
+    key = jax.random.key(seed)
+    u = jax.random.normal(key, (n, d))
+    scores = jax.nn.sigmoid(
+        2.0 * jax.random.normal(jax.random.fold_in(key, 1), (n, n_experts)))
+    experts = {
+        "up": 0.3 * jax.random.normal(jax.random.fold_in(key, 2),
+                                      (n_experts, d, f)),
+        "down": 0.3 * jax.random.normal(jax.random.fold_in(key, 3),
+                                        (n_experts, f, d))}
+    return u, scores, experts
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_two_matrix_experts_top_6_normalised_and_scaled(impl):
+    """``up`` and ``down`` alone are a relu^2 expert (the keys say so,
+    no flag); each of a token's 6 choices is weighted by its score over
+    the sum of ALL 6, held here or not, times the scale."""
+    u, scores, experts = _relu2_layer()
+    held = {k: v[4:12] for k, v in experts.items()}
+    out, stats = jax.jit(lambda u, s, p: routed_experts(
+        u, s, p, (4, 8), top_k=6, normalize=True, scale=2.5, impl=impl,
+        name="nemotron_h_experts"))(u, scores, held)
+    picked, chosen = jax.lax.top_k(scores, 6)
+    weights = 2.5 * picked / picked.sum(-1, keepdims=True)
+    want = 0
+    for e in range(4, 12):
+        y = jnp.square(jax.nn.relu(u @ experts["up"][e])) @ experts["down"][e]
+        want = want + ((chosen == e) * weights).sum(-1)[:, None] * y
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    assert stats["held_rows"] == ((chosen >= 4) & (chosen < 12)).sum()
+    assert stats["held_rows"] + stats["rows_elsewhere"] == 6 * 300
+    assert stats["expert_load"].sum() == 6 * 300
+    # without the normalisation the weights are the scores as they are
+    plain = routed_experts(u, scores, held, (4, 8), top_k=6, impl=impl)[0]
+    assert float(jnp.abs(plain - out).max()) > 1e-3
+
+
+def test_two_matrix_experts_interpreted_kernels_against_ragged_dot():
+    """Values and every gradient (tokens, scores, both matrices) of the
+    interpreted Pallas kernels against ``jax.lax.ragged_dot``, top-6,
+    at a width the column tiles do not divide (f = 200: a last tile of
+    72 columns in the up product, and the same along the down product's
+    contraction)."""
+    u, scores, experts = _relu2_layer(n=150, d=136, f=200, seed=13)
+    held = {k: v[:8] for k, v in experts.items()}
+
+    def loss(impl):
+        def fn(u, scores, held):
+            out, _ = routed_experts(u, scores, held, (0, 8), top_k=6,
+                                    normalize=True, scale=2.5, impl=impl)
+            return (out ** 2).sum(), out
+        return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    ((_, got_out), got), ((_, want_out), want) = (
+        loss(impl)(u, scores, held) for impl in ("pallas", "ragged_dot"))
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-4, atol=1e-4)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-3,
+                                   atol=1e-4 * float(jnp.abs(b).max()))
+
+
+def test_two_matrix_experts_carry_their_names():
+    u, scores, experts = _relu2_layer(n=20)
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda p: routed_experts(u, scores, p, (0, 16), top_k=6,
+                                 impl="pallas",
+                                 name="nemotron_h_experts")[0].sum()))(
+        experts))
+    for which in ("up", "down"):
+        for kernel in ("gmm", "gmm_t", "tgmm"):
+            assert f"nemotron_h_experts_{which}_{kernel}" in text
+    assert "nemotron_h_experts_gate" not in text
+
+
+@pytest.mark.parametrize("n, k, itemsize, tile", [
+    (2048, 2048, 2, 1024),    # ZayaLM's products: as before
+    (2048, 2048, 4, 512),     # ... and its weight gradient's accumulator
+    (1856, 2688, 2, 640),     # NemotronHLM up: 3 tiles, the last 576 wide
+    (2688, 1856, 2, 896),     # its down product: 3 whole tiles
+    (1856, 2688, 4, 384),     # the accumulators of the weight gradients:
+    (2688, 1856, 4, 384),     # 5 tiles, the last 320 wide; 7 whole tiles
+    (40, 24, 4, 40),          # small test shapes: the whole width
+    (200, 136, 2, 128),       # no tile wider than the product
+])
+def test_the_column_tile_at_the_widths_met(n, k, itemsize, tile):
+    """1856 = 14.5 x 128 has no dividing tile and 2688 = 21 x 128 no
+    power of two above 128: the tile is the multiple of 128 that costs
+    least within the block budget, and the last one may be partly
+    empty."""
+    assert G._column_tile(n, k, itemsize) == tile
+    assert n <= 128 or k * tile * itemsize <= G._RHS_BLOCK_BYTES
+
+
+def test_the_transposed_product_takes_whole_tiles_over_a_ragged_contraction():
+    """On the chip the transposed product hung with a partly empty last
+    tile where its contraction (1856) was no multiple of 128 (PR 34):
+    there only tiles that divide the width, or the whole width."""
+    assert G._column_tile(2688, 1856, 2, whole_tiles=True) == 896
+    assert G._column_tile(200, 136, 2, whole_tiles=True) == 200
+    assert G._column_tile(1856, 2688, 2, whole_tiles=True) == 1856
+
+
+def test_a_partly_empty_last_column_tile_in_all_three_kernels():
+    """(rows, 136) x (3, 136, 200): two column tiles of 128, the second
+    72 wide, forward and in both gradients."""
+    tile_group, n_tiles, mask, padded, spare = _tiled_layout([130, 0, 90])
+    rows = mask.shape[0]
+    key = jax.random.key(5)
+    lhs = jnp.where(mask[:, None], jax.random.normal(
+        jax.random.fold_in(key, 0), (rows, 136)), 0)
+    lhs = jnp.concatenate([lhs, jnp.zeros((spare, 136))])
+    rhs = jax.random.normal(jax.random.fold_in(key, 1), (3, 136, 200))
+    live = jnp.concatenate([mask, jnp.zeros(spare, bool)])[:, None]
+
+    def kernel(lhs, rhs):
+        out = G.grouped_matmul(lhs, rhs, tile_group, n_tiles, "test", True)
+        return jnp.where(live, out, 0)
+
+    def oracle(lhs, rhs):
+        return jnp.where(live, jax.lax.ragged_dot(lhs, rhs, padded), 0)
+
+    assert kernel(lhs, rhs).shape == (rows + spare, 200)
+    np.testing.assert_allclose(kernel(lhs, rhs), oracle(lhs, rhs),
+                               rtol=1e-5, atol=1e-4)
+    loss = lambda fn: lambda a, b: (fn(a, b) ** 2).sum()  # noqa: E731
+    got = jax.grad(loss(kernel), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(oracle), argnums=(0, 1))(lhs, rhs)
+    np.testing.assert_allclose(jnp.where(live, got[0], 0), want[0],
+                               rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-4, atol=1e-3)

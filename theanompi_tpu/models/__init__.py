@@ -26,6 +26,10 @@ MODEL_ZOO = {
     # a looped LM: one stack of layers run several times over shared
     # weights, an exit gate and the head after every pass (Ouro family)
     "ouro_lm": ("theanompi_tpu.models.ouro", "OuroLM"),
+    # a hybrid stack built from a pattern string: Mamba-2 state-space
+    # layers, sigmoid top-k experts beside a shared expert, one GQA
+    # layer in several (Nemotron-H family)
+    "nemotron_h_lm": ("theanompi_tpu.models.nemotron_h", "NemotronHLM"),
     # zoo variants (reference lasagne_model_zoo equivalents)
     "vgg19": ("theanompi_tpu.models.model_zoo", "VGG19"),
     "resnet101": ("theanompi_tpu.models.model_zoo", "ResNet101"),

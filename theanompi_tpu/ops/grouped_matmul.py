@@ -42,7 +42,10 @@ is the oracle and the path off the TPU.
 On-chip status (PR 27, TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34): the
 three kernels compile and run at (9216, 2048) x (8, 2048, 2048) bf16,
 row tile 128; the zaya1_8b cell's reference check holds the gradient
-inside an expert against float32.
+inside an expert against float32.  PR 34: at (50176, 2688) x (8, 2688,
+1856) and (50176, 1856) x (8, 1856, 2688), widths no power-of-two tile
+divides (``_column_tile``): tiles of 640 over 1856 columns, the last 576
+wide, and whole tiles of 896 and 384 over 2688.
 """
 
 from __future__ import annotations
@@ -63,14 +66,32 @@ _RHS_BLOCK_BYTES = 4 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
-def _column_tile(n: int, k: int, itemsize: int) -> int:
-    """Largest of 1024, 512, 256, 128 that divides ``n`` and keeps a
-    ``(k, tile)`` block of ``rhs`` inside ``_RHS_BLOCK_BYTES``; ``n``
-    itself when none divides it (small test shapes)."""
-    for tile in (1024, 512, 256, 128):
-        if n % tile == 0 and k * tile * itemsize <= _RHS_BLOCK_BYTES:
-            return tile
-    return n
+#: what one grid step costs beside its product, in columns of a step's
+#: product (about 0.35 us, a 128-column step at the widths met so far)
+_STEP_COLUMNS = 128
+
+
+def _column_tile(n: int, k: int, itemsize: int,
+                 whole_tiles: bool = False) -> int:
+    """The column tile of an ``n``-column product whose block of the
+    other operand is ``(k, tile)`` of ``itemsize`` bytes: of the
+    multiples of 128 up to 1024 inside ``_RHS_BLOCK_BYTES``, the one
+    whose tiles cost least, columns multiplied plus ``_STEP_COLUMNS`` a
+    grid step, the wider of two that cost the same.  Where none divides
+    ``n`` (1856 = 14.5 x 128) the last tile is partly empty: Pallas pads
+    what a block reads past the array's edge and drops what it writes
+    there, and a column of any of the three products depends on no
+    other column.  ``whole_tiles`` takes only tiles that divide ``n``.
+    ``n`` itself up to 128 columns (small test shapes) and where no
+    tile qualifies."""
+    if n <= 128:
+        return n
+    fit = [tile for tile in range(1024, 0, -128)
+           if tile <= n and k * tile * itemsize <= _RHS_BLOCK_BYTES
+           and not (whole_tiles and n % tile)]
+    if not fit:
+        return n if whole_tiles else 128
+    return min(fit, key=lambda tile: -(-n // tile) * (tile + _STEP_COLUMNS))
 
 
 def _params():
@@ -90,7 +111,11 @@ def gmm(lhs, rhs, tile_group, n_tiles, *, transpose_rhs: bool = False,
     if m % TILE_M:
         raise ValueError(f"{m} rows are no whole number of {TILE_M}-row "
                          "tiles")
-    tile_n = _column_tile(n, k, jnp.dtype(rhs.dtype).itemsize)
+    # transposed, a partly empty last tile over a contraction that is no
+    # multiple of 128 hung the chip (PR 34: 2688 rows of (8, 2688, 1856)
+    # in tiles of 1024; in whole tiles of 896 it ran): whole tiles there
+    tile_n = _column_tile(n, k, jnp.dtype(rhs.dtype).itemsize,
+                          whole_tiles=transpose_rhs and k % 128 != 0)
     contract = (((1,), (1,)), ((), ())) if transpose_rhs \
         else (((1,), (0,)), ((), ()))
 
@@ -117,7 +142,7 @@ def gmm(lhs, rhs, tile_group, n_tiles, *, transpose_rhs: bool = False,
             ],
             out_specs=pl.BlockSpec((TILE_M, tile_n),
                                    lambda j, t, group: (t, j)),
-            grid=(n // tile_n, n_tiles),
+            grid=(pl.cdiv(n, tile_n), n_tiles),
         ),
         compiler_params=_params(),
         interpret=interpret,
@@ -133,11 +158,8 @@ def tgmm(lhs, rhs, tile_group, n_tiles, n_groups: int, *,
     consecutive and every group owns at least one."""
     m, k = lhs.shape
     n = rhs.shape[1]
-    itemsize = jnp.dtype(rhs.dtype).itemsize
-    tile_n = _column_tile(n, k, itemsize)
     # the fp32 accumulator is a (k, tile_n) block: same budget
-    while k * tile_n * 4 > _RHS_BLOCK_BYTES and tile_n % 256 == 0:
-        tile_n //= 2
+    tile_n = _column_tile(n, k, 4)
 
     def kernel(tile_group_ref, lhs_ref, rhs_ref, out_ref, acc_ref):
         t = pl.program_id(1)
@@ -171,7 +193,7 @@ def tgmm(lhs, rhs, tile_group, n_tiles, n_groups: int, *,
             ],
             out_specs=pl.BlockSpec((None, k, tile_n),
                                    lambda j, t, group: (group[t], 0, j)),
-            grid=(n // tile_n, n_tiles),
+            grid=(pl.cdiv(n, tile_n), n_tiles),
             scratch_shapes=[pltpu.VMEM((k, tile_n), jnp.float32)],
         ),
         compiler_params=_params(),

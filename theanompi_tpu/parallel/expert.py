@@ -71,20 +71,26 @@ _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
                    held: tuple[int, int], top_k: int = 1,
                    select_by: jax.Array | None = None,
-                   impl: str | None = None, name: str = "routed_experts"):
+                   impl: str | None = None, name: str = "routed_experts",
+                   normalize: bool = False, scale: float = 1.0):
     """This chip's part of a dropless top-k expert layer.
 
-    ``x (n, d)`` tokens; ``probs (n, E)`` the router's probabilities
-    over ALL ``E`` experts; ``held = (first, count)``: this chip holds
-    experts ``first .. first + count - 1``, and ``expert_params`` are
-    theirs alone: ``gate`` and ``up`` ``(count, d, f)``, ``down``
-    ``(count, f, d)`` (a gated SiLU MLP).  Each token goes to its
-    ``top_k`` experts weighted by their probabilities (as they are: no
-    renormalisation); ``select_by (n, E)``, where given, is what the
-    top-k is taken over in place of ``probs`` (a balancing bias moves
-    the choice and not the weight).  Returns ``(out, stats)``: ``out
-    (n, d)`` is
-    ``sum over a token's chosen experts HELD HERE of p_e * expert_e(x)``
+    ``x (n, d)`` tokens; ``probs (n, E)`` the router's scores (softmax
+    probabilities, sigmoids) over ALL ``E`` experts; ``held = (first,
+    count)``: this chip holds experts ``first .. first + count - 1``,
+    and ``expert_params`` are theirs alone.  Their keys say what an
+    expert is: ``gate`` and ``up`` ``(count, d, f)`` with ``down``
+    ``(count, f, d)`` a gated SiLU MLP, ``(silu(x gate) * (x up))
+    down``; ``up`` and ``down`` alone a two-matrix MLP with a squared
+    ReLU between them, ``relu(x up)^2 down``.  Each token goes to its
+    ``top_k`` experts weighted by their scores: as they are, or with
+    ``normalize`` divided by their sum over ALL the token's chosen
+    experts, held here or not (``w = p / (sum of the k chosen p +
+    1e-20)``), and in either case times ``scale``.  ``select_by (n,
+    E)``, where given, is what the top-k is taken over in place of
+    ``probs`` (a balancing bias moves the choice and not the weight).
+    Returns ``(out, stats)``: ``out (n, d)`` is
+    ``sum over a token's chosen experts HELD HERE of w_e * expert_e(x)``
     and zero for a token none of whose experts is held; ``stats`` counts
     the rows this chip multiplied (``held_rows``), the assignments that
     went elsewhere (``rows_elsewhere``) and the fullest held expert's
@@ -97,7 +103,8 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     whole tiles of ``TILE_M`` and placed expert by expert in a buffer
     of static size ``n * top_k + count * TILE_M`` rows; an empty expert
     keeps one tile of zero rows.  The buffer's rows are gathered from
-    ``x``, pass through three grouped products, and are gathered back.
+    ``x``, pass through the expert's grouped products (three, or two),
+    and are gathered back.
 
     ``impl``: ``'pallas'`` (the kernels; default on a TPU, interpreted
     on the CPU platform when forced) or ``'ragged_dot'``
@@ -120,6 +127,10 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     else:
         chosen = lax.top_k(select_by, top_k)[1]
         weights = jnp.take_along_axis(probs, chosen, axis=-1)
+    if normalize:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    if scale != 1.0:
+        weights = weights * scale
     local = chosen.reshape(-1) - first                         # (n*k,)
     here = (local >= 0) & (local < count)
     # one-hot over the HELD experts only: (n*k, count), all-false rows
@@ -161,9 +172,13 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
             del which
             return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), padded_sizes)
 
-    gate = matmul(buf, expert_params["gate"], "gate")
-    up = matmul(buf, expert_params["up"], "up")
-    out_rows = matmul(jax.nn.silu(gate) * up, expert_params["down"], "down")
+    if "gate" in expert_params:
+        gate = matmul(buf, expert_params["gate"], "gate")
+        hidden = jax.nn.silu(gate) * matmul(buf, expert_params["up"], "up")
+    else:
+        hidden = jnp.square(jax.nn.relu(
+            matmul(buf, expert_params["up"], "up")))
+    out_rows = matmul(hidden, expert_params["down"], "down")
     picked = _take_rows(out_rows, dest_nk, here_nk, src[:, None],
                         placed[:, None])                       # (n, k, d)
     out = (picked * weights[..., None].astype(picked.dtype)).sum(1)
