@@ -341,6 +341,8 @@ def test_trains_through_the_base_loop_and_counts_its_rows(tmp_path):
         elsewhere = registry.value("moe/rows_elsewhere")
         fullest = registry.value("moe/max_expert_rows")
         share = registry.value("moe/held_share")
+        buffers = registry.value("moe/buffer_rows")
+        fill = registry.value("moe/buffer_fill")
     bias = model.state.model_state["router_state"]["Layer_1"]["moe"]["bias"]
     model.cleanup()
     losses = recorder.train_losses
@@ -358,6 +360,17 @@ def test_trains_through_the_base_loop_and_counts_its_rows(tmp_path):
     assert 0 < fullest <= 32
     last = N.routing_log[-1]
     assert share == pytest.approx(sum(last["held_rows"]) / (10 * 4 * 3 * 32))
+    # the buffers they lay in: one rung at this shape (96 assignments
+    # padded to a tile, and a tile of slack for each of 4 held experts),
+    # in each of 4 expert layers; the gauge is the newest flush's fill
+    assert last["buffer_rows"] == [4 * 640.0] * 10
+    assert buffers == 30 * 4 * 640
+    assert fill == pytest.approx(sum(last["held_rows"]) / (10 * 4 * 640))
+    # the older keys are what the roofline reader under
+    # benchmarks/layer_metrics/ takes
+    assert set(last) == {"held_rows", "rows_elsewhere", "max_expert_rows",
+                         "buffer_rows", "n_layers", "top_k", "expert_shape",
+                         "profiled"}
     assert float(jnp.abs(bias).max()) > 0     # the controller moved it
 
 

@@ -364,3 +364,195 @@ def test_a_partly_empty_last_column_tile_in_all_three_kernels():
     np.testing.assert_allclose(jnp.where(live, got[0], 0), want[0],
                                rtol=1e-4, atol=1e-3)
     np.testing.assert_allclose(got[1], want[1], rtol=1e-4, atol=1e-3)
+
+
+# --- the buffer's ladder (PR 35) --------------------------------------
+
+@pytest.mark.parametrize("n_assign, count, n_experts, rungs", [
+    (8192 * 6, 8, 128, (7168, 50176)),   # NemotronHLM's cell: two rungs
+    (8192, 8, 16, (9216,)),              # ZayaLM's: the top one alone
+    (300 * 6, 8, 128, (1280, 2944)),     # the small shape forced below
+    (300 * 6, 8, 16, (2944,)),           # half the experts held: no room
+    (300, 8, 16, (1408,)),               # top-1 (384 + 1024)
+    (100, 2, 64, (384,)),                # the slack of a tile an expert
+    (4096 * 8, 4, 256, (1536, 33280)),   # top-8 of 256, 4 held
+])
+def test_the_ladder_is_a_function_of_the_calls_shape(n_assign, count,
+                                                     n_experts, rungs):
+    """The top rung is the dropless buffer; a lower rung of twice the
+    expected load exists only where it is at most half of that."""
+    from theanompi_tpu.parallel.expert import buffer_ladder
+    got = buffer_ladder(n_assign, count, n_experts)
+    assert got == rungs
+    assert all(r % G.TILE_M == 0 for r in got)
+    assert got[-1] >= n_assign + count * G.TILE_M
+    assert all(2 * low <= got[-1] for low in got[:-1])
+
+
+#: routings that force a rung of the (1280, 2944) ladder of 300 tokens,
+#: top-6 of 128 experts, 8 held: name -> (expert -> the tokens that pick
+#: it, the rung's rows, the held rows)
+_ROUTINGS = {
+    # a deployment's share: 8 tiles, the lower rung with room
+    "few": ({e: range(20 * e, 20 * e + 12) for e in range(8)}, 1280, 96),
+    # 3 + 7 tiles: exactly the lower rung's 10
+    "full": ({0: range(300), **{e: range(35 * e, 35 * e + 30)
+                                for e in range(1, 8)}}, 1280, 510),
+    # 3 + 2 + 6 tiles: one more than it holds
+    "one_more": ({0: range(300), 1: range(40, 170),
+                  **{e: range(35 * e, 35 * e + 30) for e in range(2, 8)}},
+                 2944, 610),
+    # every token's six choices held here: the worst case
+    "all": ({e: range(300) for e in range(6)}, 2944, 1800),
+}
+
+
+def _forced_layer(routing, form, seed=17):
+    """Tokens, scores and the held experts' matrices: the planned
+    assignments score 0.5-0.9, a held expert scores 0 elsewhere, and a
+    token's other choices fall on experts 8-127."""
+    plan = _ROUTINGS[routing][0]
+    key = jax.random.key(seed)
+    u, _, experts = _relu2_layer(n_experts=8, seed=seed)
+    if form == "gated":
+        experts["gate"] = 0.3 * jax.random.normal(
+            jax.random.fold_in(key, 5), experts["up"].shape)
+    scores = np.array(0.01 + 0.09 * jax.random.uniform(
+        jax.random.fold_in(key, 6), (300, 128)))
+    scores[:, :8] = 0.0
+    high = np.array(0.5 + 0.4 * jax.random.uniform(
+        jax.random.fold_in(key, 7), (300, 8)))
+    for e, tokens in plan.items():
+        scores[list(tokens), e] = high[list(tokens), e]
+    return u, jnp.asarray(scores), experts
+
+
+def _uncut(u, scores, experts):
+    """The layer with no buffer at all: every held expert applied to
+    every token, weighted where the token chose it."""
+    picked, chosen = jax.lax.top_k(scores, 6)
+    weights = 2.5 * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    out = 0
+    for e in range(8):
+        up = u @ experts["up"][e]
+        hidden = (jax.nn.silu(u @ experts["gate"][e]) * up
+                  if "gate" in experts else jnp.square(jax.nn.relu(up)))
+        out = out + (((chosen == e) * weights).sum(-1)[:, None]
+                     * (hidden @ experts["down"][e]))
+    return out
+
+
+@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("routing", list(_ROUTINGS))
+def test_every_rung_is_the_uncut_layer(routing, impl, form):
+    """Output, every gradient (tokens, scores, each matrix) and the
+    counters on each rung of the ladder, top-6 normalised and scaled:
+    the lower rung sums from the buffer's side, the top one gathers as
+    before, and both are differentiated through the ladder's own VJP."""
+    u, scores, experts = _forced_layer(routing, form)
+    _, rows, held_rows = _ROUTINGS[routing]
+
+    def layer(u, scores, experts):
+        return routed_experts(u, scores, experts, (0, 8), top_k=6,
+                              normalize=True, scale=2.5, impl=impl)
+
+    def loss(fn):
+        def scalar(u, scores, experts):
+            out = fn(u, scores, experts)
+            out, stats = out if isinstance(out, tuple) else (out, None)
+            return (out ** 2).sum(), (out, stats)
+        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    (_, (got_out, stats)), got = loss(layer)(u, scores, experts)
+    (_, (want_out, _)), want = loss(_uncut)(u, scores, experts)
+    assert (stats["buffer_rows"], stats["held_rows"]) == (rows, held_rows)
+    assert stats["held_rows"] + stats["rows_elsewhere"] == 1800
+    np.testing.assert_allclose(got_out, want_out, rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max() + 1))
+
+
+def test_the_ladder_is_recomputed_under_remat_like_the_uncut_layer():
+    """``jax.checkpoint`` round the layer, as ``nn.remat`` puts it in
+    the models: the ladder's VJP keeps only its operands."""
+    u, scores, experts = _forced_layer("few", "relu2")
+
+    def loss(fn):
+        return jax.jit(jax.grad(jax.checkpoint(
+            lambda *a: (fn(*a) ** 2).sum()), argnums=(0, 1, 2)))
+
+    got = loss(lambda u, s, p: routed_experts(
+        u, s, p, (0, 8), top_k=6, normalize=True, scale=2.5)[0])(
+            u, scores, experts)
+    want = loss(_uncut)(u, scores, experts)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max() + 1))
+
+
+def test_one_rung_is_the_body_alone(monkeypatch):
+    """At ``ZayaLM``'s call shape (8 192 tokens, top-1, 8 of 16 held)
+    the ladder has one rung: no ``cond`` in the program, the ladder's
+    VJP is not entered, and the one body is traced once at 9 216 rows,
+    differentiated by JAX as before (``buffer_rows`` at one rung:
+    tests/test_zaya.py, tests/test_nemotron_h.py)."""
+    from theanompi_tpu.parallel import expert
+
+    def no_ladder(*args):
+        raise AssertionError("one rung needs no switch")
+
+    traced = []
+    rung = expert._rung
+    monkeypatch.setattr(expert, "_ladder", no_ladder)
+    monkeypatch.setattr(expert, "_rung", lambda rows, *a: (
+        traced.append(rows), rung(rows, *a))[1])
+    x = jax.ShapeDtypeStruct((8192, 32), jnp.bfloat16)
+    probs = jax.ShapeDtypeStruct((8192, 16), jnp.float32)
+    experts = {k: jax.ShapeDtypeStruct(
+        (8, 48, 32) if k == "down" else (8, 32, 48), jnp.float32)
+        for k in ("gate", "up", "down")}
+
+    def fn(x, probs, experts):
+        out, stats = routed_experts(x, probs, experts, (0, 8),
+                                    select_by=jnp.log(probs),
+                                    impl="ragged_dot", name="zaya_experts")
+        return out.astype(jnp.float32).sum(), stats
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        fn, argnums=(0, 1, 2), has_aux=True))(x, probs, experts))
+    assert traced == [9216]
+    assert "cond[" not in text and "9216" in text
+
+
+def test_the_plan_is_said_once_a_shape(caplog):
+    from theanompi_tpu.parallel import expert
+    expert._log_buffer_plan.cache_clear()
+    u, scores, experts = _forced_layer("few", "relu2")
+    with caplog.at_level("INFO", logger=expert.__name__):
+        for _ in range(2):
+            routed_experts(u, scores, experts, (0, 8), top_k=6,
+                           name="nemotron_h_experts")
+    said = [r.getMessage() for r in caplog.records]
+    assert said == ["nemotron_h_experts: expert buffer: rungs 1280 / 2944 "
+                    "of 128-row tiles"]
+
+
+def test_the_kernels_keep_their_names_inside_the_ladders_backward():
+    """The ladder's backward pass runs ``jax.vjp`` of a rung, and JAX
+    renames the first scope under a transformation (``jvp(...)``): it
+    must not be a kernel's, whose HLO instruction the trace's readers
+    find by its plain name (``..._gmm``, ``..._gmm_t``, ``..._tgmm``)."""
+    u, scores, experts = _forced_layer("few", "relu2")
+    text = jax.jit(jax.value_and_grad(
+        lambda u, s, p: routed_experts(u, s, p, (0, 8), top_k=6,
+                                       impl="pallas",
+                                       name="nemotron_h_experts")[0].sum(),
+        argnums=(0, 1, 2))).lower(u, scores, experts).as_text(
+            debug_info=True)
+    for which in ("up", "down"):
+        for kernel in ("gmm", "gmm_t", "tgmm"):
+            assert f'/nemotron_h_experts_{which}_{kernel}/' in text
+    assert "(nemotron_h_experts" not in text

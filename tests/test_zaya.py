@@ -95,6 +95,8 @@ def test_trains_through_the_base_loop_and_counts_its_rows(tmp_path):
         held = registry.value("moe/held_rows")
         elsewhere = registry.value("moe/rows_elsewhere")
         fullest = registry.value("moe/max_expert_rows")
+        buffers = registry.value("moe/buffer_rows")
+        fill = registry.value("moe/buffer_fill")
     model.cleanup()
     losses = recorder.train_losses
     assert len(losses) == 30 and losses[-1] < losses[0] - 0.3
@@ -107,6 +109,15 @@ def test_trains_through_the_base_loop_and_counts_its_rows(tmp_path):
     assert held + elsewhere == 30 * 2 * 48
     assert held == sum(sum(e["held_rows"]) for e in zaya.routing_log)
     assert 0 < fullest <= 48
+    # the buffer they lay in: one rung at this shape (48 assignments
+    # padded to a tile, and a tile of slack for each of 2 held experts),
+    # in each of 2 layers; the gauge is the newest flush's fill
+    assert entry["buffer_rows"] == [2 * 384.0] * 10
+    assert buffers == 30 * 2 * 384
+    assert fill == pytest.approx(sum(entry["held_rows"]) / (10 * 2 * 384))
+    # what the roofline reader under benchmarks/layer_metrics/ takes
+    assert set(entry) == {"held_rows", "buffer_rows", "n_layers",
+                          "expert_shape", "profiled"}
 
 
 def test_the_balancing_controller_evens_the_experts_loads():
@@ -150,7 +161,8 @@ def test_bfloat16_compute_keeps_float32_state_and_a_finite_loss():
     assert all(leaf.dtype == jnp.float32
                for leaf in jax.tree.leaves(model.state.params))
     assert set(metrics) == {"loss", "error", "moe_held_rows",
-                            "moe_rows_elsewhere", "moe_max_expert_rows"}
+                            "moe_rows_elsewhere", "moe_max_expert_rows",
+                            "moe_buffer_rows"}
 
 
 def test_remat_changes_no_value():

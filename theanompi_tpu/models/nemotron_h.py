@@ -54,11 +54,12 @@ Tracing: ``jax.named_scope``s ``nemotron_h/mamba/in_proj``, ``/conv``,
 ``nemotron_h/attention`` and ``nemotron_h/loss``; the kernels are named
 ``nemotron_h_experts_{up,down}_{gmm,gmm_t,tgmm}`` and
 ``nemotron_h_attention_{fwd,bwd}``; the scan's plan is one log line a
-shape.  Each step's metrics carry the rows this chip's experts
-multiplied; ``_flush_metrics`` feeds them to ``monitor``
+shape, the expert buffer's ladder another.  Each step's metrics carry
+the rows this chip's experts multiplied and the rows of the buffers
+they lay in; ``_flush_metrics`` feeds them to ``monitor``
 (``moe/held_rows``, ``moe/rows_elsewhere``, ``moe/max_expert_rows``,
-``moe/held_share``) and appends them to this module's ``routing_log``
-(docs/OBSERVABILITY.md).
+``moe/held_share``, ``moe/buffer_rows``, ``moe/buffer_fill``) and
+appends them to this module's ``routing_log`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -89,6 +90,7 @@ _log = logging.getLogger(__name__)
 #: whether or not a ``monitor`` session is on; one entry a flush, newest
 #: last: ``{"held_rows": [rows of each flushed step, summed over the
 #: expert layers], "rows_elsewhere": [...], "max_expert_rows": [...],
+#: "buffer_rows": [the rows of the buffers they were laid out in],
 #: "n_layers": expert layers, "top_k": ..., "expert_shape": (held
 #: experts, d_model, expert_width), "profiled": whether a
 #: ``jax.profiler`` trace was being captured at the flush}``.
@@ -97,7 +99,7 @@ _log = logging.getLogger(__name__)
 routing_log: collections.deque = collections.deque(maxlen=256)
 
 _ROUTING_KEYS = ("moe_held_rows", "moe_rows_elsewhere",
-                 "moe_max_expert_rows")
+                 "moe_max_expert_rows", "moe_buffer_rows")
 #: the layer kinds a pattern may name
 KINDS = "ME*"
 #: the balancing controller's gain: after a step an expert's correction
@@ -353,7 +355,7 @@ class NemotronHLMNet(nn.Module):
         NemotronHHead(self.d_model, self.vocab, name="head")()
         # explicit names pin the tree to the layout without remat
         layer_cls = nn.remat(NemotronHLayer) if self.remat else NemotronHLayer
-        held = elsewhere = fullest = jnp.zeros((), jnp.float32)
+        held = elsewhere = fullest = buffer = jnp.zeros((), jnp.float32)
         for i, kind in enumerate(self.pattern):
             x, stats = layer_cls(kind, self.mixers[kind], self.rms_eps,
                                  self.dtype, name=f"Layer_{i}")(x)
@@ -361,10 +363,12 @@ class NemotronHLMNet(nn.Module):
                 held += stats["held_rows"]
                 elsewhere += stats["rows_elsewhere"]
                 fullest = jnp.maximum(fullest, stats["max_expert_rows"])
+                buffer += stats["buffer_rows"]
         x = nn.RMSNorm(epsilon=self.rms_eps, dtype=self.dtype,
                        name="final_norm")(x)
         return x, {"moe_held_rows": held, "moe_rows_elsewhere": elsewhere,
-                   "moe_max_expert_rows": fullest}
+                   "moe_max_expert_rows": fullest,
+                   "moe_buffer_rows": buffer}
 
 
 def nemotron_h_train_flops(*, pattern: str, d_model: int, vocab: int,
@@ -534,7 +538,7 @@ class NemotronHLM(TpuModel):
         from theanompi_tpu import monitor
 
         if self._pending and "E" in self._net_cfg["pattern"]:
-            held, elsewhere, fullest = (
+            held, elsewhere, fullest, buffer = (
                 np.concatenate([np.atleast_1d(np.asarray(m[key]))
                                 for _, m in self._pending])
                 for key in _ROUTING_KEYS)
@@ -544,6 +548,7 @@ class NemotronHLM(TpuModel):
                 "held_rows": [float(x) for x in held],
                 "rows_elsewhere": [float(x) for x in elsewhere],
                 "max_expert_rows": [float(x) for x in fullest],
+                "buffer_rows": [float(x) for x in buffer],
                 "n_layers": c["pattern"].count("E"),
                 "top_k": experts["top_k"],
                 "expert_shape": (experts["held_experts"][1], c["d_model"],
@@ -552,6 +557,9 @@ class NemotronHLM(TpuModel):
             monitor.inc("moe/held_rows", float(held.sum()))
             monitor.inc("moe/rows_elsewhere", float(elsewhere.sum()))
             monitor.set_gauge("moe/max_expert_rows", float(fullest.max()))
+            monitor.inc("moe/buffer_rows", float(buffer.sum()))
+            monitor.set_gauge("moe/buffer_fill",
+                              float(held.sum() / buffer.sum()))
             monitor.set_gauge("moe/held_share", float(
                 held.sum() / max(held.sum() + elsewhere.sum(), 1.0)))
         super()._flush_metrics(recorder)

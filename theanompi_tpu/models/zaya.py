@@ -46,10 +46,11 @@ Tracing: the step carries ``jax.named_scope``s ``zaya/cca``,
 ``zaya/router``, ``zaya/experts`` and ``zaya/loss``; the kernels are
 named ``zaya_cca_attention_{fwd,bwd}`` and
 ``zaya_experts_{gate,up,down}_{gmm,gmm_t,tgmm}``.  Each step's metrics
-carry the rows this chip's experts multiplied; ``_flush_metrics`` feeds
-them to ``monitor`` (``moe/held_rows``, ``moe/rows_elsewhere``,
-``moe/max_expert_rows``) and appends them, step by step, to this
-module's ``routing_log`` (docs/OBSERVABILITY.md).
+carry the rows this chip's experts multiplied and the rows of the
+buffers they lay in; ``_flush_metrics`` feeds them to ``monitor``
+(``moe/held_rows``, ``moe/rows_elsewhere``, ``moe/max_expert_rows``,
+``moe/buffer_rows``, ``moe/buffer_fill``) and appends them, step by
+step, to this module's ``routing_log`` (docs/OBSERVABILITY.md).
 """
 
 from __future__ import annotations
@@ -73,14 +74,15 @@ from theanompi_tpu.utils.profiling import trace_running
 #: what the held experts multiplied in this process's last flushes,
 #: whether or not a ``monitor`` session is on; one entry a flush,
 #: newest last: ``{"held_rows": [rows of each flushed step, summed
-#: over the layers], "n_layers": ..., "expert_shape": (held experts,
+#: over the layers], "buffer_rows": [the rows of the buffers they were
+#: laid out in], "n_layers": ..., "expert_shape": (held experts,
 #: d_model, expert_width), "profiled": whether a ``jax.profiler``
 #: trace was being captured at the flush}``.  ``profiled`` is how a
 #: reader of a device trace finds the steps its trace holds.
 routing_log: collections.deque = collections.deque(maxlen=256)
 
 _ROUTING_KEYS = ("moe_held_rows", "moe_rows_elsewhere",
-                 "moe_max_expert_rows")
+                 "moe_max_expert_rows", "moe_buffer_rows")
 #: the balancing controller's gain: after a step an expert's bias moves
 #: by ``-BALANCE_GAIN * (its load / the mean load - 1)``, and stays
 #: inside ``+-BIAS_LIMIT`` (log-probability units)
@@ -283,16 +285,18 @@ class ZayaLMNet(nn.Module):
                      embedding_init=L.gaussian_init(0.02),
                      name="embed")(tokens).astype(dtype)
         layer_cls = nn.remat(ZayaLayer) if self.remat else ZayaLayer
-        held = elsewhere = fullest = 0.0
+        held = elsewhere = fullest = buffer = 0.0
         for i in range(self.n_layers):
             x, stats = layer_cls(**self.layer, name=f"Layer_{i}")(x)
             held += stats["held_rows"]
             elsewhere += stats["rows_elsewhere"]
             fullest = jnp.maximum(fullest, stats["max_expert_rows"])
+            buffer += stats["buffer_rows"]
         x = nn.RMSNorm(epsilon=self.layer["rms_eps"], dtype=dtype,
                        name="final_norm")(x)
         return x, {"moe_held_rows": held, "moe_rows_elsewhere": elsewhere,
-                   "moe_max_expert_rows": fullest}
+                   "moe_max_expert_rows": fullest,
+                   "moe_buffer_rows": buffer}
 
 
 def zaya_train_flops(*, n_layers: int, d_model: int, n_heads: int,
@@ -425,13 +429,14 @@ class ZayaLM(TpuModel):
         from theanompi_tpu import monitor
 
         if self._pending:
-            held, elsewhere, fullest = (
+            held, elsewhere, fullest, buffer = (
                 np.concatenate([np.atleast_1d(np.asarray(m[key]))
                                 for _, m in self._pending])
                 for key in _ROUTING_KEYS)
             c = self._net_cfg
             routing_log.append({
                 "held_rows": [float(x) for x in held],
+                "buffer_rows": [float(x) for x in buffer],
                 "n_layers": c["n_layers"],
                 "expert_shape": (c["held_experts"][1], c["d_model"],
                                  c["expert_width"]),
@@ -439,4 +444,7 @@ class ZayaLM(TpuModel):
             monitor.inc("moe/held_rows", float(held.sum()))
             monitor.inc("moe/rows_elsewhere", float(elsewhere.sum()))
             monitor.set_gauge("moe/max_expert_rows", float(fullest.max()))
+            monitor.inc("moe/buffer_rows", float(buffer.sum()))
+            monitor.set_gauge("moe/buffer_fill",
+                              float(held.sum() / buffer.sum()))
         super()._flush_metrics(recorder)

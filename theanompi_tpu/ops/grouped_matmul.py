@@ -15,7 +15,7 @@ names each tile's group, ``n_tiles`` (a traced scalar: the grid's
 extent) says how many leading tiles are in use.  **Rows of tiles past
 ``n_tiles`` are never read and never written**: what the output holds
 there is undefined, and the caller must not read it (``routed_experts``
-gathers only rows it placed).
+gathers, or masks before it sums, only rows it placed).
 
 Three kernels, each a ``pallas_call`` with a ``name=`` so that a trace
 reducer can find them (``<name>_gmm``, ``<name>_gmm_t``,
@@ -45,7 +45,8 @@ row tile 128; the zaya1_8b cell's reference check holds the gradient
 inside an expert against float32.  PR 34: at (50176, 2688) x (8, 2688,
 1856) and (50176, 1856) x (8, 1856, 2688), widths no power-of-two tile
 divides (``_column_tile``): tiles of 640 over 1856 columns, the last 576
-wide, and whole tiles of 896 and 384 over 2688.
+wide, and whole tiles of 896 and 384 over 2688; since PR 35 mostly at
+7168 rows, inside the branches of ``routed_experts``' buffer ladder.
 """
 
 from __future__ import annotations

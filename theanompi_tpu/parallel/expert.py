@@ -26,10 +26,49 @@ one-hot, and nothing stands in for the chips that hold the other
 experts: across an expert-parallel group the shares add up to the
 whole layer (tests/test_routed_experts.py).  The capacity path above
 stays for ``TransformerLM_MoE`` until that model moves over.
+
+Layout of ``routed_experts``.  The ``n * top_k`` assignments are counted
+per held expert (a cumulative sum, no sort), each expert's rows are
+padded up to whole tiles of ``TILE_M`` and placed expert by expert in a
+buffer; an empty expert keeps one tile of zero rows.  The buffer's rows
+are copies of their tokens' rows of ``x``, pass through the expert's
+grouped products (three, or two), and are summed back into the tokens.
+
+* **The ladder** (PR 35).  A dropless layer on static shapes must be
+  ready for every assignment: ``ceil(n * top_k / TILE_M) * TILE_M +
+  count * TILE_M`` rows, 50 176 where a chip that holds 8 of 128
+  experts sees about 1 700, and every XLA pass round the kernels walks
+  the buffer's static size.  So the size is chosen on the chip, each
+  call, from ``buffer_ladder``: the top rung is that worst case, a
+  lower rung of twice the expected load exists where it is at most
+  half of it (7 168 / 50 176 there; one rung, and no ``switch`` at all,
+  where half of the experts are held).  ``lax.switch`` on the tiles in
+  use runs the smallest rung that holds them, from the placement map to
+  the ``(n, d)`` output (``_rung``): no host round trip, no recompile,
+  and never a dropped row.
+* **The sum from the buffer's side.**  On a rung with fewer rows than
+  there are assignments, a placed row knows its token and its weight,
+  and the output is a scatter-add of the rung's weighted rows into the
+  tokens, in float32 (``_move_rows``; its transpose is the gather that
+  fills the buffer).  On the top rung the buffer is the
+  longer side, and each token gathers its ``top_k`` rows (``_take_rows``,
+  whose transpose is a gather too).  Read on a TPU v5 lite at ``(n, d)``
+  = (8 192, 2 688) bf16 (PR 35): summing 7 168 rows into the tokens takes
+  1.09 ms as a scatter-add, 1.71 ms as a one-hot product on the MXU and
+  2.53 ms as the gather of ``(n, 6, d)`` picks with its weighted sum;
+  a scatter-add of 50 176 rows 6.0 ms.  A gather of 7 168 rows 0.27 ms,
+  of 50 176 rows 1.3 ms.
+* **The ladder's own VJP** (``_ladder``).  JAX differentiates a
+  conditional by making every branch return every branch's residuals,
+  zero-filled where not taken: the top rung's buffers would be written
+  as zeros each step.  The ladder keeps its operands alone and its
+  backward pass is a second ``switch`` over ``jax.vjp`` of each rung.
 """
 
 from __future__ import annotations
 
+import functools
+import logging
 from typing import Any
 
 import jax
@@ -41,16 +80,20 @@ from theanompi_tpu.ops.grouped_matmul import TILE_M, grouped_matmul
 from theanompi_tpu.parallel.mesh import AXIS_EXPERT
 
 PyTree = Any
+_log = logging.getLogger(__name__)
 
 
 @jax.custom_vjp
 def _take_rows(x, idx, mask, inv_idx, inv_mask):
     """``where(mask, x[idx], 0)``: rows of ``x (R, d)`` picked by an
     index array of any shape.  The picks are a partial permutation
-    whose inverse the caller knows, so the transpose is a gather too
-    (a scatter-add of rows is the slow form on a TPU): row ``r`` of
-    ``x`` receives the cotangent rows ``inv_idx[r, :]`` of the
-    flattened output where ``inv_mask[r, :]``."""
+    whose inverse the caller knows, so the transpose is a gather too:
+    row ``r`` of ``x`` receives the cotangent rows ``inv_idx[r, :]`` of
+    the flattened output where ``inv_mask[r, :]``.  The form of a
+    buffer with more rows than there are assignments: a scatter-add of
+    50 176 rows of 2 688 takes 6.0 ms on a TPU v5 lite, of 7 168 rows
+    1.09 ms (PR 35), which is why the lower rungs sum from the buffer's
+    side (``_move_rows``) and the top one does not."""
     return jnp.where(mask[..., None], x[idx], 0).astype(x.dtype)
 
 
@@ -66,6 +109,158 @@ def _take_rows_bwd(res, g):
 
 
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _move_rows(a, token, placed, n: int, to_buffer: bool):
+    """Rows between the tokens ``(n, d)`` and a buffer ``(R, d)`` whose
+    placed row ``r`` belongs to token ``token[r]``, in ``a.dtype``.
+    ``to_buffer``: ``where(placed, a[token], 0)``, a gather.  Else its
+    transpose, ``out[t] = sum of the placed rows r with token[r] == t``:
+    a scatter-add, summed in float32.  The lower rungs' form; each
+    direction is the other's VJP."""
+    if to_buffer:
+        return jnp.where(placed[:, None], a[token], 0).astype(a.dtype)
+    out = jnp.zeros((n, a.shape[-1]), jnp.float32).at[
+        jnp.where(placed, token, n)].add(a.astype(jnp.float32), mode="drop")
+    return out.astype(a.dtype)
+
+
+def _move_rows_fwd(a, token, placed, n, to_buffer):
+    return _move_rows(a, token, placed, n, to_buffer), (token, placed)
+
+
+def _move_rows_bwd(n, to_buffer, res, g):
+    return _move_rows(g, *res, n, not to_buffer), None, None
+
+
+_move_rows.defvjp(_move_rows_fwd, _move_rows_bwd)
+
+
+def buffer_ladder(n_assign: int, count: int, n_experts: int) -> tuple:
+    """The static sizes, in rows, a chip's buffer may take for
+    ``n_assign`` assignments over ``n_experts`` experts of which it
+    holds ``count``, smallest first.  The top rung holds every
+    assignment (each expert's rows padded to whole tiles), so the layer
+    is dropless whatever the router does; a lower rung of twice the
+    expected load exists only where it is at most half of the top
+    one."""
+    slack = count * TILE_M
+    top = -(-n_assign // TILE_M) * TILE_M + slack
+    twice = -(-2 * n_assign * count // n_experts)
+    low = -(-twice // TILE_M) * TILE_M + slack
+    return (low, top) if 2 * low <= top else (top,)
+
+
+@functools.lru_cache(maxsize=None)
+def _log_buffer_plan(name: str, rungs: tuple) -> None:
+    """The ladder is a pure function of the call's shape, so its plan
+    is said once a shape (trace time only), as ``tile_plan`` says its
+    own; ``stats["buffer_rows"]`` counts which rung a step took."""
+    _log.info("%s: expert buffer: rungs %s of %d-row tiles", name,
+              " / ".join(str(r) for r in rungs), TILE_M)
+
+
+def _rung(rows: int, top_k: int, impl: str, name: str, x, weights,
+          expert_params, dest, here, tiles, tile_ends):
+    """The layer from the placement map to the ``(n, d)`` output in a
+    buffer of ``rows`` rows; ``dest (n * top_k,)`` is each assignment's
+    buffer row where ``here``, ``tiles`` and ``tile_ends (count,)`` each
+    held expert's tiles and their running sum."""
+    n, _ = x.shape
+    count = tiles.shape[0]
+    n_assign = n * top_k
+    # the buffer row -> assignment map: a 1-D integer scatter
+    src = jnp.full((rows,), n_assign, jnp.int32).at[
+        jnp.where(here, dest, rows)].set(
+        jnp.arange(n_assign, dtype=jnp.int32), mode="drop")
+    placed = src < n_assign
+    src = jnp.where(placed, src, 0)
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_ends, jnp.arange(rows // TILE_M),
+                         side="right"), count - 1).astype(jnp.int32)
+    n_tiles = tile_ends[-1]
+    from_buffer = rows < n_assign      # sum over the shorter side
+
+    if from_buffer:
+        token = src // top_k
+        buf = _move_rows(x, token, placed, n, True)
+    else:
+        dest_nk = dest.reshape(n, top_k)
+        here_nk = here.reshape(n, top_k)
+        buf = _take_rows(x, src // top_k, placed, dest_nk, here_nk)
+
+    if impl == "pallas":
+        def matmul(lhs, rhs, which):
+            return grouped_matmul(lhs, rhs.astype(lhs.dtype), tile_group,
+                                  n_tiles, f"{name}_{which}",
+                                  pallas_mode.interpret())
+    else:
+        padded_sizes = tiles * TILE_M
+
+        def matmul(lhs, rhs, which):
+            del which
+            return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), padded_sizes)
+
+    if "gate" in expert_params:
+        gate = matmul(buf, expert_params["gate"], "gate")
+        hidden = jax.nn.silu(gate) * matmul(buf, expert_params["up"], "up")
+    else:
+        hidden = jnp.square(jax.nn.relu(
+            matmul(buf, expert_params["up"], "up")))
+    out_rows = matmul(hidden, expert_params["down"], "down")
+    if from_buffer:
+        # a placed row knows its token and its weight; rows past
+        # ``n_tiles`` are undefined: masked before anything is scaled
+        weighted = (jnp.where(placed[:, None], out_rows, 0)
+                    .astype(jnp.float32)
+                    * weights.reshape(-1)[src][:, None])
+        return _move_rows(weighted, token, placed, n, False).astype(x.dtype)
+    picked = _take_rows(out_rows, dest_nk, here_nk, src[:, None],
+                        placed[:, None])                       # (n, k, d)
+    return (picked * weights[..., None].astype(picked.dtype)).sum(1)
+
+
+def _ladder(rungs, index, operands):
+    """``rungs[index](*operands)``, differentiated by hand: ``operands``
+    is ``(x, weights, expert_params, *integer maps)`` and only the
+    first three carry gradients.  JAX's own rule for a conditional
+    makes every branch return every branch's residuals, zero-filled for
+    the branches not taken, which would write the top rung's buffers
+    (over 1 GB a layer at 50 176 rows) as zeros each step.  Here the
+    residuals are the operands themselves, which depend on no rung, and
+    the backward pass is a second ``switch`` whose branch runs
+    ``jax.vjp`` of that rung's body (its forward again, at the rung's
+    size).  Under ``nn.remat`` the recomputed forward is then dead code,
+    so the expert products run as often as before."""
+    @jax.custom_vjp
+    def run(index, *operands):
+        return lax.switch(index, rungs, *operands)
+
+    def forward(index, *operands):
+        return run(index, *operands), (index, operands)
+
+    def backward(res, g):
+        index, operands = res
+        carried, maps = operands[:3], operands[3:]
+
+        def pull(rung):
+            def branch(g, carried, maps):
+                def body(*carried):
+                    # JAX renames the first scope under a transformation
+                    # to ``jvp(...)``: this one, and not a kernel's
+                    # ``name=``, by which the trace's readers find it
+                    with jax.named_scope("rung"):
+                        return rung(*carried, *maps)
+                return jax.vjp(body, *carried)[1](g)
+            return branch
+
+        grads = lax.switch(index, [pull(rung) for rung in rungs], g,
+                           carried, maps)
+        return (None, *grads, *(None for _ in maps))
+
+    run.defvjp(forward, backward)
+    return run(index, *operands)
 
 
 def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
@@ -93,18 +288,13 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     ``sum over a token's chosen experts HELD HERE of w_e * expert_e(x)``
     and zero for a token none of whose experts is held; ``stats`` counts
     the rows this chip multiplied (``held_rows``), the assignments that
-    went elsewhere (``rows_elsewhere``) and the fullest held expert's
-    rows (``max_expert_rows``), float32 scalars, and gives every
-    expert's assignments, held or not (``expert_load (E,)``: what a
-    balancing controller steers by).
+    went elsewhere (``rows_elsewhere``), the fullest held expert's
+    rows (``max_expert_rows``) and the rows of the buffer they were
+    laid out in (``buffer_rows``: the rung taken), float32 scalars, and
+    gives every expert's assignments, held or not (``expert_load
+    (E,)``: what a balancing controller steers by).
 
-    Layout: the ``n * top_k`` assignments are counted per held expert
-    (a cumulative sum, no sort), each expert's rows are padded up to
-    whole tiles of ``TILE_M`` and placed expert by expert in a buffer
-    of static size ``n * top_k + count * TILE_M`` rows; an empty expert
-    keeps one tile of zero rows.  The buffer's rows are gathered from
-    ``x``, pass through the expert's grouped products (three, or two),
-    and are gathered back.
+    Layout: see the module docstring.
 
     ``impl``: ``'pallas'`` (the kernels; default on a TPU, interpreted
     on the CPU platform when forced) or ``'ragged_dot'``
@@ -143,49 +333,26 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     tile_ends = jnp.cumsum(tiles)
     starts = (tile_ends - tiles) * TILE_M          # each expert's first row
     n_assign = n * top_k
-    rows = -(-n_assign // TILE_M) * TILE_M + count * TILE_M
+    rungs = buffer_ladder(n_assign, count, n_experts)
+    _log_buffer_plan(name, rungs)
     dest = jnp.where(here, starts[jnp.clip(local, 0, count - 1)] + rank, 0)
-    # the buffer row -> assignment map: a 1-D integer scatter
-    src = jnp.full((rows,), n_assign, jnp.int32).at[
-        jnp.where(here, dest, rows)].set(
-        jnp.arange(n_assign, dtype=jnp.int32), mode="drop")
-    placed = src < n_assign
-    src = jnp.where(placed, src, 0)
-    tile_group = jnp.minimum(
-        jnp.searchsorted(tile_ends, jnp.arange(rows // TILE_M),
-                         side="right"), count - 1).astype(jnp.int32)
-    n_tiles = tile_ends[-1]
-
-    dest_nk = dest.reshape(n, top_k)
-    here_nk = here.reshape(n, top_k)
-    buf = _take_rows(x, src // top_k, placed, dest_nk, here_nk)
-
-    if impl == "pallas":
-        def matmul(lhs, rhs, which):
-            return grouped_matmul(lhs, rhs.astype(lhs.dtype), tile_group,
-                                  n_tiles, f"{name}_{which}",
-                                  pallas_mode.interpret())
+    operands = (x, weights, expert_params, dest, here, tiles, tile_ends)
+    bodies = [functools.partial(_rung, rows, top_k, impl, name)
+              for rows in rungs]
+    if len(rungs) == 1:
+        out = bodies[0](*operands)
+        buffer_rows = jnp.float32(rungs[0])
     else:
-        padded_sizes = tiles * TILE_M
-
-        def matmul(lhs, rhs, which):
-            del which
-            return lax.ragged_dot(lhs, rhs.astype(lhs.dtype), padded_sizes)
-
-    if "gate" in expert_params:
-        gate = matmul(buf, expert_params["gate"], "gate")
-        hidden = jax.nn.silu(gate) * matmul(buf, expert_params["up"], "up")
-    else:
-        hidden = jnp.square(jax.nn.relu(
-            matmul(buf, expert_params["up"], "up")))
-    out_rows = matmul(hidden, expert_params["down"], "down")
-    picked = _take_rows(out_rows, dest_nk, here_nk, src[:, None],
-                        placed[:, None])                       # (n, k, d)
-    out = (picked * weights[..., None].astype(picked.dtype)).sum(1)
+        # the smallest rung that holds the tiles in use
+        index = (tile_ends[-1] * TILE_M
+                 > jnp.asarray(rungs[:-1], jnp.int32)).sum()
+        out = _ladder(bodies, index, operands)
+        buffer_rows = jnp.asarray(rungs, jnp.float32)[index]
     held_rows = sizes.sum()
     stats = {"held_rows": held_rows.astype(jnp.float32),
              "rows_elsewhere": (n_assign - held_rows).astype(jnp.float32),
              "max_expert_rows": sizes.max().astype(jnp.float32),
+             "buffer_rows": buffer_rows,
              "expert_load": (chosen.reshape(-1, 1) == jnp.arange(n_experts)
                              ).sum(0, dtype=jnp.float32)}
     return out.astype(x.dtype), stats
