@@ -26,7 +26,11 @@ CONFIG = "nemotron_twotower_30b"
 LISTS = ("tokens_per_s_per_chip", "median_segment_rate.tok",
          "input_wait_share.tok", "device_ms_per_step.tok", "mfu.tok",
          "device_idle_share.tok", "peak_hbm_gb.tok",
-         "recompiles_in_window.tok")
+         "recompiles_in_window.tok",
+         # PR 36: the step program by scope and phase
+         "scope_coverage.tok", "backward_share.tok", "update_share.tok",
+         "recompute_share.tok", "loss_share.tok", "ssd_share",
+         "expert_layer_share")
 NEW = ("nemotron_expert_matmul_share",
        "nemotron_expert_matmul_roofline_share", "nemotron_attention_share")
 

@@ -25,7 +25,10 @@ LISTS = ("tokens_per_s_per_chip", "median_segment_rate.tok",
          "input_wait_share.tok", "device_ms_per_step.tok", "mfu.tok",
          "device_idle_share.tok", "peak_hbm_gb.tok",
          "recompiles_in_window.tok", "attention_share",
-         "attention_roofline_share")
+         "attention_roofline_share",
+         # PR 36: the step program by scope and phase
+         "scope_coverage.tok", "backward_share.tok", "update_share.tok",
+         "recompute_share.tok", "loss_share.tok")
 
 
 def _reader(name):

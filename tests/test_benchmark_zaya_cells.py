@@ -62,7 +62,9 @@ def test_the_new_cells_are_declared_where_the_issue_says():
     listed = {m["name"]: m.get("workloads", []) for m in
               bench["end_to_end"] + bench["per_layer"]}
     for metric, cells_of in listed.items():
-        if metric == "tokens_per_s_per_chip" or metric.endswith(".tok"):
+        if metric == "recompute_share.tok":  # PR 36: the remat cells' own
+            assert not set(NEW_CELLS) & set(cells_of)
+        elif metric == "tokens_per_s_per_chip" or metric.endswith(".tok"):
             assert set(NEW_CELLS) <= set(cells_of), metric
     # the accepted pattern of attention_share would count the expert
     # kernels as attention: the zaya cell has a reader of its own

@@ -40,6 +40,7 @@ import optax
 
 from theanompi_tpu.data.base import Dataset
 from theanompi_tpu.data.prefetch import DevicePrefetcher
+from theanompi_tpu.monitor import scopes
 from theanompi_tpu.models.layers import (
     error_rate,
     softmax_cross_entropy,
@@ -510,6 +511,8 @@ class TpuModel:
         self.train_step = None
         self.train_step_multi = None
         self.train_step_accum = None
+        #: the train step last noted with monitor/scopes.py
+        self._noted_step = None
         self.eval_step = None
         self._train_prefetcher: DevicePrefetcher | None = None
         self._train_iter: Iterator | None = None
@@ -891,23 +894,27 @@ class TpuModel:
         batch = next(self._train_iter)
         recorder.end("wait")  # time blocked on the loader = reference 'wait'
         recorder.start()
+        if k > 1:
+            step = self.train_step_multi
+        elif a > 1:
+            step = self.train_step_accum
+            if step is None:
+                raise ValueError(
+                    f"{type(self).__name__}'s compile_iter_fns does "
+                    "not build an accumulation step; grad_accum_steps"
+                    ">1 is unsupported for this model")
+        else:
+            step = self.train_step
+        rng = self._next_rng()
+        if self._noted_step is not step:
+            # the first dispatch of this function: say what ran, so that
+            # monitor/scopes.py can map its device ops when asked
+            self._noted_step = scopes.note_step(
+                step, (self.state, batch, rng))
         # the annotation labels this iteration in jax.profiler traces
         # (utils/profiling.py); free when no trace is active
         with jax.profiler.StepTraceAnnotation("train", step_num=count):
-            if k > 1:
-                self.state, metrics = self.train_step_multi(
-                    self.state, batch, self._next_rng())
-            elif a > 1:
-                if self.train_step_accum is None:
-                    raise ValueError(
-                        f"{type(self).__name__}'s compile_iter_fns does "
-                        "not build an accumulation step; grad_accum_steps"
-                        ">1 is unsupported for this model")
-                self.state, metrics = self.train_step_accum(
-                    self.state, batch, self._next_rng())
-            else:
-                self.state, metrics = self.train_step(self.state, batch,
-                                                      self._next_rng())
+            self.state, metrics = step(self.state, batch, rng)
         recorder.end("calc")  # async dispatch; device time lands on flush
         self._pending.append((count, metrics))
         # flush window: print_freq when printing, else a fixed window so

@@ -120,7 +120,7 @@ class Block(nn.Module):
             # full local attention: the fused Pallas kernel on TPU
             # (ops/attention.py; XLA oracle elsewhere/oversize)
             o = fused_attention(q, k, v, causal=True,
-                                impl=self.attn_impl)
+                                impl=self.attn_impl, name="lm_attention")
         o = o.reshape((b, t, self.d_model))
         x = x + nn.Dense(self.d_model, use_bias=False,
                          kernel_init=L.xavier_init(), dtype=self.dtype,
@@ -587,7 +587,8 @@ class AttnBlock(nn.Module):
         shape = (b, t, self.n_heads, d_head)
         o = fused_attention(proj("q_proj").reshape(shape),
                             proj("k_proj").reshape(shape),
-                            proj("v_proj").reshape(shape), causal=True)
+                            proj("v_proj").reshape(shape), causal=True,
+                            name="lm_attention")
         o = o.reshape((b, t, self.d_model))
         return x + nn.Dense(self.d_model, use_bias=False,
                             kernel_init=L.xavier_init(), dtype=self.dtype,

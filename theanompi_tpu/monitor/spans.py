@@ -50,15 +50,15 @@ class Span:
     """One timed interval.  Use via ``monitor.span(...)`` (the facade
     returns a no-op when monitoring is disabled) or directly in tests.
 
-    ``registry=None`` times and nests but records nowhere — the bench
-    uses that mode when it only wants TraceAnnotation alignment."""
+    ``registry=None`` times, nests and annotates the profiler's trace
+    but records nowhere."""
 
     __slots__ = ("name", "full_name", "labels", "fence_on", "registry",
-                 "t0", "t_wall", "thread", "_annotation", "_annotate",
+                 "t0", "t_wall", "thread", "_annotation",
                  "trace_id", "span_id", "parent_id", "sampled")
 
     def __init__(self, name: str, registry=None, fence: Any = None,
-                 annotate: bool = True, **labels):
+                 **labels):
         self.name = name
         self.full_name = name  # finalized on __enter__ from the stack
         self.labels = labels
@@ -67,7 +67,6 @@ class Span:
         self.t0 = 0.0
         self.t_wall = 0.0
         self.thread = threading.current_thread().name
-        self._annotate = annotate
         self._annotation = None
         # trace linkage — ids stay None unless tracing is enabled at
         # __enter__, so the disabled path allocates nothing
@@ -91,19 +90,17 @@ class Span:
         st.append(self)
         with _open_lock:
             _open[id(self)] = self
-        if self._annotate:
-            try:
-                import jax
+        try:
+            import jax
 
-                self._annotation = jax.profiler.TraceAnnotation(
-                    self.full_name)
-                self._annotation.__enter__()
-            except Exception:
-                # annotation is best-effort alignment; a failure here
-                # must not abort __enter__ AFTER the span registered
-                # itself in _open/_stack (the with-statement would
-                # never run __exit__, leaking a ghost open span)
-                self._annotation = None
+            self._annotation = jax.profiler.TraceAnnotation(self.full_name)
+            self._annotation.__enter__()
+        except Exception:
+            # annotation is best-effort alignment; a failure here must
+            # not abort __enter__ AFTER the span registered itself in
+            # _open/_stack (the with-statement would never run
+            # __exit__, leaking a ghost open span)
+            self._annotation = None
         # re-stamp after annotation setup so its cost (first jax
         # import can be slow) isn't charged to the timed block; the
         # wall stamp pairs with the SAME instant so merged timelines

@@ -23,7 +23,7 @@ import optax
 from flax import struct
 from jax.sharding import PartitionSpec as P
 
-from theanompi_tpu.parallel.exchanger import BSP_Exchanger
+from theanompi_tpu.parallel.exchanger import SCOPE_EXCHANGE, BSP_Exchanger
 from theanompi_tpu.parallel.mesh import AXIS_DATA
 
 PyTree = Any
@@ -64,8 +64,17 @@ class TrainState:
         )
 
 
+#: the step's own tail in a device trace (docs/OBSERVABILITY.md
+#: "Device-trace names"): the optimizer update here, the gradient
+#: exchange with the cross-replica means under ``SCOPE_EXCHANGE``.
+#: Forward and backward need no scope: JAX's transforms say which is
+#: which (monitor/scopes.py ``parse``)
+SCOPE_UPDATE = "bsp/update"
+
+
 def _pmean(tree: PyTree, axes=(AXIS_DATA,)) -> PyTree:
-    return jax.tree.map(lambda x: jax.lax.pmean(x, axes), tree)
+    with jax.named_scope(SCOPE_EXCHANGE):
+        return jax.tree.map(lambda x: jax.lax.pmean(x, axes), tree)
 
 
 def grad_and_metrics(loss_fn: LossFn, params, model_state, batch, rng):
@@ -83,9 +92,11 @@ def grad_and_metrics(loss_fn: LossFn, params, model_state, batch, rng):
 
 def apply_update(tx: optax.GradientTransformation, state: "TrainState",
                  grads, new_ms) -> "TrainState":
-    """Shared step-tail: optimizer update + TrainState rebuild."""
-    updates, new_opt = tx.update(grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
+    """Shared step-tail: optimizer update + TrainState rebuild.  Its
+    device ops carry the scope ``bsp/update`` (monitor/scopes.py)."""
+    with jax.named_scope(SCOPE_UPDATE):
+        updates, new_opt = tx.update(grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
     return TrainState(step=state.step + 1, params=new_params,
                       opt_state=new_opt, model_state=new_ms,
                       exchange_residual=state.exchange_residual)
@@ -158,12 +169,14 @@ def _exchange_grads_and_update(exchanger: BSP_Exchanger,
                 "ModelConfig.exchange_error_feedback)")
         # residual leaves arrive per-shard as (1, *shape) — the leading
         # axis is the data-shard axis the spec splits
-        res = jax.tree.map(lambda r: r[0], state.exchange_residual)
-        grads, new_res = exchanger.exchange_with_residual(grads, res)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            res = jax.tree.map(lambda r: r[0], state.exchange_residual)
+            grads, new_res = exchanger.exchange_with_residual(grads, res)
         new_state = apply_update(tx, state, grads, new_ms)
         return new_state.replace(
             exchange_residual=jax.tree.map(lambda r: r[None], new_res))
-    grads = exchanger.exchange(grads)
+    with jax.named_scope(SCOPE_EXCHANGE):
+        grads = exchanger.exchange(grads)
     return apply_update(tx, state, grads, new_ms)
 
 
@@ -225,8 +238,10 @@ def _make_shard_step(
                 exchanger if exchanger.avg
                 else dataclasses.replace(exchanger, avg=True)
             )
+            with jax.named_scope(SCOPE_EXCHANGE):
+                new_params = avg_exch.exchange(new_state.params)
             new_state = new_state.replace(
-                params=avg_exch.exchange(new_state.params),
+                params=new_params,
                 # Momentum buffers live per-shard in 'params' mode;
                 # average them too so state stays replicated (matches
                 # the reference's param-averaging BSP semantics closely

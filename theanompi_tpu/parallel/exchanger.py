@@ -43,6 +43,10 @@ from theanompi_tpu.parallel.partition import balanced_ranges
 
 PyTree = Any
 
+#: the scope of the gradient exchange and the cross-replica means in a
+#: device trace (docs/OBSERVABILITY.md "Device-trace names")
+SCOPE_EXCHANGE = "bsp/exchange"
+
 
 def bucket_ranges(sizes, n_buckets: int) -> list[tuple[int, int]]:
     """Layer-ordered, byte-balanced bucket plan over flatten-order
@@ -404,7 +408,8 @@ class BSP_Exchanger:
             return leaves, None
 
         def bwd(_, cts):
-            return (self._reduce_bucket(cts),)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                return (self._reduce_bucket(cts),)
 
         tag.defvjp(fwd, bwd)
         return tag
@@ -424,7 +429,8 @@ class BSP_Exchanger:
             return leaves, res
 
         def bwd(res, cts):
-            out, new_r = self._reduce_bucket_ef(cts, res)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                out, new_r = self._reduce_bucket_ef(cts, res)
             return out, new_r
 
         tag.defvjp(fwd, bwd)

@@ -69,6 +69,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from theanompi_tpu.parallel.bsp import (
+    SCOPE_UPDATE,
     TrainState,
     _donate_argnums,
     _fold_axis_rng,
@@ -78,6 +79,7 @@ from theanompi_tpu.parallel.bsp import (
 )
 from theanompi_tpu.parallel.bsp import state_partition_spec  # noqa: F401
 from theanompi_tpu.parallel.exchanger import (
+    SCOPE_EXCHANGE,
     bucket_ranges,
     emit_bucket_gauges,
     validate_bucket_count,
@@ -340,6 +342,7 @@ def make_bsp_zero_step(
                                 exchange_residual=P(AXIS_DATA))
     wire = "bf16" if exchange_dtype == "bf16" else "f32"
 
+    @jax.named_scope(SCOPE_EXCHANGE)
     def scatter_segment(seg, res_seg):
         """One bucket's collective, from its local padded f32 segment:
         reduce_scatter (f32) or quantize + all_to_all + f32 local
@@ -396,7 +399,8 @@ def make_bsp_zero_step(
         average, update the shard, gather the params back per
         bucket."""
         if extra_axes:
-            gshard = lax.psum(gshard, extra_axes)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                gshard = lax.psum(gshard, extra_axes)
         if avg:
             gshard = gshard / n_total
 
@@ -404,9 +408,12 @@ def make_bsp_zero_step(
         pflat = _ravel_bucketed(state.params, layout)
         pshard = _shard_slice(pflat, layout, idx)
 
-        updates, new_opt = tx.update(gshard, state.opt_state, pshard)
-        new_pshard = optax.apply_updates(pshard, updates)
-        gathered = lax.all_gather(new_pshard, AXIS_DATA)  # (n, per_shard)
+        with jax.named_scope(SCOPE_UPDATE):  # as bsp.apply_update's
+            updates, new_opt = tx.update(gshard, state.opt_state, pshard)
+            new_pshard = optax.apply_updates(pshard, updates)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            # (n, per_shard)
+            gathered = lax.all_gather(new_pshard, AXIS_DATA)
         segs = [gathered[:, so:so + pb].reshape(-1)
                 for so, pb in zip(layout.shard_off, layout.pb)]
         new_flat = segs[0] if n_buckets == 1 else jnp.concatenate(segs)

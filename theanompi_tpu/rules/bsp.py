@@ -114,8 +114,9 @@ def run_bsp_session(model: TpuModel, sync_type: str = "avg",
                             monitor.observe_step(
                                 (time.monotonic() - t0) / consumed,
                                 phase="train", step=it)
-                            profiler.step()  # trace spans epochs until
-                            # n_steps hit
+                            # trace spans epochs until n_steps hit,
+                            # then waits for the device to have run them
+                            profiler.step(fence=model.state.step)
                         model._flush_metrics(recorder)
                         monitor.progress(phase="validate")
                         with monitor.span("bsp/validate"):
