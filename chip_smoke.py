@@ -335,6 +335,27 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
         f"grouped_matmul({n}, {d})x{held}of{routed}", experts_layer,
         _normal(((n, d), bf16), ((n, routed), f32),
                 *[((held, d, d), bf16)] * 3), rtol=2e-2, atol=2e-2))
+    # NemotronHLM's Mamba-2 scan: 64 heads of 64 in 8 groups, state and
+    # chunk 128; time steps made positive, decays negative, and B and C
+    # scaled so that C . B is of order 1, as a layer's are
+    b, t, h, g = (4, 2048, 64, 8) if full else (1, 256, 2, 1)
+
+    def scan(impl):
+        from theanompi_tpu.ops import ssd
+
+        def layer(x, dt, a, b_in, c_in, d):
+            args = (x, jax.nn.softplus(dt - 2.0), -jnp.exp(a),
+                    b_in * 128 ** -0.5, c_in * 128 ** -0.5, d)
+            if impl == "pallas":
+                return ssd.ssd_chunked(*args, chunk=128, name="smoke_ssd")
+            return ssd._ssd_jnp(*args, 128)
+        return layer
+
+    cases.append(KernelCase(
+        f"ssd{(b, t, h, 64)}x{g}", scan,
+        _normal(((b, t, h, 64), bf16), ((b, t, h), f32), ((h,), f32),
+                *[((b, t, g, 128), bf16)] * 2, ((h,), f32)),
+        rtol=2e-2, atol=2e-2))
     # ResNet-50 stage-1 epilogues: conv3 (C=256, +residual) and
     # conv1/conv2 (C=64), each with and without the residual stream
     for c in (256, 64) if full else (32,):

@@ -323,3 +323,54 @@ def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
         jax.config.update("jax_enable_compilation_cache", cached)
         compilation_cache.reset_cache()
     assert "test_attention_fwd" in text and "test_attention_bwd" in text
+
+
+def test_the_scan_kernels_compile_for_a_v5e_under_their_scope(monkeypatch,
+                                                              one_chip):
+    """The Mamba-2 scan's kernel pair at the Nemotron cell's scan shape
+    (4 x 2048 tokens, 64 heads of 64 in 8 groups, state 128, chunks of
+    128) inside a recomputed mixer, compiled for the chip: Mosaic takes
+    both, and the step's map places the forward (run and recomputed) and
+    the backward under the scope ``ssd_share`` reads."""
+    import re
+
+    import flax.linen as nn
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from theanompi_tpu.models import nemotron_h
+    from theanompi_tpu.monitor import scopes
+    from theanompi_tpu.ops import pallas_mode
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            return nn.remat(nemotron_h.Mamba2Mixer)(
+                d_model=256, n_heads=64, head_dim=64, n_groups=8, state=128,
+                chunk=128, dtype=jnp.bfloat16, name="mamba")(u)
+
+    net = Net()
+    u = jax.ShapeDtypeStruct((4, 2048, 256), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.key(0), u))
+    loss = lambda p, u: net.apply(p, u).astype(  # noqa: E731
+        jnp.float32).sum()
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.value_and_grad(loss)).lower(
+            params, u).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    scope = r"(^|/)nemotron_h/mamba/ssd(/|$)"
+    kernels = {(name.rsplit(".", 1)[0], phase)
+               for name, (phase, where) in scopes.scope_map(text).items()
+               if name.startswith("nemotron_h_ssd_")
+               and re.search(scope, where)}
+    assert kernels == {("nemotron_h_ssd_fwd", "forward"),
+                       ("nemotron_h_ssd_fwd", "recompute"),
+                       ("nemotron_h_ssd_bwd", "backward")}

@@ -8,6 +8,7 @@ and the reference check against planted faults."""
 
 import importlib.util
 import json
+import math
 import os
 import re
 import types
@@ -32,13 +33,24 @@ LISTS = ("tokens_per_s_per_chip", "median_segment_rate.tok",
          "recompute_share.tok", "loss_share.tok", "ssd_share",
          "expert_layer_share")
 NEW = ("nemotron_expert_matmul_share",
-       "nemotron_expert_matmul_roofline_share", "nemotron_attention_share")
+       "nemotron_expert_matmul_roofline_share", "nemotron_attention_share",
+       # PR 37: the scan's kernels
+       "ssd_roofline_share")
 
 
 def _reader(name):
     spec = importlib.util.spec_from_file_location(
         "bench_reader_" + name,
         os.path.join(ROOT, "benchmarks", "layer_metrics", name + ".py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def _load_flops(name):
+    spec = importlib.util.spec_from_file_location(
+        "bench_flops_" + name[:-3],
+        os.path.join(ROOT, "benchmarks", "flops", name))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -266,6 +278,85 @@ def test_the_roofline_reader_on_a_handmade_list_of_events(monkeypatch):
     assert reader.read(run) is None
 
 
+#: one call of each of the scan's kernels at the cell's shape (4 x 2048
+#: tokens, 64 heads of 64 in 8 groups, state 128, chunks of 128), worked
+#: from the kernels' bodies: 4 x 8 x 16 = 512 grid steps; a head of 64
+#: runs its products over its 128-lane tile
+SSD_STEPS = 4 * 8 * 16
+SSD_FWD_FLOPS = 2.0 * SSD_STEPS * (
+    128 * 128 * 128             # C B^T
+    + 128 * 128 * 512           # C S_in, the group's 8 heads at once
+    + 8 * 128 * 128 * 128       # a head's masked scores applied to x
+    + 128 * 128 * 512)          # B^T applied to x: the state
+SSD_BWD_FLOPS = 2.0 * SSD_STEPS * (
+    3 * 128 * 128 * 128         # C B^T; d(C B^T) B; d(C B^T)^T C
+    + 5 * 128 * 128 * 512       # C S_in; B dS; dy S^T; x dS^T; C^T dy
+    + 2 * 8 * 128 * 128 * 128)  # a head's dy x^T and M^T dy
+_X = 4 * 2048 * 64 * 64 * 2               # x, y, dy or dx in bf16
+_BC = 4 * 2048 * 8 * 128 * 2              # B, C or a gradient
+_ROWS = 4 * 2048 * 64 * 4                 # dt or dt A rows, float32
+_STATES = 4 * 16 * 128 * 64 * 64 * 4      # the chunks' entering states
+SSD_FWD_BYTES = 2 * _X + 2 * _BC + 2 * _ROWS + 64 * 64 * 4 + _STATES
+SSD_BWD_BYTES = (3 * _X + 4 * _BC + 4 * _ROWS + 64 * 64 * 4
+                 + 4 * 64 * 64 * 4 + _STATES)
+
+
+def _ssd_trace(fwd_ns, bwd_ns):
+    """A window of 10 ms: two forward calls and a backward one inside,
+    a forward call before it, and another model's kernel."""
+    ops = [("nemotron_h_ssd_fwd.2", "custom-call tpu_custom_call",
+            -2_000_000, -1_000_000),
+           ("nemotron_h_ssd_fwd.1", "custom-call tpu_custom_call",
+            0, fwd_ns),
+           ("nemotron_h_attention_fwd", "custom-call tpu_custom_call",
+            fwd_ns, fwd_ns + 500),
+           ("nemotron_h_ssd_fwd.3", "custom-call tpu_custom_call",
+            fwd_ns + 500, 2 * fwd_ns + 500),
+           ("nemotron_h_ssd_bwd.1", "custom-call tpu_custom_call",
+            2 * fwd_ns + 500, 2 * fwd_ns + 500 + bwd_ns)]
+    return trace_lib.from_events({0: ops},
+                                 [("bench/segment", 0, 10_000_000)])
+
+
+def test_the_scan_roofline_reader_on_a_handmade_list_of_events():
+    """Calls counted by name inside the window only; each call's work is
+    the hand count above; None where no such call was traced; the
+    calls' least time itself reads (just) under 100%."""
+    from benchmarks import peaks
+
+    reader = _reader("ssd_roofline_share")
+    flops_lib = _load_flops("nemotron_h_ssd.py")
+    shape = reader.call_shape()
+    assert shape == dict(batch=4, seq_len=2048, heads=64, head_dim=64,
+                         groups=8, state=128, chunk=128)
+    assert flops_lib.ssd_kernel_flops(which="fwd", **shape) == SSD_FWD_FLOPS
+    assert flops_lib.ssd_kernel_flops(which="bwd", **shape) == SSD_BWD_FLOPS
+    assert flops_lib.ssd_kernel_bytes(which="fwd", **shape) == SSD_FWD_BYTES
+    assert flops_lib.ssd_kernel_bytes(which="bwd", **shape) == SSD_BWD_BYTES
+    # 36.5 and 83.8 GFLOP, 306 and 411 MB: both bound by the bytes
+    assert SSD_FWD_BYTES / 819e9 > SSD_FWD_FLOPS / 197e12
+    assert SSD_BWD_BYTES / 819e9 > SSD_BWD_FLOPS / 197e12
+    run = types.SimpleNamespace(
+        trace=_ssd_trace(1_000_000, 2_500_000), trace_lib=trace_lib,
+        on_device=True, peak=peaks.peak("TPU v5 lite"))
+    assert reader.calls_in(run.trace) == {"fwd": [2, 2e6], "bwd": [1, 2.5e6]}
+    least = (2 * SSD_FWD_BYTES + SSD_BWD_BYTES) / 819e9
+    assert reader.read(run) == pytest.approx(100 * least / 4.5e-3)
+    # the least time itself, to the nanosecond above
+    fwd_ns = math.ceil(SSD_FWD_BYTES / 819e9 * 1e9)
+    bwd_ns = math.ceil(SSD_BWD_BYTES / 819e9 * 1e9)
+    run.trace = _ssd_trace(fwd_ns, bwd_ns)
+    assert 99.999 < reader.read(run) <= 100
+    # nothing to read
+    run.trace = _handmade_trace()
+    assert reader.read(run) is None
+    _, run.trace = _fixture_trace()      # the parent's step: no such call
+    assert reader.read(run) is None
+    run.on_device = False
+    assert reader.read(run) is None
+    assert reader.read(types.SimpleNamespace(trace=None)) is None
+
+
 def test_the_readers_on_a_step_recorded_on_the_chip(monkeypatch):
     """One step of the cell recorded on the chip: each pattern finds
     its kernels there, the two classes are disjoint and together are the
@@ -362,8 +453,8 @@ def test_the_reference_check_tells_a_planted_fault(dry_run_model, fault,
         return (normed.reshape(y.shape) * scale
                 * jax.nn.silu(z.astype(jnp.float32))).astype(y.dtype)
 
-    def no_decay(x, dt, a, b, c, d, *, chunk):
-        return ssd(x, dt, jnp.zeros_like(a), b, c, d, chunk=chunk)
+    def no_decay(x, dt, a, b, c, d, **kwargs):
+        return ssd(x, dt, jnp.zeros_like(a), b, c, d, **kwargs)
 
     if fault == "e4m3":
         monkeypatch.setattr(model, "loss_fn", rounded)

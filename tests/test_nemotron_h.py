@@ -16,6 +16,7 @@ import pytest
 from theanompi_tpu import monitor
 from theanompi_tpu.models import nemotron_h as N
 from theanompi_tpu.models.base import ModelConfig, TpuModel
+from theanompi_tpu.ops import ssd
 from theanompi_tpu.ops.ssd import ssd_chunked
 from theanompi_tpu.parallel.expert import routed_experts
 from theanompi_tpu.parallel.mesh import data_mesh
@@ -55,17 +56,23 @@ def _model(devices=1, batch_size=2, held=(0, 4), dtype="float32",
                          **dict(TINY, **overrides))
 
 
-def _scan_inputs(t, heads=4, groups=2, seed=0):
+def _scan_inputs(t, heads=4, groups=2, seed=0, batch=2, head_dim=3,
+                 state=5):
     """A group shared by ``heads / groups`` heads; time steps and decay
     rates in the ranges a layer starts from."""
     k = jax.random.split(jax.random.key(seed), 6)
-    b, p, n = 2, 3, 5
+    b, p, n = batch, head_dim, state
     return (jax.random.normal(k[0], (b, t, heads, p)),
             jax.nn.softplus(jax.random.normal(k[1], (b, t, heads)) - 1.0),
             -jnp.exp(jax.random.normal(k[2], (heads,))),
             jax.random.normal(k[3], (b, t, groups, n)),
             jax.random.normal(k[4], (b, t, groups, n)),
             jax.random.normal(k[5], (heads,)))
+
+
+#: the smallest shape the kernels take: one sequence, two groups of two
+#: heads of 64 (a 128-lane tile a group), state 128, chunks of 128
+ALIGNED = dict(batch=1, head_dim=64, state=128)
 
 
 def _stepped(x, dt, a, b, c, d):
@@ -75,22 +82,42 @@ def _stepped(x, dt, a, b, c, d):
                                  jnp.repeat(c, rep, axis=2), d)
 
 
-@pytest.mark.parametrize("t, chunk", [(24, 24), (24, 8), (24, 4), (128, 64)])
-def test_the_chunked_scan_is_the_recurrence(t, chunk):
+def _scan(path, chunk):
+    """The scan by ``path``: ``ssd_chunked``'s own choice, which the
+    shape makes (``"pallas"`` or ``"jax.numpy"``, checked), or the
+    ``jax.numpy`` body called by name at any shape (``"body"``)."""
+    def run(x, dt, a, b, c, d):
+        if path == "body":
+            return ssd._ssd_jnp(x, dt, a, b, c, d, chunk)
+        plan = ssd.ssd_plan(x.shape[0], x.shape[1], x.shape[2], x.shape[3],
+                            b.shape[2], b.shape[3], chunk)
+        assert plan.pallas == (path == "pallas"), str(plan)
+        return ssd_chunked(x, dt, a, b, c, d, chunk=chunk)
+    return run
+
+
+@pytest.mark.parametrize("path, t, chunk, shape", [
+    ("jax.numpy", 24, 24, {}), ("jax.numpy", 24, 8, {}),
+    ("jax.numpy", 24, 4, {}), ("jax.numpy", 128, 64, {}),
+    # the kernels (interpret mode), over one chunk and two
+    ("pallas", 128, 128, ALIGNED), ("pallas", 256, 128, ALIGNED),
+    # the jax.numpy body at the kernels' shape
+    ("body", 256, 128, ALIGNED)])
+def test_the_chunked_scan_is_the_recurrence(path, t, chunk, shape):
     """Outputs and the gradient with respect to EVERY input (x, dt, A,
     B, C, D), over one chunk and over several, so that the state carried
     between chunks is exercised; two heads share each group.  1e-5 of
     the largest entry: both sides are float32 and differ by the order of
     their sums alone."""
-    inputs = _scan_inputs(t)
-    got = ssd_chunked(*inputs, chunk=chunk)
+    inputs = _scan_inputs(t, **shape)
+    scan = _scan(path, chunk)
+    got = scan(*inputs)
     want = _stepped(*inputs)
     np.testing.assert_allclose(got, want, rtol=1e-5,
                                atol=1e-5 * float(jnp.abs(want).max()))
     weigh = jax.random.normal(jax.random.key(9), want.shape)
     grads = [jax.grad(lambda *v: (fn(*v) * weigh).sum(), argnums=range(6))(
-        *inputs) for fn in (lambda *v: ssd_chunked(*v, chunk=chunk),
-                            _stepped)]
+        *inputs) for fn in (scan, _stepped)]
     for g, w in zip(*grads):
         np.testing.assert_allclose(g, w, rtol=1e-4,
                                    atol=2e-5 * float(jnp.abs(w).max()))
@@ -103,17 +130,91 @@ def test_the_scan_refuses_a_ragged_sequence():
         ssd_chunked(*_scan_inputs(24, heads=3), chunk=8)
 
 
-def test_the_scan_in_bfloat16_keeps_its_decays_in_float32():
+@pytest.mark.parametrize("path, t, chunk, shape", [
+    ("jax.numpy", 64, 16, {}), ("pallas", 256, 128, ALIGNED)])
+def test_the_scan_in_bfloat16_keeps_its_decays_in_float32(path, t, chunk,
+                                                          shape):
     """bfloat16 products, float32 decays and carried states: the output
     comes back in bfloat16 within its own rounding of the float32 one."""
-    inputs = _scan_inputs(64)
-    want = ssd_chunked(*inputs, chunk=16)
+    inputs = _scan_inputs(t, **shape)
+    scan = _scan(path, chunk)
+    want = scan(*inputs)
     x, dt, a, b, c, d = inputs
-    got = ssd_chunked(x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
-                      c.astype(jnp.bfloat16), d, chunk=16)
+    got = scan(x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+               c.astype(jnp.bfloat16), d)
     assert got.dtype == jnp.bfloat16
     err = jnp.abs(got.astype(jnp.float32) - want).max() / jnp.abs(want).max()
     assert err < 0.03
+
+
+@pytest.mark.parametrize("shape, line", [
+    # the cell's: 4 x 2048 tokens, 64 heads of 64 in 8 groups, state 128
+    (dict(batch=4, t=2048, d_model=2688, n_heads=64, head_dim=64,
+          n_groups=8, state=128, chunk=128, dtype=jnp.bfloat16),
+     "ssd: 16 chunks of 128, 64 heads, state 64 x 128, pallas (grid 4 x 8 "
+     "x 16, state in VMEM)"),
+    # the dry run's: chunk 8, state 8, head 8
+    (dict(batch=2, t=16, d_model=32, n_heads=4, head_dim=8, n_groups=2,
+          state=8, chunk=8, dtype=jnp.float32),
+     "ssd: 2 chunks of 8, 4 heads, state 8 x 8, jax.numpy")])
+def test_the_plan_line_says_which_path_the_shape_takes(shape, line, caplog):
+    """One ``ssd_chunked``: the kernels at the cell's shape, the
+    ``jax.numpy`` body at the dry run's, read from the layer's own plan
+    line (traced, not run)."""
+    batch, t = shape.pop("batch"), shape.pop("t")
+    mixer = N.Mamba2Mixer(**shape)
+    u = jnp.zeros((batch, t, shape["d_model"]), shape["dtype"])
+    N._log_ssd_plan.cache_clear()
+    with caplog.at_level("INFO", logger=N.__name__):
+        jax.eval_shape(lambda u: mixer.init_with_output(
+            jax.random.key(0), u), u)
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("ssd:")]
+    assert said == [line]
+
+
+def test_each_kernel_is_traced_and_lowered_once_a_shape(monkeypatch):
+    """Two recomputed Mamba layers or four: one trace of each kernel
+    body (the forward's twice: ``init``'s primal writes no states, the
+    gradient's forward does), and one lowered function a pass that
+    every layer calls."""
+    import flax.linen as nn
+
+    counts = {"_fwd_kernel": 0, "_bwd_kernel": 0}
+    for name in counts:
+        real = getattr(ssd, name)
+
+        def counted(*a, _real=real, _name=name, **kw):
+            counts[_name] += 1
+            return _real(*a, **kw)
+        monkeypatch.setattr(ssd, name, counted)
+    ssd._forward.clear_cache()
+    ssd._backward.clear_cache()
+
+    class Stack(nn.Module):
+        depth: int
+
+        @nn.compact
+        def __call__(self, u):
+            for _ in range(self.depth):
+                u = u + nn.remat(N.Mamba2Mixer)(
+                    d_model=16, n_heads=2, head_dim=64, n_groups=1,
+                    state=128, chunk=128)(u)
+            return u
+
+    u = jnp.zeros((1, 128, 16))
+    seen = {}
+    for depth in (2, 4):
+        net, before = Stack(depth), dict(counts)
+        params = jax.eval_shape(net.init, jax.random.key(0), u)
+        text = jax.jit(jax.grad(lambda p: net.apply(p, u).sum())).lower(
+            params).as_text()
+        seen[depth] = ({k: counts[k] - before[k] for k in counts},
+                       text.count("func.func private @_forward("),
+                       text.count("func.func private @_backward("),
+                       text.count("call @_backward("))
+    assert seen[2] == ({"_fwd_kernel": 2, "_bwd_kernel": 1}, 1, 1, 2)
+    assert seen[4] == ({"_fwd_kernel": 0, "_bwd_kernel": 0}, 1, 1, 4)
 
 
 def test_the_convolution_is_causal_and_depthwise():

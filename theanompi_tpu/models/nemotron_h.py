@@ -52,9 +52,11 @@ Tracing: ``jax.named_scope``s ``nemotron_h/mamba/in_proj``, ``/conv``,
 ``/ssd``, ``/gate_norm``, ``/out_proj``, ``nemotron_h/router``,
 ``nemotron_h/experts``, ``nemotron_h/shared_expert``,
 ``nemotron_h/attention`` and ``nemotron_h/loss``; the kernels are named
-``nemotron_h_experts_{up,down}_{gmm,gmm_t,tgmm}`` and
-``nemotron_h_attention_{fwd,bwd}``; the scan's plan is one log line a
-shape, the expert buffer's ladder another.  Each step's metrics carry
+``nemotron_h_experts_{up,down}_{gmm,gmm_t,tgmm}``,
+``nemotron_h_attention_{fwd,bwd}`` and, where the scan's shape meets
+the kernels' tiling, ``nemotron_h_ssd_{fwd,bwd}``; the scan's plan
+(which path ran and how) is one log line a shape, the expert buffer's
+ladder another.  Each step's metrics carry
 the rows this chip's experts multiplied and the rows of the buffers
 they lay in; ``_flush_metrics`` feeds them to ``monitor``
 (``moe/held_rows``, ``moe/rows_elsewhere``, ``moe/max_expert_rows``,
@@ -79,7 +81,7 @@ from theanompi_tpu.data.lm import SeqLM_data
 from theanompi_tpu.models import layers as L
 from theanompi_tpu.models.base import ModelConfig, TpuModel
 from theanompi_tpu.ops.attention import fused_attention
-from theanompi_tpu.ops.ssd import ssd_chunked
+from theanompi_tpu.ops.ssd import ssd_chunked, ssd_plan
 from theanompi_tpu.parallel.expert import routed_experts
 from theanompi_tpu.parallel.mesh import AXIS_DATA
 from theanompi_tpu.utils.profiling import trace_running
@@ -109,6 +111,8 @@ KINDS = "ME*"
 #: would leave the choice to the bias alone)
 BALANCE_GAIN = 0.05
 BIAS_LIMIT = 1.0
+#: the scan's kernels are ``<SSD_NAME>_fwd`` and ``<SSD_NAME>_bwd``
+SSD_NAME = "nemotron_h_ssd"
 
 
 def _dense(features: int, name: str, dtype, std: float = 0.02):
@@ -153,13 +157,11 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 
 @functools.lru_cache(maxsize=None)
-def _log_ssd_plan(tokens: int, chunk: int, heads: int, head_dim: int,
-                  state: int) -> None:
+def _log_ssd_plan(plan) -> None:
     """The chunked scan always engages, so its counter is its plan: one
-    line a shape (trace time only), as ``tile_plan`` and the blocked
-    loss say theirs."""
-    _log.info("ssd: %d chunks of %d, %d heads, state %d x %d",
-              tokens // chunk, chunk, heads, head_dim, state)
+    line a shape (trace time only), which path ran and how, as
+    ``tile_plan`` and the blocked loss say theirs."""
+    _log.info("%s", plan)
 
 
 class Mamba2Mixer(nn.Module):
@@ -202,13 +204,14 @@ class Mamba2Mixer(nn.Module):
             (h,))
         skip = self.param("D", nn.initializers.ones, (h,))
         chunk = min(self.chunk, t)
-        _log_ssd_plan(t, chunk, h, p, n)
+        _log_ssd_plan(ssd_plan(b, t, h, p, g, n, chunk,
+                               jnp.dtype(self.dtype).itemsize, SSD_NAME))
         with jax.named_scope("nemotron_h/mamba/ssd"):
             y = ssd_chunked(
                 x.reshape(b, t, h, p),
                 jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
                 -jnp.exp(a_log), b_in.reshape(b, t, g, n),
-                c_in.reshape(b, t, g, n), skip, chunk=chunk)
+                c_in.reshape(b, t, g, n), skip, chunk=chunk, name=SSD_NAME)
         with jax.named_scope("nemotron_h/mamba/gate_norm"):
             scale = self.param("norm_scale", nn.initializers.ones, (inner,))
             y = gated_group_norm(y.reshape(b, t, inner), z, scale, g,
