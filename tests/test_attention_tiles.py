@@ -50,6 +50,10 @@ CASES = {
                                  (3, 12), "pallas"),
     "grouped_8_over_2_heads_of_128": ((1, 256, 256, 8, 2, 128), 64, None,
                                       True, None, (10, 16), "pallas"),
+    # Qwen3-Next's head of 256 (two lane tiles), 8 query heads a
+    # key/value head as its 16 over 2
+    "grouped_8_over_1_heads_of_256": ((1, 256, 256, 8, 1, 256), 64, None,
+                                      True, None, (10, 16), "pallas"),
     "not_causal_visits_every_tile": ((2, 24, 24, 2, 2, 8), 8, None, False,
                                      None, (9, 9), "pallas"),
     "allgather_positions_last_shard": (
@@ -288,6 +292,10 @@ def one_chip():
     ((2, 1024, 4, 256), 4, True,
      "q block 512, key tile 512, 3 of 4 tiles, heads by index map, "
      "rotary in kernel"),
+    # the Qwen3-Next cell's: a head of 256, 16 query over 2 key/value
+    # heads, under the wide-head budget (its fused backward holds 24.5 MiB)
+    ((4, 2048, 16, 256), 2, False,
+     "q block 512, key tile 512, 10 of 16 tiles"),
 ])
 def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
                                        kv_heads, rotary, plan):
@@ -374,3 +382,52 @@ def test_the_scan_kernels_compile_for_a_v5e_under_their_scope(monkeypatch,
     assert kernels == {("nemotron_h_ssd_fwd", "forward"),
                        ("nemotron_h_ssd_fwd", "recompute"),
                        ("nemotron_h_ssd_bwd", "backward")}
+
+
+#: every attention shape the LM cells call (q, key/value heads, whether
+#: the kernels rotate) and its plan: the four cells' from before PR 38,
+#: pinned so that the wide-head budget moves none of them, and the
+#: Qwen3-Next cell's head of 256
+CELL_PLANS = {
+    "gpt2m_s1024_x1": ((8, 1024, 16, 64), 16, False,
+                       A.TilePlan(512, 512, True, 3, 4, True, None)),
+    "gpt2m_s128_x1": ((64, 128, 16, 64), 16, False,
+                      A.TilePlan(128, 128, True, 1, 1, True, None)),
+    "zaya1_8b_s2048_x1": ((4, 2048, 8, 128), 2, False,
+                          A.TilePlan(512, 512, True, 10, 16, True, None)),
+    "ouro_2_6b_s2048_x1": ((4, 2048, 16, 128), 16, True,
+                           A.TilePlan(512, 512, True, 10, 16, False,
+                                      "kernel")),
+    "nemotron_twotower_30b_s2048_x1": (
+        (4, 2048, 32, 128), 2, False,
+        A.TilePlan(512, 512, True, 10, 16, True, None)),
+    "qwen3_next_80b_s2048_x1": ((4, 2048, 16, 256), 2, False,
+                                A.TilePlan(512, 512, True, 10, 16, True,
+                                           None)),
+}
+
+
+@pytest.mark.parametrize("cell", CELL_PLANS)
+def test_each_cells_plan_and_budget_are_pinned(cell):
+    """The plan (q block, key tile, tiles visited, what the kernels are
+    handed) of each cell's attention shape, bf16, causal; both passes
+    fused, the head of 256 under its own budget and every other head
+    under the 16 MiB it was graded at."""
+    (b, t, h, d), kv_heads, rotary, plan = CELL_PLANS[cell]
+    got = A.tile_plan(t, t, d, jnp.bfloat16, True, rotary=rotary)
+    assert got == plan
+    assert A._vmem_budget(d) == (32 if d >= 256 else 16) * 2 ** 20
+    assert A._fits_vmem(t, d, jnp.bfloat16, got.q_block, got.positions,
+                        got.rotates)
+    assert A._fits_vmem_bwd(t, t, d, jnp.bfloat16, got.q_block,
+                            got.positions, got.rotates)
+
+
+def test_a_head_of_256_needs_its_own_budget(monkeypatch):
+    """At 16 MiB no q block holds the Qwen3-Next cell's fused backward
+    (whole Q, G, dq, K, V, dk, dv of a head of 256, twice): its backward
+    would fall back to the composed XLA form and its (4, 16, 2048,
+    2048) float32 scores."""
+    monkeypatch.setattr(A, "_WIDE_HEAD_BUDGET_BYTES", A._VMEM_BUDGET_BYTES)
+    assert not any(A._fits_vmem_bwd(2048, 2048, 256, jnp.bfloat16, blk)
+                   for blk in (512, 256, 128))

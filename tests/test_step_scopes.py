@@ -605,7 +605,10 @@ def test_benchmark_json_gained_the_ten_entries_of_pr_36_in_order():
             ("expert_layer_share", "lower", tok, [lm[1], lm[4]])]
     first = [m["name"] for m in bench["per_layer"]].index(
         "scope_coverage.img")
-    assert bench["per_layer"][first:first + 10] == [
+    got = bench["per_layer"][first:first + 10]
+    # a later PR's cells may join a list, at its end (PR 38's did)
+    assert [dict(m, workloads=m["workloads"][:len(cells)]) for m, (
+        _, _, _, cells) in zip(got, want)] == [
         {"name": name, "unit": "%", "better": better,
          "source": "device_trace", "layer": "step program", "moves": moves,
          "workloads": cells} for name, better, moves, cells in want]

@@ -30,6 +30,10 @@ MODEL_ZOO = {
     # layers, sigmoid top-k experts beside a shared expert, one GQA
     # layer in several (Nemotron-H family)
     "nemotron_h_lm": ("theanompi_tpu.models.nemotron_h", "NemotronHLM"),
+    # Gated DeltaNet linear attention three layers in four, gated
+    # attention at head 256, softmax top-10 of 512 experts beside a gated
+    # shared expert (Qwen3-Next family)
+    "qwen3_next_lm": ("theanompi_tpu.models.qwen3_next", "Qwen3NextLM"),
     # zoo variants (reference lasagne_model_zoo equivalents)
     "vgg19": ("theanompi_tpu.models.model_zoo", "VGG19"),
     "resnet101": ("theanompi_tpu.models.model_zoo", "ResNet101"),

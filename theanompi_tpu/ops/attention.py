@@ -141,11 +141,17 @@ Scope notes:
   (chip_smoke.py's three checks); the four LM cells' reference checks
   run both passes against float32 at (8, 1024, 16, 64), (64, 128, 16,
   64), (4, 2048, 8|2, 128) and (4, 2048, 16|16, 128) rotating.
-  Explicit positions, ``causal=False``, the rotation with grouped
-  heads or a head of 256, and lengths that take a q block under the
-  key tile are compiled for the chip (tests/test_attention_tiles.py)
-  and have not run on it; the ragged-q-tail path has not been
-  compiled.
+  Since PR 38 a head of 256 runs both passes on the chip too, (4, 2048,
+  16|2, 256) bf16 causal in the Qwen3-Next cell (its reference check
+  runs them against float32), under a VMEM budget of its own
+  (``_vmem_budget``: a head of 256 holds twice a head of 128's K and V,
+  and its fused backward 24.5 MiB at a q block of 512; every smaller
+  head keeps the 16 MiB it was graded at, pinned by
+  tests/test_attention_tiles.py).  Explicit positions,
+  ``causal=False``, the rotation with grouped heads or a head of 256,
+  and lengths that take a q block under the key tile are compiled for
+  the chip (tests/test_attention_tiles.py) and have not run on it; the
+  ragged-q-tail path has not been compiled.
 """
 
 from __future__ import annotations
@@ -172,6 +178,11 @@ _MASK_NEG = -1e30
 #: own scoped limit on a v5e (16 MiB), against estimates that count
 #: what the pipeline really holds (``_fits_vmem*``).
 _VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+#: the budget of a head of 256 lanes or more (``_vmem_budget``): its
+#: fused backward holds a head's Q, G, dq, K, V, dk and dv whole, twice,
+#: 24.5 MiB at (2048, 2048, 256) bf16 with a q block of 512, and a v5e
+#: core has 128 MiB
+_WIDE_HEAD_BUDGET_BYTES = 32 * 1024 * 1024
 #: the largest q block and key tile a plan takes.  Graded on the chip
 #: (PERF.md §6, PR 29; fwd+bwd of one layer): at (8, 1024, 16, 64)
 #: 512 x 512 reads 1.660 ms against 2.311 (256 x 256), 2.044
@@ -412,12 +423,20 @@ def _kernel(*refs, scale, causal, plan, group):
     lse_ref[0] = m + jnp.log(l)                       # (1, TQB) fp32
 
 
-def _compiler_params():
+def _vmem_budget(d: int) -> int:
+    """What a program may hold at head size ``d``: the compiler's
+    scoped default up to a head of 128 (every plan the LM cells run was
+    graded under it), twice that from a head of 256 on, whose K and V
+    alone are twice a head of 128's."""
+    return _VMEM_BUDGET_BYTES if d < 256 else _WIDE_HEAD_BUDGET_BYTES
+
+
+def _compiler_params(d: int):
     """The compiler may take twice what the estimates admit: its own
     count of the fused backward grows with batch x heads in a way they
     do not model ((2, 4096, 16, 64) bf16 under 16 MiB, (8, 4096, 16,
     64) 18.0, (16, 3584, 16, 64) 17.5), and a v5e core has 128 MiB."""
-    return pltpu.CompilerParams(vmem_limit_bytes=2 * _VMEM_BUDGET_BYTES)
+    return pltpu.CompilerParams(vmem_limit_bytes=2 * _vmem_budget(d))
 
 
 def _fold(x):                                # (B,T,H,D) -> (B*H,T,D)
@@ -507,7 +526,7 @@ def _pallas_attention(q, k, v, q_pos, k_pos, table=None, *, scale, causal,
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(d),
         interpret=interpret,
         name=name and name + "_fwd",
     )(*operands)
@@ -576,7 +595,7 @@ def _fits_vmem(tk, d, dtype, tq_blk: int, positions: bool = True,
             + rotary * tk * d * itemsize       # rotated K
             + 2 * tile * tq_blk * 4            # fp32 scores, exp
             + 2 * tq_blk * d * 4)              # accumulator, v p
-    return need <= _VMEM_BUDGET_BYTES
+    return need <= _vmem_budget(d)
 
 
 def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int, positions: bool = True,
@@ -604,7 +623,7 @@ def _fits_vmem_bwd(tq, tk, d, dtype, tq_blk: int, positions: bool = True,
             + 3 * tq_blk * d * 4               # q/g casts, dq carry
             + 2 * tile * d * 4                 # k/v tile casts
             + 2 * tile * tq_blk * 4)           # s/p and dp/ds blocks
-    return need <= _VMEM_BUDGET_BYTES
+    return need <= _vmem_budget(d)
 
 
 def _q_block(tq, tk, d, dtype, positions: bool = True,
@@ -838,7 +857,7 @@ def _pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g, table=None, *,
             jax.ShapeDtypeStruct(heads(h_kv, tk), v.dtype),
         ],
         scratch_shapes=scratch,
-        compiler_params=_compiler_params(),
+        compiler_params=_compiler_params(d),
         interpret=interpret,
         name=name and name + "_bwd",
     )(*operands, gf, lse.reshape(b * h, *rows), delta)
