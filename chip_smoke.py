@@ -356,6 +356,32 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
         _normal(((b, t, h, 64), bf16), ((b, t, h), f32), ((h,), f32),
                 *[((b, t, g, 128), bf16)] * 2, ((h,), f32)),
         rtol=2e-2, atol=2e-2))
+    # Qwen3-Next's gated delta rule: 32 value heads of 128, chunks of
+    # 64; keys and queries L2-normed (queries scaled), log decays
+    # negative and write strengths in (0, 1), as the layer hands them
+    shape = (4, 2048, 32, 128) if full else (1, 128, 2, 128)
+
+    def delta_rule(impl):
+        from theanompi_tpu.ops import gated_delta
+
+        def layer(q, k, v, g, beta):
+            def unit(x):
+                x = x.astype(f32)
+                return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+                        ).astype(bf16)
+
+            args = (unit(q) * shape[3] ** -0.5, unit(k), v,
+                    -jax.nn.softplus(g), jax.nn.sigmoid(beta))
+            if impl == "pallas":
+                return gated_delta.gated_delta_chunked(
+                    *args, chunk=64, name="smoke_delta_rule")
+            return gated_delta._delta_jnp(*args, 64)
+        return layer
+
+    cases.append(KernelCase(
+        f"gated_delta{shape}", delta_rule,
+        _normal(*[(shape, bf16)] * 3, *[(shape[:3], f32)] * 2),
+        rtol=2e-2, atol=2e-2))
     # ResNet-50 stage-1 epilogues: conv3 (C=256, +residual) and
     # conv1/conv2 (C=64), each with and without the residual stream
     for c in (256, 64) if full else (32,):

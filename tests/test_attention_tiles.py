@@ -384,6 +384,60 @@ def test_the_scan_kernels_compile_for_a_v5e_under_their_scope(monkeypatch,
                        ("nemotron_h_ssd_bwd", "backward")}
 
 
+def test_the_delta_rule_kernels_compile_for_a_v5e_under_their_scope(
+        monkeypatch, one_chip):
+    """The gated delta rule's kernel pair at the Qwen3-Next cell's shape
+    (4 x 2048 tokens, 16 key heads repeated to 32 value heads of 128,
+    chunks of 64) inside a recomputed Gated DeltaNet mixer, compiled for
+    the chip: Mosaic takes both, XLA's triangular solve is gone, and the
+    step's map places the forward (run and recomputed) and the backward
+    under the scope both delta-rule metrics read."""
+    import re
+
+    import flax.linen as nn
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from theanompi_tpu.models import qwen3_next
+    from theanompi_tpu.monitor import scopes
+    from theanompi_tpu.ops import pallas_mode
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+
+    class Net(nn.Module):
+        @nn.compact
+        def __call__(self, u):
+            return nn.remat(qwen3_next.GatedDeltaNetMixer)(
+                d_model=256, key_heads=16, value_heads=32, key_dim=128,
+                value_dim=128, chunk=64, dtype=jnp.bfloat16,
+                name="linear")(u)
+
+    net = Net()
+    u = jax.ShapeDtypeStruct((4, 2048, 256), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(net.init, jax.random.key(0), u))
+    loss = lambda p, u: net.apply(p, u).astype(  # noqa: E731
+        jnp.float32).sum()
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.value_and_grad(loss)).lower(
+            params, u).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    scope = r"(^|/)qwen3_next/linear_attention/delta_rule(/|$)"
+    kernels = {(name.rsplit(".", 1)[0], phase)
+               for name, (phase, where) in scopes.scope_map(text).items()
+               if name.startswith("qwen3_next_delta_rule_")
+               and re.search(scope, where)}
+    assert kernels == {("qwen3_next_delta_rule_fwd", "forward"),
+                       ("qwen3_next_delta_rule_fwd", "recompute"),
+                       ("qwen3_next_delta_rule_bwd", "backward")}
+
+
 #: every attention shape the LM cells call (q, key/value heads, whether
 #: the kernels rotate) and its plan: the four cells' from before PR 38,
 #: pinned so that the wide-head budget moves none of them, and the

@@ -147,10 +147,11 @@ class TestKernelLeg:
                 if "pl.pallas_call(" in f.read():
                     with_kernel.add(os.path.basename(path))
         assert with_kernel == {"lrn_pallas.py", "attention.py",
-                               "fused_bn.py", "grouped_matmul.py", "ssd.py"}
+                               "fused_bn.py", "grouped_matmul.py", "ssd.py",
+                               "gated_delta.py"}
         names = " ".join(c.name for c in _TINY_CASES)
         for stem in ("lrn", "attention", "attention_gqa", "fused_bn",
-                     "grouped_matmul", "ssd"):
+                     "grouped_matmul", "ssd", "gated_delta"):
             assert stem in names
 
     def test_kernel_that_disagrees_fails(self):
