@@ -317,6 +317,18 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
             q, k, v, causal=True, impl=impl, rotary=rotary_table(
                 jnp.arange(q.shape[1]), q.shape[3], 1e6)),
         _normal(*[(shape, bf16)] * 3), rtol=2e-2, atol=2e-2))
+    # SmallThinkerLM's window layers: 28 over 4 heads of 128 under a
+    # sliding window, K/V streamed from HBM a key tile at a time (at the
+    # cell's 16 384 tokens the XLA form would hold 30 GB of scores)
+    q_shape, kv_shape = (((1, 4096, 28, 128), (1, 4096, 4, 128)) if full
+                         else ((1, 64, 4, 128), (1, 64, 2, 128)))
+    window = 1024 if full else 16
+    cases.append(KernelCase(
+        f"attention_window{q_shape}",
+        lambda impl: lambda q, k, v: fused_attention(
+            q, k, v, causal=True, impl=impl, window=window),
+        _normal((q_shape, bf16), (kv_shape, bf16), (kv_shape, bf16)),
+        rtol=2e-2, atol=2e-2))
     n, d, held, routed = (8192, 2048, 8, 16) if full else (200, 16, 2, 4)
 
     def experts_layer(impl):

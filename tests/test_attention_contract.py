@@ -135,7 +135,9 @@ def test_where_the_kernel_does_not_rotate_xla_does(monkeypatch, case):
 def test_a_backward_over_the_budget_turns_the_gradients_back(monkeypatch):
     """The kernel rotated in the forward and the fused backward does
     not fit: the composed backward runs on the rotated q and k, and dq
-    and dk come back through the rotation's transpose."""
+    and dk come back through the rotation's transpose.  Over explicit
+    positions: over the default ones such a shape streams both passes
+    (tests/test_attention_window.py)."""
     monkeypatch.setattr(A, "_Q_BLOCK", 64)
     q, k, v = _qkv(1, 128, 2, 2, 128, jnp.float32)
     positions = jnp.arange(128)
@@ -146,8 +148,9 @@ def test_a_backward_over_the_budget_turns_the_gradients_back(monkeypatch):
     monkeypatch.setattr(A, "_xla_bwd",
                         lambda *a: (ran.append(1), real(*a))[1])
     got = _out_and_grads(
-        lambda q, k, v: A.fused_attention(q, k, v, causal=True,
-                                          impl="pallas", rotary=table),
+        lambda q, k, v: A.fused_attention(q, k, v, positions, positions,
+                                          causal=True, impl="pallas",
+                                          rotary=table),
         q, k, v)
     assert ran
     want = _out_and_grads(

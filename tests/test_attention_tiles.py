@@ -333,6 +333,36 @@ def test_both_passes_compile_for_a_v5e(monkeypatch, one_chip, q_shape,
     assert "test_attention_fwd" in text and "test_attention_bwd" in text
 
 
+@pytest.mark.parametrize("window", [4096, None])
+def test_the_streamed_pair_compiles_for_a_v5e(monkeypatch, one_chip, window):
+    """Mosaic takes the streamed forward and both backward passes at
+    (1, 16384, 28 over 4, 128) bf16, windowed and global."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from theanompi_tpu.ops import pallas_mode
+
+    monkeypatch.setattr(pallas_mode, "interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, 16384, 28, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    k = jax.ShapeDtypeStruct((1, 16384, 4, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    loss = lambda q, k, v: A.fused_attention(  # noqa: E731
+        q, k, v, causal=True, impl="pallas", name="test_attention",
+        window=window).astype(jnp.float32).sum()
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, k).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    for kernel in ("test_attention_fwd", "test_attention_bwd_kv",
+                   "test_attention_bwd_q"):
+        assert kernel in text
+
+
 def test_the_scan_kernels_compile_for_a_v5e_under_their_scope(monkeypatch,
                                                               one_chip):
     """The Mamba-2 scan's kernel pair at the Nemotron cell's scan shape

@@ -100,8 +100,10 @@ def test_the_cell_is_declared_where_the_issue_says():
             "moves": "tokens_per_s_per_chip", "workloads": [CELL]}
         assert os.path.exists(os.path.join(
             ROOT, "benchmarks", "layer_metrics", name + ".py"))
-    assert bench["per_layer"][-len(NEW):] == [
-        m for m in bench["per_layer"] if m["name"] in NEW]
+    # appended together, in this order, after what was there
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(next(iter(NEW)))
+    assert names[first:first + len(NEW)] == list(NEW)
 
 
 def test_the_configuration_keeps_every_published_width():

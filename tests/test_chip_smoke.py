@@ -150,7 +150,8 @@ class TestKernelLeg:
                                "fused_bn.py", "grouped_matmul.py", "ssd.py",
                                "gated_delta.py"}
         names = " ".join(c.name for c in _TINY_CASES)
-        for stem in ("lrn", "attention", "attention_gqa", "fused_bn",
+        for stem in ("lrn", "attention", "attention_gqa",
+                     "attention_window", "fused_bn",
                      "grouped_matmul", "ssd", "gated_delta"):
             assert stem in names
 

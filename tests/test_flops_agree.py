@@ -64,6 +64,7 @@ def _shapes_only_init(self, config=None, mesh=None, verbose=True,
     ("zaya1_8b", "lm_s2048_x1"),
     ("ouro_2_6b", "lm_s2048_seg1_x1"),
     ("nemotron_twotower_30b", "lm_s2048_seg4_x1"),
+    ("smallthinker_21b", "lm_s16384_seg2_x1"),
 ])
 def test_program_and_benchmark_count_the_same_flops(
         monkeypatch, config_name, traffic_name):
@@ -90,3 +91,23 @@ def test_program_and_benchmark_count_the_same_flops(
     # scales (under 0.1% of GPT-2-medium) ride along; nothing else may
     assert model.train_flops_per_sample == pytest.approx(
         benchmark_count, rel=2e-3)
+
+
+@pytest.mark.parametrize("seq_len, window", [
+    (16, None), (16, 5), (16, 16), (16, 40), (33, 8), (64, 17)])
+def test_the_window_count_is_the_masks(seq_len, window):
+    """SmallThinker's pairs a head and sequence, the count behind its
+    FLOPs and its attention roofline shares, against a brute-force count
+    of the mask the kernels and the reference apply."""
+    import numpy as np
+
+    from theanompi_tpu.models.smallthinker import window_pairs
+    from theanompi_tpu.ops.attention import causal_mask
+
+    pos = np.arange(seq_len)
+    mask = np.asarray(causal_mask(pos, pos, window))
+    assert window_pairs(seq_len, window) == mask.sum()
+    lib = load_file_module(os.path.join(BENCH, "flops", "smallthinker.py"))
+    assert lib.attention_flops(which="fwd", batch=2, heads=3, head_dim=4,
+                               seq_len=seq_len, window=window) == (
+        2 * 2.0 * 2 * 3 * 4 * mask.sum())

@@ -414,7 +414,7 @@ def _forced_layer(routing, form, seed=17):
     plan = _ROUTINGS[routing][0]
     key = jax.random.key(seed)
     u, _, experts = _relu2_layer(n_experts=8, seed=seed)
-    if form == "gated":
+    if form in ("gated", "reglu"):
         experts["gate"] = 0.3 * jax.random.normal(
             jax.random.fold_in(key, 5), experts["up"].shape)
     scores = np.array(0.01 + 0.09 * jax.random.uniform(
@@ -427,35 +427,46 @@ def _forced_layer(routing, form, seed=17):
     return u, jnp.asarray(scores), experts
 
 
-def _uncut(u, scores, experts):
+#: the gated form's activation in each form of expert
+_ACTIVATIONS = {"relu2": "silu", "gated": "silu", "reglu": "relu"}
+
+
+def _uncut(u, scores, experts, activation="silu"):
     """The layer with no buffer at all: every held expert applied to
     every token, weighted where the token chose it."""
     picked, chosen = jax.lax.top_k(scores, 6)
     weights = 2.5 * picked / (picked.sum(-1, keepdims=True) + 1e-20)
+    act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
     out = 0
     for e in range(8):
         up = u @ experts["up"][e]
-        hidden = (jax.nn.silu(u @ experts["gate"][e]) * up
+        hidden = (act(u @ experts["gate"][e]) * up
                   if "gate" in experts else jnp.square(jax.nn.relu(up)))
         out = out + (((chosen == e) * weights).sum(-1)[:, None]
                      * (hidden @ experts["down"][e]))
     return out
 
 
-@pytest.mark.parametrize("form", ["relu2", "gated"])
+@pytest.mark.parametrize("form", ["relu2", "gated", "reglu"])
 @pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("routing", list(_ROUTINGS))
 def test_every_rung_is_the_uncut_layer(routing, impl, form):
     """Output, every gradient (tokens, scores, each matrix) and the
     counters on each rung of the ladder, top-6 normalised and scaled:
     the lower rung sums from the buffer's side, the top one gathers as
-    before, and both are differentiated through the ladder's own VJP."""
+    before, and both are differentiated through the ladder's own VJP;
+    for the squared ReLU, the gated SiLU and the gated ReLU (ReGLU)."""
     u, scores, experts = _forced_layer(routing, form)
     _, rows, held_rows = _ROUTINGS[routing]
+    activation = _ACTIVATIONS[form]
 
     def layer(u, scores, experts):
         return routed_experts(u, scores, experts, (0, 8), top_k=6,
-                              normalize=True, scale=2.5, impl=impl)
+                              normalize=True, scale=2.5, impl=impl,
+                              activation=activation)
+
+    def uncut(u, scores, experts):
+        return _uncut(u, scores, experts, activation)
 
     def loss(fn):
         def scalar(u, scores, experts):
@@ -466,7 +477,7 @@ def test_every_rung_is_the_uncut_layer(routing, impl, form):
                                           has_aux=True))
 
     (_, (got_out, stats)), got = loss(layer)(u, scores, experts)
-    (_, (want_out, _)), want = loss(_uncut)(u, scores, experts)
+    (_, (want_out, _)), want = loss(uncut)(u, scores, experts)
     assert (stats["buffer_rows"], stats["held_rows"]) == (rows, held_rows)
     assert stats["held_rows"] + stats["rows_elsewhere"] == 1800
     np.testing.assert_allclose(got_out, want_out, rtol=1e-5, atol=1e-5)
@@ -556,3 +567,28 @@ def test_the_kernels_keep_their_names_inside_the_ladders_backward():
         for kernel in ("gmm", "gmm_t", "tgmm"):
             assert f'/nemotron_h_experts_{which}_{kernel}/' in text
     assert "(nemotron_h_experts" not in text
+
+
+@pytest.mark.parametrize("routing", ["few", "all"])
+def test_the_default_activation_is_the_gated_silu_bit_for_bit(routing):
+    """``activation`` left out is the program it was before the choice
+    existed: the same jaxpr as ``"silu"`` named, the same bits, and
+    ReGLU another layer; a two-matrix expert takes no gate activation."""
+    u, scores, experts = _forced_layer(routing, "gated")
+
+    def layer(**kw):
+        return jax.jit(lambda u, s, p: routed_experts(
+            u, s, p, (0, 8), top_k=6, normalize=True, scale=2.5, **kw)[0])
+
+    default = layer()(u, scores, experts)
+    silu = layer(activation="silu")(u, scores, experts)
+    assert np.array_equal(np.asarray(default), np.asarray(silu))
+    assert str(jax.make_jaxpr(layer())(u, scores, experts)) == str(
+        jax.make_jaxpr(layer(activation="silu"))(u, scores, experts))
+    reglu = layer(activation="relu")(u, scores, experts)
+    assert not np.allclose(np.asarray(reglu), np.asarray(default))
+    with pytest.raises(ValueError, match="gated form"):
+        layer(activation="gelu")(u, scores, experts)
+    two = {k: experts[k] for k in ("up", "down")}
+    with pytest.raises(ValueError, match="gated form"):
+        layer(activation="relu")(u, scores, two)

@@ -34,6 +34,11 @@ MODEL_ZOO = {
     # attention at head 256, softmax top-10 of 512 experts beside a gated
     # shared expert (Qwen3-Next family)
     "qwen3_next_lm": ("theanompi_tpu.models.qwen3_next", "Qwen3NextLM"),
+    # global attention without positions one layer in four beside RoPE
+    # sliding-window attention, a router read before attention, softmax
+    # top-6 of 64 ReGLU experts (SmallThinker family)
+    "smallthinker_lm": ("theanompi_tpu.models.smallthinker",
+                        "SmallThinkerLM"),
     # zoo variants (reference lasagne_model_zoo equivalents)
     "vgg19": ("theanompi_tpu.models.model_zoo", "VGG19"),
     "resnet101": ("theanompi_tpu.models.model_zoo", "ResNet101"),

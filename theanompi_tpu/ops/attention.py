@@ -36,12 +36,29 @@ Scope notes:
   leaves it as (D, q block), turned by XLA as the operand of the next
   matmul (turned in the kernel it cost the forward 13%, 40% at one
   tile).
-* K/V for one (batch, head) must fit VMEM (checked; oversize shapes
-  fall back to the XLA path) — local shard lengths up to a few
-  thousand, which is the regime this framework runs attention at:
-  GLOBAL long context is the ring/Ulysses layer's job
-  (parallel/sequence.py), and what each device sees locally is exactly
-  this kernel's shape.
+* The kernels above hold K/V for one (batch, head) RESIDENT in VMEM,
+  up to a few thousand keys (2 560 at head 128 for both passes).  Over
+  the default positions a shape that either resident pass cannot hold,
+  and every call with a sliding ``window=``, takes the STREAMED kernels
+  instead (``TilePlan.stream``; the resident plans of every other shape
+  are as they were, pinned by tests/test_attention_tiles.py): a grid
+  step is one (q block, key tile) pair the mask leaves
+  (``_stream_visits``), listed in scalar-prefetched tables that the
+  index maps read, so a key tile outside the causal triangle or the
+  window is neither computed nor fetched from HBM, and consecutive
+  visits of one block fetch it once; the running max, sum and
+  accumulator live in VMEM scratch across a q block's visits.  The
+  backward streams too: a dK/dV pass key tile by key tile (the group's
+  query heads innermost) and a dQ pass q block by q block, each over
+  the same pairs, storing nothing but the forward's ``out`` and
+  ``lse``.  A window of W keys is ``(i - W, i]``: the query's own key
+  and the W - 1 before it; at (16 384, 16 384), blocks and tiles of
+  512, a window of 4 096 visits 252 of 1 024 tiles, the causal mask
+  528.  The streamed kernels rotate nothing: a caller's ``rotary=``
+  table is applied by ``rotary_xla`` before them.  Explicit positions
+  keep the resident kernels or, past them, the XLA path (a window over
+  explicit positions too): GLOBAL long context over devices is the
+  ring/Ulysses layer's job (parallel/sequence.py).
 * Backward is ALSO fused (flash-style): the fwd emits the per-row
   logsumexp, and the bwd kernel recomputes p from (q, k, lse) tile by
   tile, accumulating dq in the walk's carry and dk/dv in fp32 VMEM
@@ -130,8 +147,9 @@ Scope notes:
   nothing to overlap in a rolled loop), so on the chip 512 x 512 beats
   every smaller plan at both benchmark shapes although 256 x 256 skips
   more (PERF.md §6, PR 29).
-* ``name=`` labels the two ``pallas_call``s (``<name>_fwd`` /
-  ``<name>_bwd``) so a trace reducer can tell one model's attention
+* ``name=`` labels the ``pallas_call``s (``<name>_fwd`` /
+  ``<name>_bwd``; the streamed backward's two ``<name>_bwd_kv`` and
+  ``<name>_bwd_q``) so a trace reducer can tell one model's attention
   from another custom call; None keeps Pallas's default.
 * On-chip status (TPU v5 lite, JAX 0.9.0 / libtpu 0.0.34, PR 33): fwd
   and the fused bwd compile and match the XLA form at (8, 1024, 12,
@@ -190,6 +208,9 @@ _WIDE_HEAD_BUDGET_BYTES = 32 * 1024 * 1024
 #: (4, 2048, 8|2, 128) 1.320 against 2.051 (256 x 256) and 1.443
 #: (1024 x 1024).
 _Q_BLOCK = 512
+#: the streamed kernels' largest q block or key tile: a length that no
+#: configured size divides would be one block of all its rows
+_STREAM_BLOCK_LIMIT = 2048
 
 
 def block_scores(q, k, scale):
@@ -199,8 +220,14 @@ def block_scores(q, k, scale):
                       preferred_element_type=jnp.float32) * scale
 
 
-def causal_mask(q_pos, k_pos):
-    return q_pos[:, None] >= k_pos[None, :]          # (Tq, Tk)
+def causal_mask(q_pos, k_pos, window: int | None = None):
+    """(Tq, Tk): key j is seen by query i iff ``k_pos[j] <= q_pos[i]``
+    and, under a ``window`` of W keys, ``q_pos[i] - k_pos[j] < W``."""
+    ahead = q_pos[:, None] - k_pos[None, :]
+    seen = ahead >= 0
+    if window is not None:
+        seen &= ahead < window
+    return seen
 
 
 class TilePlan(NamedTuple):
@@ -213,7 +240,10 @@ class TilePlan(NamedTuple):
     for; the kernels that rotate also pick a head by index map from
     (B, T, H * D), the others are handed (B * H, T, D)), and whether
     they are handed the positions (``positions``: always to the folded
-    kernels; to the others only where a mask has to read them)."""
+    kernels; to the others only where a mask has to read them).  A
+    ``window`` of W keys (query i sees keys ``(i - W, i]``) and
+    ``stream`` (K/V fetched from HBM a key tile at a time, the streamed
+    kernels) come last."""
 
     q_block: int
     key_tile: int
@@ -222,6 +252,8 @@ class TilePlan(NamedTuple):
     total: int
     positions: bool = True
     rotary: str | None = None
+    window: int | None = None
+    stream: bool = False
 
     @property
     def rotates(self) -> bool:
@@ -232,6 +264,8 @@ class TilePlan(NamedTuple):
     def __str__(self):
         return (f"q block {self.q_block}, key tile {self.key_tile}, "
                 f"{self.visited} of {self.total} tiles"
+                + (f", window {self.window}" if self.window else "")
+                + (", K/V streamed" if self.stream else "")
                 + (", heads by index map" if self.rotates else "")
                 + (f", rotary in {self.rotary}" if self.rotary else ""))
 
@@ -264,11 +298,16 @@ def _key_tile(tk: int) -> int:
 
 def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
               default_positions: bool = True,
-              rotary: bool = False) -> TilePlan:
+              rotary: bool = False, window: int | None = None) -> TilePlan:
     """The plan of a shape: a pure function of shape, dtype, mask,
     whether the caller passed a rotary table, and the module's
     configured sizes, which the kernels, the log line, the tests and
-    PERF.md all read."""
+    PERF.md all read.  Over the default positions the streamed kernels
+    take a ``window`` and every shape whose K/V the resident forward or
+    fused backward cannot hold whole; every other shape keeps the plan
+    it had.  A window the
+    streamed kernels cannot take (explicit positions, a length no block
+    divides) stays on the plan, for ``_resolve_impl`` to route."""
     skip = causal and default_positions
     # a head of whole 128-lane rows is a block of (B, T, H * D) that
     # Mosaic takes; a 64-lane block of a 1 024-lane row is not
@@ -283,8 +322,57 @@ def tile_plan(tq: int, tk: int, d: int, dtype, causal: bool,
     n_blocks, n_tiles = pl.cdiv(tq, q_block), tk // key_tile
     visited = sum(_walk_bounds(j, q_block, key_tile, n_tiles, causal,
                                skip)[1] for j in range(n_blocks))
-    return TilePlan(q_block, key_tile, skip, visited, n_blocks * n_tiles,
-                    positions, where)
+    plan = TilePlan(q_block, key_tile, skip, visited, n_blocks * n_tiles,
+                    positions, where, window)
+    rotates = where == "kernel"
+    if default_positions and (window is not None or not (
+            _fits_vmem(tk, d, dtype, q_block, positions, rotates)
+            and _fits_vmem_bwd(tq, tk, d, dtype, q_block, positions,
+                               rotates))):
+        return _stream_plan(tq, tk, causal, rotary, window) or plan
+    return plan
+
+
+def _stream_plan(tq: int, tk: int, causal: bool, rotary: bool,
+                 window: int | None) -> TilePlan | None:
+    """The streamed kernels' plan: the configured q block and key tile
+    or their halves down to 128, the largest that divide the lengths
+    (a length shorter than the block is one block); the rotation, where
+    asked for, in XLA.  None where a length takes a block over
+    ``_STREAM_BLOCK_LIMIT`` rows (no configured size divides it)."""
+    q_block = min(_Q_BLOCK, tq)
+    while tq % q_block and q_block >= 256 and q_block % 16 == 0:
+        q_block //= 2
+    key_tile = _key_tile(tk)
+    if tq % q_block or max(q_block, key_tile) > _STREAM_BLOCK_LIMIT:
+        return None
+    plan = TilePlan(q_block, key_tile, causal, 0,
+                    (tq // q_block) * (tk // key_tile), False,
+                    "XLA" if rotary else None, window, True)
+    return plan._replace(visited=len(_stream_visits(plan, tq, tk)))
+
+
+def _stream_visits(plan: TilePlan, tq: int, tk: int) -> list:
+    """``(q block, key tile, masked)`` of every tile the streamed
+    kernels visit, q block by q block: over the default positions query
+    i sees key j iff ``0 <= i - j`` (causal) and ``i - j < W`` (a
+    window of W), so a tile is visited where some pair of it is seen,
+    and masked where some pair is not.  A pure function of the plan and
+    the lengths."""
+    qb, kt, w = plan.q_block, plan.key_tile, plan.window
+    visits = []
+    for j in range(tq // qb):
+        for t in range(tk // kt):
+            if not plan.skip:                   # not causal: every tile
+                visits.append((j, t, False))
+                continue
+            least = j * qb - ((t + 1) * kt - 1)     # least i - j in the tile
+            most = (j + 1) * qb - 1 - t * kt        # and the most
+            if most < 0 or (w is not None and least >= w):
+                continue
+            visits.append((j, t, least < 0 or (w is not None
+                                               and most >= w)))
+    return visits
 
 
 def _walk(j, body, carry, plan: TilePlan, n_tiles: int, causal: bool):
@@ -568,12 +656,13 @@ def rotary_xla(x, table):
                            -1).astype(x.dtype)
 
 
-def _xla_attention(q, k, v, q_pos, k_pos, scale, causal):
+def _xla_attention(q, k, v, q_pos, k_pos, scale, causal, window=None):
     """The composed-XLA fallback (same primitives as the oracle)."""
     k, v = _repeat_kv(q, k, v)
     s = block_scores(q, k, scale)
     if causal:
-        s = jnp.where(causal_mask(q_pos, k_pos)[None, None], s, _MASK_NEG)
+        s = jnp.where(causal_mask(q_pos, k_pos, window)[None, None], s,
+                      _MASK_NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
@@ -664,12 +753,20 @@ def _resolve_impl(impl: str | None, q, k,
     impl = impl or "auto"
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
-    if impl != "auto":
-        return impl
     b, tq, h, d = q.shape
     plan = plan or tile_plan(tq, k.shape[1], d, q.dtype, causal=True)
+    if plan.window is not None and not plan.stream:
+        # only the streamed kernels mask a window
+        impl = "auto" if impl == "pallas" else impl
+    if impl != "auto":
+        return impl
     if jax.default_backend() != "tpu":
         choice, why = "xla", "not a TPU"
+    elif plan.window is not None and not plan.stream:
+        choice, why = "xla", ("a window over explicit positions or a "
+                              "length no block divides")
+    elif plan.stream:
+        choice, why = "pallas", f"streams, {plan}"
     elif not _fits_vmem(k.shape[1], d, q.dtype, plan.q_block,
                         plan.positions, plan.rotates):
         choice, why = "xla", "K/V + score block exceed the VMEM budget"
@@ -870,7 +967,329 @@ def _pallas_attention_bwd(q, k, v, q_pos, k_pos, out, lse, g, table=None, *,
     return unfold(dq, tq, h), unfold(dk, tk, h_kv), unfold(dv, tk, h_kv)
 
 
-def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g):
+# ---- the streamed kernels: K/V a key tile at a time from HBM ----
+#
+# A grid step is one VISIT, a (q block, key tile) pair the mask leaves,
+# listed by ``_stream_visits`` and handed to the kernels as
+# scalar-prefetched tables: the index maps read which block and tile a
+# step fetches, so a tile the mask leaves out is neither computed nor
+# fetched, and consecutive visits of one block or tile fetch it once.
+# The running max, sum and accumulator live in VMEM scratch across a
+# block's visits.  Scores are held transposed, (key tile, q block), as
+# in the resident kernels; their products and float32 casts too.
+
+#: a visit's flags: the first and last visit of its block (or tile),
+#: whether its scores are masked one by one, and (the dK/dV pass alone)
+#: a key tile no query sees, whose gradients are written as zeros
+_FIRST, _LAST, _MASKED, _EMPTY = 1, 2, 4, 8
+
+
+def _stream_tables(visits: list, key_tiles: int | None = None):
+    """Three int32 tables over the visits, in the order a pass walks
+    them: q block, key tile, flags.  ``key_tiles``: key tile by key tile
+    over that many (the dK/dV pass), a tile that no query sees given one
+    empty visit; None: q block by q block."""
+    rows = []
+    if key_tiles is not None:
+        seen = {t for _, t, _ in visits}
+        visits = sorted(visits + [(0, t, None) for t in range(key_tiles)
+                                  if t not in seen],
+                        key=lambda v: (v[1], v[0]))
+    owner = 0 if key_tiles is None else 1   # a visit's q block, or tile
+    for n, visit in enumerate(visits):
+        j, t, masked = visit
+        first = n == 0 or visits[n - 1][owner] != visit[owner]
+        last = n == len(visits) - 1 or visits[n + 1][owner] != visit[owner]
+        rows.append((j, t, first * _FIRST + last * _LAST
+                     + (masked is True) * _MASKED
+                     + (masked is None) * _EMPTY))
+    return tuple(jnp.asarray([r[i] for r in rows], jnp.int32)
+                 for i in range(3))
+
+
+def _stream_mask(j, t, plan: TilePlan):
+    """The (key tile, q block) mask of q block ``j`` against key tile
+    ``t`` (traced indices): query - key = ``ahead - offset``."""
+    shape = (plan.key_tile, plan.q_block)
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+             - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
+    offset = t * plan.key_tile - j * plan.q_block
+    seen = ahead >= offset
+    if plan.window is not None:
+        seen = jnp.logical_and(seen, ahead < offset + plan.window)
+    return seen
+
+
+def _on_visit(flag, visit):
+    """``visit(masked)`` under the flag's mask bit: two bodies, each
+    lowered once."""
+    pl.when((flag & _MASKED) != 0)(lambda: visit(True))
+    pl.when((flag & (_MASKED | _EMPTY)) == 0)(lambda: visit(False))
+
+
+def _stream_kernel(q_of, k_of, flags, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                   m_s, l_s, acc_s, *, scale, plan):
+    """One visit of the streamed forward: the online softmax of the
+    resident kernel, its carry in scratch across a q block's visits."""
+    s = pl.program_id(1)
+    flag, j, t = flags[s], q_of[s], k_of[s]
+
+    @pl.when((flag & _FIRST) != 0)
+    def _():
+        m_s[...] = jnp.full(m_s.shape, _MASK_NEG, jnp.float32)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    def visit(masked):
+        sc = jax.lax.dot_general(
+            k_ref[0], q_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (TILE, TQB)
+        if masked:
+            sc = jnp.where(_stream_mask(j, t, plan), sc, _MASK_NEG)
+        m = m_s[...]
+        m_new = jnp.maximum(m, jnp.max(sc, axis=0, keepdims=True))
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_s[...] = alpha * l_s[...] + jnp.sum(p, axis=0, keepdims=True)
+        acc_s[...] = alpha * acc_s[...] + jax.lax.dot_general(
+            v_ref[0], p.astype(v_ref.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)       # v^T p (D, TQB)
+        m_s[...] = m_new
+
+    _on_visit(flag, visit)
+
+    @pl.when((flag & _LAST) != 0)
+    def _():
+        o_ref[0] = (acc_s[...] / l_s[...]).astype(o_ref.dtype)
+        lse_ref[0] = m_s[...] + jnp.log(l_s[...])
+
+
+def _stream_layout(b: int, h: int, h_kv: int, d: int):
+    """How the streamed kernels reach a head: by index map in (B, T, H *
+    D) where a head fills whole lanes, else folded (B * H, T, D).
+    Returns ``(reshape, query, shared)``: ``query(row, block)`` and
+    ``shared(row, tile)`` are the block indices of folded query row
+    ``row = b * H + head`` and of the key/value head it reads."""
+    group = h // h_kv
+    if d % 128 == 0:
+        return (lambda x: x.reshape(*x.shape[:2], -1),
+                lambda r, j: (r // h, j, r % h),
+                lambda r, t: (r // h, t, r % h // group))
+    return (_fold, lambda r, j: (r, j, 0),
+            lambda r, t: (r // group, t, 0))
+
+
+def _stream_params(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "interpret", "name", "plan"))
+def _stream_attention(q, k, v, *, scale, interpret, plan: TilePlan,
+                      name: str | None = None):
+    """The streamed forward -> (out (B, Tq, H, D), lse (B*H, 1, Tq)),
+    as ``_pallas_attention`` returns them."""
+    b, tq, h, d = q.shape
+    tk, h_kv = k.shape[1:3]
+    qb, kt = plan.q_block, plan.key_tile
+    tables = _stream_tables(_stream_visits(plan, tq, tk))
+    heads, query, shared = _stream_layout(b, h, h_kv, d)
+    out, lse = pl.pallas_call(
+        functools.partial(_stream_kernel, scale=scale, plan=plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h, len(tables[0])),
+            in_specs=[
+                pl.BlockSpec((1, qb, d),
+                             lambda i, s, qo, ko, f: query(i, qo[s])),
+                pl.BlockSpec((1, kt, d),
+                             lambda i, s, qo, ko, f: shared(i, ko[s])),
+                pl.BlockSpec((1, kt, d),
+                             lambda i, s, qo, ko, f: shared(i, ko[s])),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, d, qb),
+                             lambda i, s, qo, ko, f: (i, 0, qo[s])),
+                pl.BlockSpec((1, 1, qb),
+                             lambda i, s, qo, ko, f: (i, 0, qo[s])),
+            ],
+            scratch_shapes=[pltpu.VMEM((1, qb), jnp.float32),
+                            pltpu.VMEM((1, qb), jnp.float32),
+                            pltpu.VMEM((d, qb), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((b * h, d, tq), q.dtype),
+                   jax.ShapeDtypeStruct((b * h, 1, tq), jnp.float32)],
+        compiler_params=_stream_params("parallel", "arbitrary"),
+        interpret=interpret,
+        name=name and name + "_fwd",
+    )(*tables, heads(q), heads(k), heads(v))
+    return out.reshape(b, h, d, tq).transpose(0, 3, 1, 2), lse
+
+
+def _stream_scores(q_ref, k_ref, lse_ref, j, t, masked, scale, plan):
+    """A visit's probabilities, (key tile, q block) float32, recomputed
+    from (q, k, lse), and its q and k as float32."""
+    q = q_ref[0].astype(jnp.float32)                  # (TQB, D)
+    kmat = k_ref[0].astype(jnp.float32)               # (TILE, D)
+    sc = jax.lax.dot_general(kmat, q, (((1,), (1,)), ((), ()))) * scale
+    if masked:
+        sc = jnp.where(_stream_mask(j, t, plan), sc, _MASK_NEG)
+    return jnp.exp(sc - lse_ref[0]), q, kmat
+
+
+def _stream_kv_kernel(q_of, k_of, flags, q_ref, k_ref, v_ref, g_ref,
+                      lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
+                      scale, plan):
+    """One visit of the dK/dV pass: key tile by key tile, over the q
+    blocks that see it and the group's query heads (the innermost grid
+    axis), dk and dv summed in float32 scratch."""
+    s, member = pl.program_id(1), pl.program_id(2)
+    flag, j, t = flags[s], q_of[s], k_of[s]
+    group = pl.num_programs(2)
+
+    @pl.when(jnp.logical_and((flag & _FIRST) != 0, member == 0))
+    def _():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    def visit(masked):
+        p, q, _ = _stream_scores(q_ref, k_ref, lse_ref, j, t, masked,
+                                 scale, plan)
+        g = g_ref[0].astype(jnp.float32)
+        dv_s[...] += jax.lax.dot_general(
+            p, g, (((1,), (0,)), ((), ())))           # p^T g (TILE, D)
+        dp = jax.lax.dot_general(
+            v_ref[0].astype(jnp.float32), g, (((1,), (1,)), ((), ())))
+        ds = p * (dp - delta_ref[0])
+        dk_s[...] += jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ()))) * scale  # ds^T q
+
+    _on_visit(flag, visit)
+
+    @pl.when(jnp.logical_and((flag & _LAST) != 0, member == group - 1))
+    def _():
+        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
+
+
+def _stream_q_kernel(q_of, k_of, flags, q_ref, k_ref, v_ref, g_ref,
+                     lse_ref, delta_ref, dq_ref, dq_s, *, scale, plan):
+    """One visit of the dQ pass: q block by q block over the key tiles
+    it sees, dq summed in float32 scratch."""
+    s = pl.program_id(1)
+    flag, j, t = flags[s], q_of[s], k_of[s]
+
+    @pl.when((flag & _FIRST) != 0)
+    def _():
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def visit(masked):
+        p, _, kmat = _stream_scores(q_ref, k_ref, lse_ref, j, t, masked,
+                                    scale, plan)
+        g = g_ref[0].astype(jnp.float32)
+        dp = jax.lax.dot_general(
+            v_ref[0].astype(jnp.float32), g, (((1,), (1,)), ((), ())))
+        ds = p * (dp - delta_ref[0])
+        dq_s[...] += jax.lax.dot_general(
+            ds, kmat, (((0,), (0,)), ((), ())))       # ds k (TQB, D)
+
+    _on_visit(flag, visit)
+
+    @pl.when((flag & _LAST) != 0)
+    def _():
+        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "interpret", "name", "plan"))
+def _stream_attention_bwd(q, k, v, out, lse, g, *, scale, interpret,
+                          plan: TilePlan, name: str | None = None):
+    """The streamed backward: a dK/dV pass over the key tiles and a dQ
+    pass over the q blocks, each walking only the visits the mask
+    leaves; ``delta = g . out`` a row, as the resident backward takes
+    it."""
+    b, tq, h, d = q.shape
+    tk, h_kv = k.shape[1:3]
+    group = h // h_kv
+    qb, kt = plan.q_block, plan.key_tile
+    visits = _stream_visits(plan, tq, tk)
+    heads, query, shared = _stream_layout(b, h, h_kv, d)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    delta = delta.transpose(0, 2, 1).reshape(b * h, 1, tq)
+    operands = (heads(q), heads(k), heads(v), heads(g), lse, delta)
+
+    # the dK/dV pass: grid (batch * key/value head, visit, group member);
+    # query row of (i, member) is i * group + member
+    def q_row(i, m):
+        return i * group + m
+
+    kv_tables = _stream_tables(visits, tk // kt)
+    kv_in = [
+        pl.BlockSpec((1, qb, d), lambda i, s, m, qo, ko, f: query(
+            q_row(i, m), qo[s])),
+        pl.BlockSpec((1, kt, d), lambda i, s, m, qo, ko, f: shared(
+            q_row(i, m), ko[s])),
+        pl.BlockSpec((1, kt, d), lambda i, s, m, qo, ko, f: shared(
+            q_row(i, m), ko[s])),
+        pl.BlockSpec((1, qb, d), lambda i, s, m, qo, ko, f: query(
+            q_row(i, m), qo[s])),
+        pl.BlockSpec((1, 1, qb), lambda i, s, m, qo, ko, f: (
+            q_row(i, m), 0, qo[s])),
+        pl.BlockSpec((1, 1, qb), lambda i, s, m, qo, ko, f: (
+            q_row(i, m), 0, qo[s])),
+    ]
+    kv_out = [pl.BlockSpec((1, kt, d), lambda i, s, m, qo, ko, f: shared(
+        i * group, ko[s]))] * 2
+    kv_shape = ((b, tk, h_kv * d) if d % 128 == 0 else (b * h_kv, tk, d))
+    dk, dv = pl.pallas_call(
+        functools.partial(_stream_kv_kernel, scale=scale, plan=plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h_kv, len(kv_tables[0]), group),
+            in_specs=kv_in, out_specs=kv_out,
+            scratch_shapes=[pltpu.VMEM((kt, d), jnp.float32),
+                            pltpu.VMEM((kt, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(kv_shape, k.dtype),
+                   jax.ShapeDtypeStruct(kv_shape, v.dtype)],
+        compiler_params=_stream_params("parallel", "arbitrary",
+                                       "arbitrary"),
+        interpret=interpret,
+        name=name and name + "_bwd_kv",
+    )(*kv_tables, *operands)
+
+    # the dQ pass: grid (batch * query head, visit), the forward's walk
+    q_tables = _stream_tables(visits)
+    q_in = [
+        pl.BlockSpec((1, qb, d), lambda i, s, qo, ko, f: query(i, qo[s])),
+        pl.BlockSpec((1, kt, d), lambda i, s, qo, ko, f: shared(i, ko[s])),
+        pl.BlockSpec((1, kt, d), lambda i, s, qo, ko, f: shared(i, ko[s])),
+        pl.BlockSpec((1, qb, d), lambda i, s, qo, ko, f: query(i, qo[s])),
+        pl.BlockSpec((1, 1, qb), lambda i, s, qo, ko, f: (i, 0, qo[s])),
+        pl.BlockSpec((1, 1, qb), lambda i, s, qo, ko, f: (i, 0, qo[s])),
+    ]
+    dq = pl.pallas_call(
+        functools.partial(_stream_q_kernel, scale=scale, plan=plan),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b * h, len(q_tables[0])),
+            in_specs=q_in,
+            out_specs=pl.BlockSpec((1, qb, d), lambda i, s, qo, ko, f:
+                                   query(i, qo[s])),
+            scratch_shapes=[pltpu.VMEM((qb, d), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(heads(q).shape, q.dtype),
+        compiler_params=_stream_params("parallel", "arbitrary"),
+        interpret=interpret,
+        name=name and name + "_bwd_q",
+    )(*q_tables, *operands)
+
+    def unfold(x, t, n):
+        if d % 128 == 0:
+            return x.reshape(b, t, n, d)
+        return x.reshape(b, n, t, d).transpose(0, 2, 1, 3)
+
+    return unfold(dq, tq, h), unfold(dk, tk, h_kv), unfold(dv, tk, h_kv)
+
+
+def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g, window=None):
     """Composed-XLA VJP (recompute p from inputs): dv = p^T g;
     ds = p * (dp - rowsum(dp*p)), dp = g v^T; dq = ds k * scale;
     dk = ds^T q * scale.  Fallback when the Pallas bwd's VMEM/blocking
@@ -879,7 +1298,8 @@ def _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g):
     k, v = _repeat_kv(q, k, v)
     s = block_scores(q, k, scale)
     if causal:
-        s = jnp.where(causal_mask(q_pos, k_pos)[None, None], s, _MASK_NEG)
+        s = jnp.where(causal_mask(q_pos, k_pos, window)[None, None], s,
+                      _MASK_NEG)
     p = jax.nn.softmax(s, axis=-1)                       # fp32
     g32 = g.astype(jnp.float32)
     dv = jnp.einsum("bhqk,bqhd->bkhd", p, g32)
@@ -905,27 +1325,37 @@ def _fused(q, k, v, q_pos, k_pos, table, scale, causal, interpret, name,
 
 def _fused_fwd(q, k, v, q_pos, k_pos, table, scale, causal, interpret,
                name, plan):
-    out, lse = _pallas_attention(q, k, v, q_pos, k_pos, table, scale=scale,
-                                 causal=causal, interpret=interpret,
-                                 name=name, plan=plan)
+    if plan.stream:
+        out, lse = _stream_attention(q, k, v, scale=scale,
+                                     interpret=interpret, name=name,
+                                     plan=plan)
+    else:
+        out, lse = _pallas_attention(q, k, v, q_pos, k_pos, table,
+                                     scale=scale, causal=causal,
+                                     interpret=interpret, name=name,
+                                     plan=plan)
     return out, (q, k, v, q_pos, k_pos, table, out, lse)
 
 
 def _fused_bwd(scale, causal, interpret, name, plan, res, g):
     q, k, v, q_pos, k_pos, table, out, lse = res
     tq, tk, d = q.shape[1], k.shape[1], q.shape[-1]
-    # the fused bwd loops exact q-blocks; ragged tails or oversize
-    # VMEM needs take the composed-XLA path instead
-    fused = tq % plan.q_block == 0 and _fits_vmem_bwd(
+    # the fused bwd loops exact q-blocks; ragged tails and oversize VMEM
+    # needs that the plan could not stream take the composed-XLA path
+    fused = not plan.stream and tq % plan.q_block == 0 and _fits_vmem_bwd(
         tq, tk, d, q.dtype, plan.q_block, plan.positions, plan.rotates)
     _log_choice("attention bwd", q.shape + k.shape[1:3], str(q.dtype),
-                "pallas" if fused else "xla",
-                f"fits, {plan}" if fused else "ragged q-tail "
-                "or over the VMEM budget")
+                "pallas" if fused or plan.stream else "xla",
+                f"fits, {plan}" if fused else f"streams, {plan}"
+                if plan.stream else "ragged q-tail or over the VMEM budget")
     if fused:
         dq, dk, dv = _pallas_attention_bwd(
             q, k, v, q_pos, k_pos, out, lse, g, table, scale=scale,
             causal=causal, interpret=interpret, name=name, plan=plan)
+    elif plan.stream:
+        dq, dk, dv = _stream_attention_bwd(
+            q, k, v, out, lse, g, scale=scale, interpret=interpret,
+            name=name, plan=plan)
     else:
         if not plan.positions:
             q_pos, k_pos = jnp.arange(tq), jnp.arange(tk)
@@ -934,7 +1364,8 @@ def _fused_bwd(scale, causal, interpret, name, plan, res, g):
             (q, k), unrotate = jax.vjp(
                 lambda q, k: (rotary_xla(q, table), rotary_xla(k, table)),
                 q, k)
-        dq, dk, dv = _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g)
+        dq, dk, dv = _xla_bwd(q, k, v, q_pos, k_pos, scale, causal, g,
+                              plan.window)
         if unrotate:
             dq, dk = unrotate((dq, dk))
     return dq, dk, dv, None, None, None
@@ -946,7 +1377,7 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 def fused_attention(q, k, v, q_pos=None, k_pos=None,
                     causal: bool = False, scale: float | None = None,
                     impl: str | None = None, name: str | None = None,
-                    rotary=None):
+                    rotary=None, window: int | None = None):
     """Softmax attention, fused on TPU.
 
     q: (B, Tq, H, D); k/v: (B, Tk, Hkv, D) with H a multiple of Hkv
@@ -956,8 +1387,10 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
     Tk; (T, D) float32), by which q and k are rotated before the score
     product: inside the kernels where a head fills whole lanes, by
     ``rotary_xla`` elsewhere, the same numbers either way.
-    ``name`` labels the kernels in a trace.  Returns (B, Tq, H, D) in
-    q.dtype.
+    ``window``: a causal sliding window of W keys, query i seeing keys
+    ``(i - W, i]`` (its own included): the streamed kernels, which
+    neither compute nor fetch a tile outside it.  ``name`` labels the
+    kernels in a trace.  Returns (B, Tq, H, D) in q.dtype.
     """
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads over {k.shape[2]} key "
@@ -968,13 +1401,18 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
         raise ValueError(
             f"a rotary table of {rotary.shape} for q {q.shape} and k "
             f"{k.shape}: one row a position of q AND k, (T, D)")
+    if window is not None and not (causal and window >= 1
+                                   and q.shape[1] == k.shape[1]):
+        raise ValueError(
+            f"a window of {window} keys needs a causal mask and q and k "
+            f"of one length (q {q.shape}, k {k.shape})")
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     # tiles above the diagonal are skipped only where the kernel KNOWS
     # the positions: the defaults.  Explicit (traced) positions visit
     # every tile and mask it score by score, exactly as before
     plan = tile_plan(q.shape[1], k.shape[1], q.shape[-1], q.dtype, causal,
                      default_positions=q_pos is None and k_pos is None,
-                     rotary=rotary is not None)
+                     rotary=rotary is not None, window=window)
     if q_pos is None:
         q_pos = jnp.arange(q.shape[1])
     if k_pos is None:
@@ -983,7 +1421,7 @@ def fused_attention(q, k, v, q_pos=None, k_pos=None,
     if rotary is not None and (resolved == "xla" or not plan.rotates):
         q, k, rotary = rotary_xla(q, rotary), rotary_xla(k, rotary), None
     if resolved == "xla":
-        return _xla_attention(q, k, v, q_pos, k_pos, scale, causal)
+        return _xla_attention(q, k, v, q_pos, k_pos, scale, causal, window)
     if not plan.positions:
         q_pos = k_pos = None
     return _fused(q, k, v, q_pos, k_pos, rotary, scale, causal,

@@ -161,8 +161,12 @@ def _log_buffer_plan(name: str, rungs: tuple) -> None:
               " / ".join(str(r) for r in rungs), TILE_M)
 
 
-def _rung(rows: int, top_k: int, impl: str, name: str, x, weights,
-          expert_params, dest, here, tiles, tile_ends):
+#: the gated form's activations, by ``routed_experts``' ``activation``
+_GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _rung(rows: int, top_k: int, impl: str, name: str, activation: str, x,
+          weights, expert_params, dest, here, tiles, tile_ends):
     """The layer from the placement map to the ``(n, d)`` output in a
     buffer of ``rows`` rows; ``dest (n * top_k,)`` is each assignment's
     buffer row where ``here``, ``tiles`` and ``tile_ends (count,)`` each
@@ -204,7 +208,8 @@ def _rung(rows: int, top_k: int, impl: str, name: str, x, weights,
 
     if "gate" in expert_params:
         gate = matmul(buf, expert_params["gate"], "gate")
-        hidden = jax.nn.silu(gate) * matmul(buf, expert_params["up"], "up")
+        hidden = _GATE_ACTIVATIONS[activation](gate) * matmul(
+            buf, expert_params["up"], "up")
     else:
         hidden = jnp.square(jax.nn.relu(
             matmul(buf, expert_params["up"], "up")))
@@ -267,7 +272,8 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
                    held: tuple[int, int], top_k: int = 1,
                    select_by: jax.Array | None = None,
                    impl: str | None = None, name: str = "routed_experts",
-                   normalize: bool = False, scale: float = 1.0):
+                   normalize: bool = False, scale: float = 1.0,
+                   activation: str = "silu"):
     """This chip's part of a dropless top-k expert layer.
 
     ``x (n, d)`` tokens; ``probs (n, E)`` the router's scores (softmax
@@ -275,9 +281,10 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     count)``: this chip holds experts ``first .. first + count - 1``,
     and ``expert_params`` are theirs alone.  Their keys say what an
     expert is: ``gate`` and ``up`` ``(count, d, f)`` with ``down``
-    ``(count, f, d)`` a gated SiLU MLP, ``(silu(x gate) * (x up))
-    down``; ``up`` and ``down`` alone a two-matrix MLP with a squared
-    ReLU between them, ``relu(x up)^2 down``.  Each token goes to its
+    ``(count, f, d)`` a gated MLP, ``(act(x gate) * (x up)) down`` with
+    ``act`` the ``activation`` named (``"silu"``, the default, or
+    ``"relu"``: ReGLU); ``up`` and ``down`` alone a two-matrix MLP with
+    a squared ReLU between them, ``relu(x up)^2 down``.  Each token goes to its
     ``top_k`` experts weighted by their scores: as they are, or with
     ``normalize`` divided by their sum over ALL the token's chosen
     experts, held here or not (``w = p / (sum of the k chosen p +
@@ -311,6 +318,10 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
         impl = "pallas" if jax.default_backend() == "tpu" else "ragged_dot"
     if impl not in ("pallas", "ragged_dot"):
         raise ValueError(f"unknown expert matmul impl {impl!r}")
+    if activation not in _GATE_ACTIVATIONS or (
+            activation != "silu" and "gate" not in expert_params):
+        raise ValueError(f"activation {activation!r}: the gated form takes "
+                         f"one of {sorted(_GATE_ACTIVATIONS)}")
 
     if select_by is None:
         weights, chosen = lax.top_k(probs, top_k)              # (n, k)
@@ -337,7 +348,7 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     _log_buffer_plan(name, rungs)
     dest = jnp.where(here, starts[jnp.clip(local, 0, count - 1)] + rank, 0)
     operands = (x, weights, expert_params, dest, here, tiles, tile_ends)
-    bodies = [functools.partial(_rung, rows, top_k, impl, name)
+    bodies = [functools.partial(_rung, rows, top_k, impl, name, activation)
               for rows in rungs]
     if len(rungs) == 1:
         out = bodies[0](*operands)
