@@ -371,7 +371,11 @@ def test_a_partly_empty_last_column_tile_in_all_three_kernels():
 @pytest.mark.parametrize("n_assign, count, n_experts, rungs", [
     (8192 * 6, 8, 128, (7168, 50176)),   # NemotronHLM's cell: two rungs
     (8192, 8, 16, (9216,)),              # ZayaLM's: the top one alone
+    (8192 * 10, 32, 512, (14336, 86016)),  # Qwen3NextLM's
+    (16384 * 6, 16, 64, (51200, 100352)),  # SmallThinkerLM's: a quarter held
     (300 * 6, 8, 128, (1280, 2944)),     # the small shape forced below
+    (400 * 6, 4, 16, (1792, 2944)),      # a held quarter, forced below
+    (300 * 6, 4, 16, (1536, 2432)),      # a held quarter: past half
     (300 * 6, 8, 16, (2944,)),           # half the experts held: no room
     (300, 8, 16, (1408,)),               # top-1 (384 + 1024)
     (100, 2, 64, (384,)),                # the slack of a tile an expert
@@ -380,48 +384,70 @@ def test_a_partly_empty_last_column_tile_in_all_three_kernels():
 def test_the_ladder_is_a_function_of_the_calls_shape(n_assign, count,
                                                      n_experts, rungs):
     """The top rung is the dropless buffer; a lower rung of twice the
-    expected load exists only where it is at most half of that."""
+    expected load exists only where it is at most three quarters of
+    that: at a held quarter the slack of a tile an expert takes the
+    lower rung just past half of the top one."""
     from theanompi_tpu.parallel.expert import buffer_ladder
     got = buffer_ladder(n_assign, count, n_experts)
     assert got == rungs
     assert all(r % G.TILE_M == 0 for r in got)
     assert got[-1] >= n_assign + count * G.TILE_M
-    assert all(2 * low <= got[-1] for low in got[:-1])
+    assert all(4 * low <= 3 * got[-1] for low in got[:-1])
 
 
-#: routings that force a rung of the (1280, 2944) ladder of 300 tokens,
-#: top-6 of 128 experts, 8 held: name -> (expert -> the tokens that pick
-#: it, the rung's rows, the held rows)
+#: the two calls whose ladders the routings below force: name ->
+#: (tokens, experts, held), top-6
+_SHAPES = {
+    # an eighth of 128 held: the (1280, 2944) ladder
+    "eighth": (300, 128, 8),
+    # a held quarter, as SmallThinkerLM's chip holds 16 of 64: the
+    # (1792, 2944) ladder, the lower rung just past half of the top one
+    "quarter": (400, 16, 4),
+}
+
+#: routings that force a rung of a ladder: name -> (the shape, expert ->
+#: the tokens that pick it, the rung's rows, the held rows)
 _ROUTINGS = {
     # a deployment's share: 8 tiles, the lower rung with room
-    "few": ({e: range(20 * e, 20 * e + 12) for e in range(8)}, 1280, 96),
+    "few": ("eighth", {e: range(20 * e, 20 * e + 12) for e in range(8)},
+            1280, 96),
     # 3 + 7 tiles: exactly the lower rung's 10
-    "full": ({0: range(300), **{e: range(35 * e, 35 * e + 30)
-                                for e in range(1, 8)}}, 1280, 510),
+    "full": ("eighth", {0: range(300), **{e: range(35 * e, 35 * e + 30)
+                                          for e in range(1, 8)}}, 1280, 510),
     # 3 + 2 + 6 tiles: one more than it holds
-    "one_more": ({0: range(300), 1: range(40, 170),
-                  **{e: range(35 * e, 35 * e + 30) for e in range(2, 8)}},
-                 2944, 610),
+    "one_more": ("eighth", {0: range(300), 1: range(40, 170),
+                            **{e: range(35 * e, 35 * e + 30)
+                               for e in range(2, 8)}}, 2944, 610),
     # every token's six choices held here: the worst case
-    "all": ({e: range(300) for e in range(6)}, 2944, 1800),
+    "all": ("eighth", {e: range(300) for e in range(6)}, 2944, 1800),
+    # a held quarter's share: 4 x 2 tiles on the lower rung
+    "quarter_few": ("quarter", {e: range(60 * e, 60 * e + 150)
+                                for e in range(4)}, 1792, 600),
+    # 4 + 4 + 4 + 2 tiles: exactly the lower rung's 14
+    "quarter_full": ("quarter", {**{e: range(400) for e in range(3)},
+                                 3: range(200)}, 1792, 1400),
+    # 4 + 4 + 4 + 3 tiles: one more than it holds
+    "quarter_one_more": ("quarter", {**{e: range(400) for e in range(3)},
+                                     3: range(300)}, 2944, 1500),
 }
 
 
 def _forced_layer(routing, form, seed=17):
     """Tokens, scores and the held experts' matrices: the planned
     assignments score 0.5-0.9, a held expert scores 0 elsewhere, and a
-    token's other choices fall on experts 8-127."""
-    plan = _ROUTINGS[routing][0]
+    token's other choices fall on the experts not held."""
+    shape, plan = _ROUTINGS[routing][:2]
+    n, n_experts, count = _SHAPES[shape]
     key = jax.random.key(seed)
-    u, _, experts = _relu2_layer(n_experts=8, seed=seed)
+    u, _, experts = _relu2_layer(n=n, n_experts=count, seed=seed)
     if form in ("gated", "reglu"):
         experts["gate"] = 0.3 * jax.random.normal(
             jax.random.fold_in(key, 5), experts["up"].shape)
     scores = np.array(0.01 + 0.09 * jax.random.uniform(
-        jax.random.fold_in(key, 6), (300, 128)))
-    scores[:, :8] = 0.0
+        jax.random.fold_in(key, 6), (n, n_experts)))
+    scores[:, :count] = 0.0
     high = np.array(0.5 + 0.4 * jax.random.uniform(
-        jax.random.fold_in(key, 7), (300, 8)))
+        jax.random.fold_in(key, 7), (n, count)))
     for e, tokens in plan.items():
         scores[list(tokens), e] = high[list(tokens), e]
     return u, jnp.asarray(scores), experts
@@ -438,13 +464,24 @@ def _uncut(u, scores, experts, activation="silu"):
     weights = 2.5 * picked / (picked.sum(-1, keepdims=True) + 1e-20)
     act = {"silu": jax.nn.silu, "relu": jax.nn.relu}[activation]
     out = 0
-    for e in range(8):
+    for e in range(experts["up"].shape[0]):
         up = u @ experts["up"][e]
         hidden = (act(u @ experts["gate"][e]) * up
                   if "gate" in experts else jnp.square(jax.nn.relu(up)))
         out = out + (((chosen == e) * weights).sum(-1)[:, None]
                      * (hidden @ experts["down"][e]))
     return out
+
+
+def _loss(fn):
+    """``fn``'s output, its counters where it has them, and the
+    gradients of its sum of squares by tokens, scores and matrices."""
+    def scalar(u, scores, experts):
+        out = fn(u, scores, experts)
+        out, stats = out if isinstance(out, tuple) else (out, None)
+        return (out ** 2).sum(), (out, stats)
+    return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2),
+                                      has_aux=True))
 
 
 @pytest.mark.parametrize("form", ["relu2", "gated", "reglu"])
@@ -455,35 +492,61 @@ def test_every_rung_is_the_uncut_layer(routing, impl, form):
     counters on each rung of the ladder, top-6 normalised and scaled:
     the lower rung sums from the buffer's side, the top one gathers as
     before, and both are differentiated through the ladder's own VJP;
-    for the squared ReLU, the gated SiLU and the gated ReLU (ReGLU)."""
+    for the squared ReLU, the gated SiLU and the gated ReLU (ReGLU),
+    with an eighth of the experts held and with a quarter."""
     u, scores, experts = _forced_layer(routing, form)
-    _, rows, held_rows = _ROUTINGS[routing]
+    shape, _, rows, held_rows = _ROUTINGS[routing]
+    n, _, count = _SHAPES[shape]
     activation = _ACTIVATIONS[form]
 
     def layer(u, scores, experts):
-        return routed_experts(u, scores, experts, (0, 8), top_k=6,
+        return routed_experts(u, scores, experts, (0, count), top_k=6,
                               normalize=True, scale=2.5, impl=impl,
                               activation=activation)
 
     def uncut(u, scores, experts):
         return _uncut(u, scores, experts, activation)
 
-    def loss(fn):
-        def scalar(u, scores, experts):
-            out = fn(u, scores, experts)
-            out, stats = out if isinstance(out, tuple) else (out, None)
-            return (out ** 2).sum(), (out, stats)
-        return jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2),
-                                          has_aux=True))
-
-    (_, (got_out, stats)), got = loss(layer)(u, scores, experts)
-    (_, (want_out, _)), want = loss(uncut)(u, scores, experts)
+    (_, (got_out, stats)), got = _loss(layer)(u, scores, experts)
+    (_, (want_out, _)), want = _loss(uncut)(u, scores, experts)
     assert (stats["buffer_rows"], stats["held_rows"]) == (rows, held_rows)
-    assert stats["held_rows"] + stats["rows_elsewhere"] == 1800
+    assert stats["held_rows"] + stats["rows_elsewhere"] == 6 * n
     np.testing.assert_allclose(got_out, want_out, rtol=1e-5, atol=1e-5)
     for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(a, b, rtol=2e-4,
                                    atol=2e-5 * float(jnp.abs(b).max() + 1))
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_a_held_quarter_is_the_same_layer_on_either_rung(impl,
+                                                         monkeypatch):
+    """The routing of a held quarter's share, on the lower rung (summed
+    from the buffer's side) and forced onto the top rung alone (summed
+    from the tokens' side): the same output and gradients as each other
+    and as ``ragged_dot`` on the lower rung, in ReGLU as
+    ``SmallThinkerLM`` runs it."""
+    from theanompi_tpu.parallel import expert
+    u, scores, experts = _forced_layer("quarter_few", "reglu")
+
+    def run(impl):
+        return _loss(lambda u, s, p: routed_experts(
+            u, s, p, (0, 4), top_k=6, normalize=True, scale=2.5, impl=impl,
+            activation="relu"))(u, scores, experts)
+
+    (_, (low, low_stats)), low_grads = run(impl)
+    (_, (oracle, _)), oracle_grads = run("ragged_dot")
+    ladder = expert.buffer_ladder
+    monkeypatch.setattr(expert, "buffer_ladder",
+                        lambda *shape: ladder(*shape)[-1:])
+    (_, (top, top_stats)), top_grads = run(impl)
+    assert (low_stats["buffer_rows"], top_stats["buffer_rows"]) == (
+        1792, 2944)
+    for want, want_grads in ((top, top_grads), (oracle, oracle_grads)):
+        np.testing.assert_allclose(low, want, rtol=1e-5, atol=1e-5)
+        for a, b in zip(jax.tree.leaves(low_grads),
+                        jax.tree.leaves(want_grads)):
+            np.testing.assert_allclose(
+                a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max() + 1))
 
 
 def test_the_ladder_is_recomputed_under_remat_like_the_uncut_layer():
