@@ -329,24 +329,33 @@ def kernel_cases(full: bool = True) -> list[KernelCase]:
             q, k, v, causal=True, impl=impl, window=window),
         _normal((q_shape, bf16), (kv_shape, bf16), (kv_shape, bf16)),
         rtol=2e-2, atol=2e-2))
-    n, d, held, routed = (8192, 2048, 8, 16) if full else (200, 16, 2, 4)
+    def experts_case(kernel, n, d, held, routed, **kw):
+        def experts_layer(impl):
+            from theanompi_tpu.parallel.expert import routed_experts
 
-    def experts_layer(impl):
-        from theanompi_tpu.parallel.expert import routed_experts
+            def layer(x, logits, gate, up, down):
+                out, _ = routed_experts(
+                    x, jax.nn.softmax(logits, -1),
+                    {"gate": gate * d ** -0.5, "up": up * d ** -0.5,
+                     "down": down * d ** -0.5}, (0, held),
+                    impl="pallas" if impl == "pallas" else "ragged_dot",
+                    **kw)
+                return out
+            return layer
 
-        def layer(x, logits, gate, up, down):
-            out, _ = routed_experts(
-                x, jax.nn.softmax(logits, -1),
-                {"gate": gate * d ** -0.5, "up": up * d ** -0.5,
-                 "down": down * d ** -0.5}, (0, held),
-                impl="pallas" if impl == "pallas" else "ragged_dot")
-            return out
-        return layer
+        return KernelCase(
+            f"{kernel}({n}, {d})x{held}of{routed}", experts_layer,
+            _normal(((n, d), bf16), ((n, routed), f32),
+                    *[((held, d, d), bf16)] * 3), rtol=2e-2, atol=2e-2)
 
-    cases.append(KernelCase(
-        f"grouped_matmul({n}, {d})x{held}of{routed}", experts_layer,
-        _normal(((n, d), bf16), ((n, routed), f32),
-                *[((held, d, d), bf16)] * 3), rtol=2e-2, atol=2e-2))
+    cases.append(experts_case(
+        "grouped_matmul",
+        *((8192, 2048, 8, 16) if full else (200, 16, 2, 4))))
+    # a held quarter, top-6: the ladder's lower rung sums the buffer
+    # into the tokens by the row kernel (against XLA's scatter-add)
+    cases.append(experts_case(
+        "expert_rows", *((8192, 2048, 8, 32) if full else (400, 16, 4, 16)),
+        top_k=6, normalize=True))
     # NemotronHLM's Mamba-2 scan: 64 heads of 64 in 8 groups, state and
     # chunk 128; time steps made positive, decays negative, and B and C
     # scaled so that C . B is of order 1, as a layer's are

@@ -468,6 +468,46 @@ def test_the_delta_rule_kernels_compile_for_a_v5e_under_their_scope(
                        ("qwen3_next_delta_rule_bwd", "backward")}
 
 
+@pytest.mark.parametrize("n, top_k, count, rows, d", [
+    (16384, 6, 16, 51200, 2560),     # SmallThinker's lower rung
+    (8192, 10, 32, 14336, 2048),     # Qwen3-Next's
+    (8192, 6, 8, 7168, 2688),        # Nemotron's
+])
+def test_the_row_kernel_compiles_for_a_v5e(one_chip, n, top_k, count, rows,
+                                           d):
+    """The expert buffer's sum into the tokens (ops/expert_rows.py) at
+    the three lower rungs that sum from the buffer's side, bf16, with
+    the weight fused and without, its tables built in the same
+    program: Mosaic takes it, and the scalar tables fit SMEM."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from theanompi_tpu.ops import expert_rows
+
+    plan = expert_rows.row_plan(n, rows, count, "test")
+    assert plan.pallas
+
+    def layer(values, token, weight, onehot, starts):
+        walk = expert_rows.visits(*expert_rows.block_ranges(
+            onehot, starts, top_k, plan), plan)
+        return (expert_rows.sum_rows(values, token, weight, walk, plan),
+                expert_rows.sum_rows(values, token, None, walk, plan))
+
+    shapes = [((rows, d), jnp.bfloat16), ((rows,), jnp.int32),
+              ((rows,), jnp.float32), ((n * top_k, count), jnp.bool_),
+              ((count,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(layer).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "test_rows" in text
+
+
 #: every attention shape the LM cells call (q, key/value heads, whether
 #: the kernels rotate) and its plan: the four cells' from before PR 38,
 #: pinned so that the wide-head budget moves none of them, and the

@@ -148,11 +148,11 @@ class TestKernelLeg:
                     with_kernel.add(os.path.basename(path))
         assert with_kernel == {"lrn_pallas.py", "attention.py",
                                "fused_bn.py", "grouped_matmul.py", "ssd.py",
-                               "gated_delta.py"}
+                               "gated_delta.py", "expert_rows.py"}
         names = " ".join(c.name for c in _TINY_CASES)
         for stem in ("lrn", "attention", "attention_gqa",
                      "attention_window", "fused_bn",
-                     "grouped_matmul", "ssd", "gated_delta"):
+                     "grouped_matmul", "ssd", "gated_delta", "expert_rows"):
             assert stem in names
 
     def test_kernel_that_disagrees_fails(self):

@@ -4,6 +4,7 @@
 interpreted, ``jax.lax.ragged_dot`` is the oracle, and the benchmark's
 plain reference (benchmarks/reference/zaya1_8b.py) is the uncut layer."""
 
+import dataclasses
 import importlib.util
 import os
 
@@ -611,7 +612,9 @@ def test_the_plan_is_said_once_a_shape(caplog):
                            name="nemotron_h_experts")
     said = [r.getMessage() for r in caplog.records]
     assert said == ["nemotron_h_experts: expert buffer: rungs 1280 / 2944 "
-                    "of 128-row tiles"]
+                    "of 128-row tiles",
+                    "nemotron_h_experts: rung 1280 summed into 300 tokens "
+                    "by an XLA scatter-add"]
 
 
 def test_the_kernels_keep_their_names_inside_the_ladders_backward():
@@ -655,3 +658,199 @@ def test_the_default_activation_is_the_gated_silu_bit_for_bit(routing):
     two = {k: experts[k] for k in ("up", "down")}
     with pytest.raises(ValueError, match="gated form"):
         layer(activation="relu")(u, scores, two)
+
+
+# --- the row kernel: the buffer summed into the tokens ----------------
+
+def _placed_buffer(chosen, count, seed, dtype, spare_tiles=2):
+    """A buffer laid out as ``routed_experts`` lays it, built here from
+    the picks alone: experts ``0 .. count - 1`` are held, each one's
+    rows in assignment order from the first row of its tiles.  Padding
+    rows and the ``spare_tiles`` past the tiles in use hold NaN, which
+    must not reach the output."""
+    n, top_k = chosen.shape
+    local = chosen.reshape(-1)
+    onehot = local[:, None] == np.arange(count)
+    sizes = onehot.sum(0)
+    tiles = np.maximum(-(-sizes // G.TILE_M), 1)
+    starts = (np.cumsum(tiles) - tiles) * G.TILE_M
+    rows = int(tiles.sum() + spare_tiles) * G.TILE_M
+    src = np.full(rows, n * top_k)
+    for e in range(count):
+        picks = np.flatnonzero(local == e)
+        src[starts[e]:starts[e] + len(picks)] = picks
+    placed = src < n * top_k
+    key = jax.random.key(seed)
+    values = jax.random.normal(key, (rows, 24), jnp.float32).astype(dtype)
+    values = jnp.where(jnp.asarray(placed)[:, None], values, jnp.nan)
+    weight = jax.random.uniform(jax.random.fold_in(key, 1), (rows,),
+                                minval=0.1, maxval=0.9)
+    return (values, weight, jnp.asarray(np.where(placed, src, 0) // top_k,
+                                        jnp.int32),
+            jnp.asarray(placed), jnp.asarray(onehot),
+            jnp.asarray(starts, jnp.int32))
+
+
+def _picks(n, top_k, n_experts, seed, held_by=None, none_held=()):
+    """Each token's ``top_k`` distinct experts at random; ``held_by``
+    pins the first picks of some tokens (``token -> experts``), and the
+    tokens in ``none_held`` pick none of the held experts (those below
+    ``n_experts // 2`` here)."""
+    rng = np.random.default_rng(seed)
+    chosen = np.stack([rng.permutation(n_experts)[:top_k] for _ in range(n)])
+    for t, experts in (held_by or {}).items():
+        rest = [e for e in chosen[t] if e not in experts]
+        chosen[t] = (list(experts) + rest)[:top_k]
+    for t in none_held:
+        chosen[t] = rng.permutation(np.arange(n_experts // 2, n_experts))[
+            :top_k]
+    return chosen
+
+
+#: name -> (picks, held experts, tokens a block, rows a window)
+_ROW_CASES = {
+    # tokens 32..63 pick no held expert: a block with no row (its one
+    # empty visit), and empty (block, expert) ranges all round
+    "empty_ranges_and_an_empty_block": (
+        _picks(96, 2, 8, 1, none_held=range(32, 64)), 4, 32, 16),
+    # every token picks held expert 0: a range of 64 rows in one block,
+    # four windows of 16
+    "a_range_longer_than_a_window": (
+        _picks(64, 2, 4, 2, held_by={t: (0,) for t in range(64)}), 2, 64,
+        16),
+    # all of a token's picks held (and every token's: all experts held)
+    "every_pick_held": (_picks(80, 3, 4, 3), 4, 16, 16),
+    # a held quarter, top-6, uneven blocks (200 = 3 x 64 + 8)
+    "a_held_quarter_top_6": (_picks(200, 6, 16, 4), 4, 64, 32),
+}
+
+
+@pytest.mark.parametrize("weighted", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(_ROW_CASES))
+def test_the_row_kernel_is_the_scatter_add(case, dtype, weighted):
+    """``sum_rows`` interpreted against the XLA scatter-add it replaces,
+    with and without the weight fused, forward and both gradients
+    (through ``_move_rows`` and ``_sum_weighted``, whose VJPs are XLA's
+    gathers): empty ranges and an empty block, a range over several
+    windows, tokens with every pick held and with none, and NaN in the
+    rows no range covers."""
+    from theanompi_tpu.ops import expert_rows
+    from theanompi_tpu.parallel import expert
+
+    chosen, count, block, window = _ROW_CASES[case]
+    n = chosen.shape[0]
+    rows, weight, token, placed, onehot, starts = _placed_buffer(
+        chosen, count, 5, dtype)
+    plan = expert_rows.RowPlan("test", rows.shape[0], n, count, block,
+                               window)
+    walk = expert_rows.visits(*expert_rows.block_ranges(
+        onehot, starts, chosen.shape[1], plan), plan)
+    xla = dataclasses.replace(plan, pallas=False)
+
+    def kernel(rows, weight):
+        if weighted:
+            return expert._sum_weighted(rows, weight, token, placed, walk,
+                                        plan)
+        return expert._move_rows(rows, token, placed, walk, n, False, plan)
+
+    def scatter_add(rows, weight):
+        if weighted:
+            rows = (jnp.where(placed[:, None], rows, 0).astype(jnp.float32)
+                    * weight[:, None])
+        return expert._move_rows(rows, token, placed, walk, n, False,
+                                 xla).astype(dtype)
+
+    cotangent = jax.random.normal(jax.random.key(9), (n, 24), dtype)
+    for fn in (kernel, scatter_add):
+        assert fn(rows, weight).dtype == dtype
+    got, want = (jax.jit(fn)(rows, weight) for fn in (kernel, scatter_add))
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    tol = 1e-6 if dtype == jnp.float32 else 1e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+    grads = [jax.jit(jax.grad(
+        lambda r, w, fn=fn: (fn(r, w).astype(jnp.float32)
+                             * cotangent.astype(jnp.float32)).sum(),
+        argnums=(0, 1)))(rows, weight) for fn in (kernel, scatter_add)]
+    for a, b in zip(*grads):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=tol, atol=tol)
+    # the walk: visits in order of block, at least one a block
+    v_block, _, first, end, n_visits = (np.asarray(a) for a in walk)
+    assert set(v_block[:n_visits]) == set(range(plan.blocks))
+    assert (np.diff(v_block[:n_visits]) >= 0).all()
+    assert (end - first)[:n_visits].sum() == int(placed.sum())
+    assert n_visits <= plan.max_visits
+
+
+@pytest.mark.parametrize("block, window", [(None, None), (64, 16)])
+@pytest.mark.parametrize("form", ["reglu", "gated", "relu2"])
+def test_the_lower_rung_sums_by_the_row_kernel(form, block, window,
+                                               monkeypatch):
+    """The whole layer on a held quarter's lower rung with the kernels
+    forced (interpreted), against ``ragged_dot`` and its scatter-add:
+    the row kernel runs in the program, and output and every gradient
+    agree, at the planned blocks (one block here) and at blocks of 64
+    tokens in windows of 16 rows."""
+    from theanompi_tpu.ops import expert_rows
+
+    if block:
+        monkeypatch.setattr(expert_rows, "BLOCK", block)
+        monkeypatch.setattr(expert_rows, "WINDOW", window)
+    u, scores, experts = _forced_layer("quarter_few", form)
+
+    def run(impl):
+        return _loss(lambda u, s, p: routed_experts(
+            u, s, p, (0, 4), top_k=6, normalize=True, scale=2.5, impl=impl,
+            activation=_ACTIVATIONS[form], name="smallthinker_experts"))
+
+    text = str(jax.make_jaxpr(run("pallas"))(u, scores, experts))
+    assert "smallthinker_experts_rows" in text
+    assert "smallthinker_experts_rows" not in str(
+        jax.make_jaxpr(run("ragged_dot"))(u, scores, experts))
+    (_, (got, stats)), got_grads = run("pallas")(u, scores, experts)
+    (_, (want, _)), want_grads = run("ragged_dot")(u, scores, experts)
+    assert stats["buffer_rows"] == 1792
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(a, b, rtol=1e-4,
+                                   atol=1e-5 * float(jnp.abs(b).max() + 1))
+
+
+@pytest.mark.parametrize("n, rows, count, plan", [
+    # the three cells whose lower rung sums from the buffer's side
+    (16384, 51200, 16, "blocks of 1024 tokens, windows of 128 rows, at "
+                       "most 928 visits"),
+    (8192, 14336, 32, "blocks of 1024 tokens, windows of 128 rows, at "
+                      "most 632 visits"),
+    (8192, 7168, 8, "blocks of 1024 tokens, windows of 128 rows, at "
+                    "most 192 visits"),
+])
+def test_the_row_plan_is_a_function_of_the_calls_shape(n, rows, count,
+                                                        plan):
+    from theanompi_tpu.ops import expert_rows
+    got = expert_rows.row_plan(n, rows, count, "x")
+    assert str(got) == (f"x: rung {rows} summed into {n} tokens by x_rows "
+                        f"({plan})")
+    assert not expert_rows.row_plan(n, rows, count, "x", False).pallas
+    # a buffer whose tables would not fit SMEM keeps the scatter-add
+    assert not expert_rows.row_plan(n, 65536 + rows, count, "x").pallas
+
+
+def test_the_row_plan_is_said_beside_the_ladder(caplog):
+    from theanompi_tpu.parallel import expert
+    expert._log_buffer_plan.cache_clear()
+    u, scores, experts = _forced_layer("few", "relu2")
+    with caplog.at_level("INFO", logger=expert.__name__):
+        for _ in range(2):
+            routed_experts(u, scores, experts, (0, 8), top_k=6,
+                           impl="pallas", name="nemotron_h_experts")
+    said = [r.getMessage() for r in caplog.records]
+    assert said == ["nemotron_h_experts: expert buffer: rungs 1280 / 2944 "
+                    "of 128-row tiles",
+                    "nemotron_h_experts: rung 1280 summed into 300 tokens "
+                    "by nemotron_h_experts_rows (blocks of 304 tokens, "
+                    "windows of 128 rows, at most 27 visits)"]

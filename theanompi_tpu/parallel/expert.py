@@ -49,9 +49,12 @@ grouped products (three, or two), and are summed back into the tokens.
   host round trip, no recompile, and never a dropped row.
 * **The sum from the buffer's side.**  On a rung with fewer rows than
   there are assignments, a placed row knows its token and its weight,
-  and the output is a scatter-add of the rung's weighted rows into the
-  tokens, in float32 (``_move_rows``; its transpose is the gather that
-  fills the buffer).  On the top rung the buffer is the
+  and the output is the sum of the rung's weighted rows into the
+  tokens, in float32: on the kernels' path the Pallas kernel
+  ``<name>_rows`` (ops/expert_rows.py) with the weight applied inside
+  it (``_sum_weighted``), which is also the transpose of the gather
+  that fills the buffer (``_move_rows``); off it (``ragged_dot``, the
+  oracle) XLA's scatter-add, both ways.  On the top rung the buffer is the
   longer side, and each token gathers its ``top_k`` rows (``_take_rows``,
   whose transpose is a gather too).  Read on a TPU v5 lite at ``(n, d)``
   = (8 192, 2 688) bf16 (PR 35): summing 7 168 rows into the tokens takes
@@ -61,6 +64,13 @@ grouped products (three, or two), and are summed back into the tokens.
   of 50 176 rows 1.3 ms.  On the same chip a whole ReGLU layer at
   (16 384, 2 560), top-6, on a rung of 51 200 rows takes 40.0 ms forward
   and backward summed from the buffer's side, 51.7 ms from the tokens'.
+  The row kernel against the scatter-add there, weighted / not, the
+  walk's tables included, under uniform routing: 51 200 rows of which
+  24 542 held, d 2 560: 1.01 / 0.99 ms against 7.88 / 7.93; 14 336
+  rows, 5 184 held, d 2 048: 0.44 / 0.45 against 1.65 / 1.66; 7 168
+  rows, 3 042 held, d 2 688: 0.40 / 0.42 against 1.17 / 1.18.  It is
+  faster at all three lower rungs that sum from the buffer's side, so
+  it runs wherever its tables fit SMEM.
 * **The ladder's own VJP** (``_ladder``).  JAX differentiates a
   conditional by making every branch return every branch's residuals,
   zero-filled where not taken: the top rung's buffers would be written
@@ -78,7 +88,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from theanompi_tpu.ops import pallas_mode
+from theanompi_tpu.ops import expert_rows, pallas_mode
 from theanompi_tpu.ops.grouped_matmul import TILE_M, grouped_matmul
 from theanompi_tpu.parallel.mesh import AXIS_EXPERT
 
@@ -114,30 +124,67 @@ def _take_rows_bwd(res, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _move_rows(a, token, placed, n: int, to_buffer: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _move_rows(a, token, placed, walk, n: int, to_buffer: bool, plan):
     """Rows between the tokens ``(n, d)`` and a buffer ``(R, d)`` whose
     placed row ``r`` belongs to token ``token[r]``, in ``a.dtype``.
     ``to_buffer``: ``where(placed, a[token], 0)``, a gather.  Else its
-    transpose, ``out[t] = sum of the placed rows r with token[r] == t``:
-    a scatter-add, summed in float32.  The lower rungs' form; each
+    transpose, ``out[t] = sum of the placed rows r with token[r] == t``,
+    summed in float32: the row kernel where ``plan`` runs it (over the
+    visits ``walk``), else a scatter-add.  The lower rungs' form; each
     direction is the other's VJP."""
     if to_buffer:
         return jnp.where(placed[:, None], a[token], 0).astype(a.dtype)
+    if plan.pallas:
+        return expert_rows.sum_rows(a, token, None, walk, plan,
+                                    pallas_mode.interpret())
     out = jnp.zeros((n, a.shape[-1]), jnp.float32).at[
         jnp.where(placed, token, n)].add(a.astype(jnp.float32), mode="drop")
     return out.astype(a.dtype)
 
 
-def _move_rows_fwd(a, token, placed, n, to_buffer):
-    return _move_rows(a, token, placed, n, to_buffer), (token, placed)
+def _move_rows_fwd(a, token, placed, walk, n, to_buffer, plan):
+    return (_move_rows(a, token, placed, walk, n, to_buffer, plan),
+            (token, placed, walk))
 
 
-def _move_rows_bwd(n, to_buffer, res, g):
-    return _move_rows(g, *res, n, not to_buffer), None, None
+def _move_rows_bwd(n, to_buffer, plan, res, g):
+    token, placed, walk = res
+    return (_move_rows(g, token, placed, walk, n, not to_buffer, plan),
+            None, None, None)
 
 
 _move_rows.defvjp(_move_rows_fwd, _move_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _sum_weighted(rows, weight, token, placed, walk, plan):
+    """``out[t] = sum over the placed rows r with token[r] == t of
+    f32(rows[r]) * weight[r]``, in float32 and then ``rows.dtype``: the
+    row kernel with the weight applied inside it, so that the float32
+    ``(R, d)`` weighted rows are never written.  Its VJP is the XLA
+    program the unfused form differentiates to: the gather of the
+    float32 cotangent, then the product's transpose."""
+    return expert_rows.sum_rows(rows, token, weight, walk, plan,
+                                pallas_mode.interpret())
+
+
+def _sum_weighted_fwd(rows, weight, token, placed, walk, plan):
+    return (_sum_weighted(rows, weight, token, placed, walk, plan),
+            (rows, weight, token, placed))
+
+
+def _sum_weighted_bwd(plan, res, g):
+    rows, weight, token, placed = res
+    mask = placed[:, None]
+    picked = jnp.where(mask, g.astype(jnp.float32)[token], 0)
+    d_rows = jnp.where(mask, (picked * weight[:, None].astype(jnp.float32))
+                       .astype(rows.dtype), 0)
+    d_weight = (picked * jnp.where(mask, rows, 0).astype(jnp.float32)).sum(-1)
+    return d_rows, d_weight.astype(weight.dtype), None, None, None
+
+
+_sum_weighted.defvjp(_sum_weighted_fwd, _sum_weighted_bwd)
 
 
 def buffer_ladder(n_assign: int, count: int, n_experts: int) -> tuple:
@@ -157,24 +204,31 @@ def buffer_ladder(n_assign: int, count: int, n_experts: int) -> tuple:
 
 
 @functools.lru_cache(maxsize=None)
-def _log_buffer_plan(name: str, rungs: tuple) -> None:
+def _log_buffer_plan(name: str, rungs: tuple, plan) -> None:
     """The ladder is a pure function of the call's shape, so its plan
     is said once a shape (trace time only), as ``tile_plan`` says its
-    own; ``stats["buffer_rows"]`` counts which rung a step took."""
+    own, and the row kernel's ``plan`` where a rung sums from the
+    buffer's side; ``stats["buffer_rows"]`` counts which rung a step
+    took."""
     _log.info("%s: expert buffer: rungs %s of %d-row tiles", name,
               " / ".join(str(r) for r in rungs), TILE_M)
+    if plan:
+        _log.info("%s", plan)
 
 
 #: the gated form's activations, by ``routed_experts``' ``activation``
 _GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
-def _rung(rows: int, top_k: int, impl: str, name: str, activation: str, x,
-          weights, expert_params, dest, here, tiles, tile_ends):
+def _rung(rows: int, top_k: int, impl: str, name: str, activation: str,
+          plan, x, weights, expert_params, dest, here, tiles, tile_ends,
+          *walk):
     """The layer from the placement map to the ``(n, d)`` output in a
     buffer of ``rows`` rows; ``dest (n * top_k,)`` is each assignment's
     buffer row where ``here``, ``tiles`` and ``tile_ends (count,)`` each
-    held expert's tiles and their running sum."""
+    held expert's tiles and their running sum.  ``plan``: the row
+    kernel's plan where this rung sums from the buffer's side (else
+    None), and ``walk`` its visits."""
     n, _ = x.shape
     count = tiles.shape[0]
     n_assign = n * top_k
@@ -192,7 +246,7 @@ def _rung(rows: int, top_k: int, impl: str, name: str, activation: str, x,
 
     if from_buffer:
         token = src // top_k
-        buf = _move_rows(x, token, placed, n, True)
+        buf = _move_rows(x, token, placed, walk, n, True, plan)
     else:
         dest_nk = dest.reshape(n, top_k)
         here_nk = here.reshape(n, top_k)
@@ -221,10 +275,14 @@ def _rung(rows: int, top_k: int, impl: str, name: str, activation: str, x,
     if from_buffer:
         # a placed row knows its token and its weight; rows past
         # ``n_tiles`` are undefined: masked before anything is scaled
+        weight = weights.reshape(-1)[src]
+        if plan.pallas:
+            return _sum_weighted(out_rows, weight, token, placed, walk,
+                                 plan).astype(x.dtype)
         weighted = (jnp.where(placed[:, None], out_rows, 0)
-                    .astype(jnp.float32)
-                    * weights.reshape(-1)[src][:, None])
-        return _move_rows(weighted, token, placed, n, False).astype(x.dtype)
+                    .astype(jnp.float32) * weight[:, None])
+        return _move_rows(weighted, token, placed, walk, n, False,
+                          plan).astype(x.dtype)
     picked = _take_rows(out_rows, dest_nk, here_nk, src[:, None],
                         placed[:, None])                       # (n, k, d)
     return (picked * weights[..., None].astype(picked.dtype)).sum(1)
@@ -349,10 +407,21 @@ def routed_experts(x: jax.Array, probs: jax.Array, expert_params: PyTree,
     starts = (tile_ends - tiles) * TILE_M          # each expert's first row
     n_assign = n * top_k
     rungs = buffer_ladder(n_assign, count, n_experts)
-    _log_buffer_plan(name, rungs)
+    # the lower rung, where it has fewer rows than there are assignments
+    # (the top rung holds them all), sums from the buffer's side
+    low = rungs[0] if rungs[0] < n_assign else None
+    plan = (expert_rows.row_plan(n, low, count, name, impl == "pallas")
+            if low else None)
+    _log_buffer_plan(name, rungs, plan)
     dest = jnp.where(here, starts[jnp.clip(local, 0, count - 1)] + rank, 0)
-    operands = (x, weights, expert_params, dest, here, tiles, tile_ends)
-    bodies = [functools.partial(_rung, rows, top_k, impl, name, activation)
+    walk = ()
+    if plan and plan.pallas:
+        walk = expert_rows.visits(*expert_rows.block_ranges(
+            onehot, starts, top_k, plan), plan)
+    operands = (x, weights, expert_params, dest, here, tiles, tile_ends,
+                *walk)
+    bodies = [functools.partial(_rung, rows, top_k, impl, name, activation,
+                                plan if rows == low else None)
               for rows in rungs]
     if len(rungs) == 1:
         out = bodies[0](*operands)
